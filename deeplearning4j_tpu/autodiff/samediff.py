@@ -323,7 +323,7 @@ class SameDiff:
         return name
 
     def _while_static_pattern(self, node):
-        """Match the bounded-counter loop shape (VERDICT r3 item 5):
+        """Match the bounded-counter loop shape (round-3 review item 5):
         cond is ``less(state_k, N)`` with N a cond-graph constant or a
         pass-through loop var, and the body increments state_k by
         exactly 1.  Returns (k, ("const", N) | ("state", j)) or None.

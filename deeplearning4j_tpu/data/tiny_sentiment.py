@@ -1,5 +1,5 @@
 """Tiny hand-written sentiment corpus — the egress-free stand-in for
-SST-2 in BASELINE config 4's fine-tune quality proof (VERDICT r4 item
+SST-2 in BASELINE config 4's fine-tune quality proof (round-4 review item
 3: "no run anywhere shows held-out accuracy improving on a real
 labeled text task").
 

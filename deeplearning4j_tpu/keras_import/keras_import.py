@@ -1,6 +1,6 @@
 """Keras HDF5 → MultiLayerNetwork / ComputationGraph.
 
-Scope (the layer set covering this repo's zoo, per VERDICT item 6):
+Scope (the layer set covering this repo's zoo, per review item 6):
 InputLayer, Dense, Conv2D, DepthwiseConv2D, MaxPooling2D,
 AveragePooling2D, GlobalAveragePooling2D, BatchNormalization, Flatten,
 Dropout, Activation, ZeroPadding2D, Embedding, LSTM, Add, Concatenate.

@@ -5,7 +5,7 @@ each kernel here is a few dozen lines of Python lowered through Mosaic.
 """
 from deeplearning4j_tpu.kernels.flash_attention import (
     attention, flash_attention, mask_to_bias, reset_route_log, route_log,
-    xla_attention)
+    trace_mesh, xla_attention)
 from deeplearning4j_tpu.kernels.paged_attention import (
     paged_decode_attention, paged_decode_attention_reference,
     paged_gather, paged_verify_attention,
@@ -15,4 +15,4 @@ __all__ = ["attention", "flash_attention", "mask_to_bias",
            "paged_decode_attention", "paged_decode_attention_reference",
            "paged_gather", "paged_verify_attention",
            "paged_verify_attention_reference", "reset_route_log",
-           "route_log", "xla_attention"]
+           "route_log", "trace_mesh", "xla_attention"]

@@ -20,7 +20,8 @@ decision at trace time):
   byte-identical to the stripe layout, which is what lets the server's
   offline-parity invariant survive the paged rewrite.  CPU tier-1
   always routes here.
-* ``_paged_decode_pallas`` — a Pallas TPU kernel, grid (B, max_blocks):
+* ``_paged_verify_pallas`` — a Pallas TPU kernel, grid (B, max_blocks),
+  W query rows per slot (``_paged_decode_pallas`` is its W == 1 case):
   the block table rides as a SCALAR-PREFETCH operand so each K/V block
   DMA is issued straight out of the table entry (no gathered [B, L]
   copy of the pool ever materializes in HBM), with the flash-style
@@ -28,7 +29,9 @@ decision at trace time):
   the block axis and lane-replicated row stats.  Out-of-context blocks
   (``kb * bs > pos``) skip their matmuls entirely.  Ideal shapes are
   the usual Mosaic ones (dh a multiple of 128); correctness at any
-  shape is exercised under ``interpret=True``.
+  shape is exercised under ``interpret=True``, and
+  ``tests/test_chip_compile.py`` holds both entries to the chip's
+  compiler at the served widths.
 
 Scratch block 0 is the pool's write sink for masked-inactive slots —
 never referenced by a live table entry, so its contents are garbage by
@@ -48,19 +51,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.kernels.flash_attention import (_NEG, _LANES,
-                                                        _interpret,
-                                                        _lane_bcast)
-
-
-def _dimsem(*sem):
-    """dimension_semantics compiler params across jax versions (the
-    flash module's helper predates the CompilerParams ->
-    TPUCompilerParams rename and fails on this jax)."""
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    if cp is None:              # very old jax: plain dict form
-        return dict(mosaic=dict(dimension_semantics=sem))
-    return cp(dimension_semantics=sem)
+                                                        _dimsem,
+                                                        _interpret)
 
 _ROUTE_TOTAL = telemetry.counter(
     "paged_route_total",
@@ -107,82 +99,14 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_table,
     return att[:, :, 0, :]
 
 
-def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, bs: int, mb: int,
-                   scale: float):
-    """Grid (B, max_blocks), block axis minor/arbitrary: per slot,
-    stream the table's K/V blocks through VMEM with the running softmax
-    state in scratch; blocks past the context length skip compute."""
-    b, kb = pl.program_id(0), pl.program_id(1)
-    h, dh = q_ref.shape[1], q_ref.shape[2]
-
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[b]
-
-    @pl.when(kb * bs <= pos)
-    def _compute():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale      # [h, bs]
-        j = kb * bs + lax.broadcasted_iota(jnp.int32, (h, bs), 1)
-        s = jnp.where(j <= pos, s, _NEG)
-        m_prev, l_prev = m_ref[:], l_ref[:]                  # [h, 128]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - _lane_bcast(m_new, bs))
-        corr = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)              # [h, dh]
-        acc_ref[:] = acc_ref[:] * _lane_bcast(corr, dh) + pv
-
-    @pl.when(kb == mb - 1)
-    def _finish():
-        l = l_ref[:]
-        empty = l == 0.0           # can't happen live (pos >= 0 always
-        l_safe = jnp.where(empty, 1.0, l)  # covers the written row)
-        o_ref[0] = (acc_ref[:]
-                    / _lane_bcast(l_safe, dh)).astype(o_ref.dtype)
-
-
 def _paged_decode_pallas(q, k_pool, v_pool, block_table, pos,
                          scale: float):
-    B, h, dh = q.shape
-    bs = k_pool.shape[2]
-    mb = block_table.shape[1]
-    kv_spec = pl.BlockSpec(
-        (1, h, bs, dh), lambda b, kb, tbl, p: (tbl[b, kb], 0, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, mb),
-        in_specs=[
-            pl.BlockSpec((1, h, dh), lambda b, kb, tbl, p: (b, 0, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, h, dh),
-                               lambda b, kb, tbl, p: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((h, _LANES), jnp.float32),   # running denom
-            pltpu.VMEM((h, dh), jnp.float32),       # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, bs=bs, mb=mb, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h, dh), q.dtype),
-        compiler_params=_dimsem("parallel", "arbitrary"),
-        interpret=_interpret(),
-    )(block_table, pos, q, k_pool, v_pool)
+    """One query row per slot: the W == 1 case of the verify kernel.
+    A ``[h, dh] x [h, bs, dh]`` batched mat-vec has no free lhs
+    dimension and Mosaic refuses it; the verify kernel's
+    ``[h, W, dh]`` query block gives the product one."""
+    return _paged_verify_pallas(q[:, None], k_pool, v_pool,
+                                block_table, pos, scale)[:, 0]
 
 
 def paged_verify_attention_reference(q, k_pool, v_pool, block_table,
@@ -228,11 +152,12 @@ def _lane_bcast3(stat, width):
 def _verify_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, bs: int, mb: int, W: int,
                    scale: float):
-    """Grid (B, max_blocks): the decode kernel's streaming-softmax
-    recurrence with W query rows per slot instead of one — query row w
-    sits at position pos0 + w, so the in-block causal mask compares
-    each key's position against a per-row query position.  Blocks past
-    the DEEPEST query's context skip compute entirely."""
+    """Grid (B, max_blocks), block axis minor/arbitrary: per slot,
+    stream the table's K/V blocks through VMEM with the running softmax
+    state in scratch, W query rows per slot — query row w sits at
+    position pos0 + w, so the in-block causal mask compares each key's
+    position against a per-row query position.  Blocks past the
+    DEEPEST query's context skip compute entirely."""
     b, kb = pl.program_id(0), pl.program_id(1)
     h, dh = q_ref.shape[1], q_ref.shape[3]
     @pl.when(kb == 0)

@@ -44,8 +44,8 @@ from deeplearning4j_tpu.nn.conf.layers_transformer import (
 
 
 # Decode telemetry: tokens are THE serving unit for a causal decoder;
-# steps/s is the per-row tick rate the params-bandwidth roofline bounds
-# (GENERATION_r05.json).  A generate() that retraces (new shape key)
+# steps/s is the per-row tick rate the params-bandwidth roofline
+# bounds.  A generate() that retraces (new shape key)
 # shows up as a latency outlier in generation_seconds, not a separate
 # series — check _fn_cache hygiene when the histogram grows a tail.
 _GEN_REQS = telemetry.counter(

@@ -82,7 +82,7 @@ class Word2Vec:
     min_learning_rate: float = 1e-3
     seed: int = 42
     tokenizer_factory: object = None
-    # word2vec.c fidelity knobs (VERDICT r2 item 8):
+    # word2vec.c fidelity knobs (round-2 review item 8):
     negative_table_power: float = 0.75  # unigram^0.75 sampling; 0=uniform
     use_hierarchic_softmax: bool = False  # Huffman-tree HS instead of NS
     sampling: float = 0.0               # frequent-word subsample t (0=off)

@@ -1,4 +1,4 @@
-"""Remaining DL4J layer types (VERDICT round-1 item 8).
+"""Remaining DL4J layer types (round-1 review item 8).
 
 Parity targets (``org.deeplearning4j.nn.conf.layers.**``):
 ``PReLULayer``, ``ElementWiseMultiplicationLayer``,

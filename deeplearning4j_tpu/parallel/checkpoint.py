@@ -82,7 +82,12 @@ class ShardedCheckpointer:
             max_to_keep=keep_last,
             enable_async_checkpointing=async_save,
         )
-        self._mgr = ocp.CheckpointManager(self.directory, options=opts)
+        # the handler is declared up front: a manager that has not
+        # saved or restored yet otherwise cannot type the item, and
+        # item_metadata() (the sidecar-less elastic path) reads None
+        self._mgr = ocp.CheckpointManager(
+            self.directory, options=opts,
+            item_handlers=ocp.StandardCheckpointHandler())
 
     # -- world/layout sidecar -------------------------------------------
     # Orbax owns the array bytes; the few scalars elastic resume needs
@@ -197,12 +202,7 @@ class ShardedCheckpointer:
         if layout is not None:
             run = meta.get("pipe_run")
             return layout, (tuple(int(v) for v in run) if run else None)
-        try:
-            mtree = self._mgr.item_metadata(step)
-            saved_opt = (mtree.get("opt_state")
-                         if isinstance(mtree, dict) else None)
-        except Exception:               # pragma: no cover - defensive
-            return None, None
+        saved_opt = self._mgr.item_metadata(step).tree.get("opt_state")
         layout = _elastic.opt_layout(saved_opt)
         run = (_elastic.find_pipe_run(saved_opt)
                if layout == "pipe" else None)

@@ -2,10 +2,10 @@
 
 ``ParallelInference`` coalesces STATELESS forwards; a causal decoder is
 the stateful analogue — every decode tick streams the full parameter
-set from HBM regardless of how many rows ride along
-(GENERATION_r05.json measured 31.4% of the bf16 params-bandwidth ideal
-at a fixed batch of 8), so aggregate tokens/s scales almost free with
-batch until memory binds.  This module multiplexes many concurrent
+set from HBM regardless of how many rows ride along (how far a tick
+sits from that params-bandwidth ideal is not measured on today's
+code), so aggregate tokens/s scales almost free with batch until
+memory binds.  This module multiplexes many concurrent
 ``submit()`` callers onto ONE jitted decode tick over a fixed pool of
 ``n_slots`` slots — Orca-style continuous batching: requests join and
 leave mid-flight instead of waiting for the whole batch.
@@ -462,6 +462,30 @@ def _kill_slots(state, mask):
     return dict(state, remaining=jnp.where(mask, 0, state["remaining"]))
 
 
+def _refuse_cache_loaded_mesh_programs(devices) -> None:
+    """On the installed jax 0.9.0 / libtpu 0.0.34 a decode scan over a
+    two-chip slice that is LOADED from JAX's persistent compilation
+    cache halts the TPU ("The program continuator has halted
+    unexpectedly"); the same program freshly compiled — cache off, or
+    the run that writes the entry — serves correctly (PERF.md, PR 21:
+    every warm four-chip run failed, every cold or cache-off one
+    passed; single-device programs and the whole-host train step load
+    fine).  JAX decides once per process whether it uses that cache,
+    so a replica cannot opt its own programs out: a multi-chip replica
+    refuses to start in a process that has the cache on."""
+    devices = list(devices)
+    if (len(devices) > 1 and devices[0].platform == "tpu"
+            and jax.config.jax_enable_compilation_cache
+            and jax.config.jax_compilation_cache_dir):
+        raise RuntimeError(
+            "a GenerationServer over more than one TPU chip cannot run "
+            "with JAX's persistent compilation cache on (a decode "
+            "program loaded from it halts the chip on jax 0.9.0 / "
+            "libtpu 0.0.34): start the process with "
+            "jax.config.update('jax_enable_compilation_cache', False) "
+            f"(cache dir: {jax.config.jax_compilation_cache_dir!r})")
+
+
 class _Pending:
     """One submitted request.  ``result()`` blocks the caller; the
     scheduler thread fills ``_result``/``_error`` and sets the event.
@@ -729,6 +753,7 @@ class GenerationServer:
         # and the constraints are no-ops on a 1-extent mesh.
         self._shard = None
         if devices is not None:
+            _refuse_cache_loaded_mesh_programs(devices)
             ctx = TpShardCtx(serving_mesh(devices, tp))
             h = gen.blocks[0].n_heads
             if h % ctx.tp:

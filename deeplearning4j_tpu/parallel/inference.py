@@ -22,7 +22,7 @@ import numpy as np
 
 from deeplearning4j_tpu import telemetry
 
-# Serving telemetry (VERDICT r5 rec 10: saturation visibility).  All
+# Serving telemetry (round-5 review rec 10: saturation visibility).  All
 # ParallelInference instances in a process share these series — the
 # scrape answers "is THIS process saturated", which is the fleet
 # question; per-instance breakdown would need an instance label and a

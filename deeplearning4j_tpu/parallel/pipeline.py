@@ -40,67 +40,10 @@ _PIPE_STEPS = telemetry.counter(
     labelnames=("worker",))
 
 
-#: first jax release exposing top-level ``jax.shard_map`` with the
-#: ``axis_names`` (manual-axes) parameter — the API partial-auto
-#: sharding (TP auto-partitioned INSIDE pipeline stages) requires
-_SHARD_MAP_MIN_JAX = "0.6.0"
-
-
-class ShardMapPartialAutoError(NotImplementedError):
-    """Raised when a mesh needs PARTIAL-AUTO ``shard_map`` (some axes
-    manual — pipe/data — while others — 'model'/'sequence' — stay
-    GSPMD-partitioned inside the manual region) on a jax release
-    without top-level ``jax.shard_map``.
-
-    The legacy ``jax.experimental.shard_map`` fallback cannot express
-    this: its ``auto=`` form CHECK-fails in the matching jaxlib's
-    compiler (an aborted process, not a Python error), so the only
-    safe behavior is a loud refusal.  Fully-manual meshes (pure
-    DP x PP, no TP inside stages) work on either API; composing TP
-    inside pipeline stages needs jax >= ``_SHARD_MAP_MIN_JAX``.
-
-    Subclasses ``NotImplementedError`` so pre-existing callers (and
-    test skips) that caught the untyped error keep working.  Carries
-    ``auto_axes`` — the mesh axes the caller wanted auto-partitioned."""
-
-    def __init__(self, auto_axes):
-        self.auto_axes = tuple(sorted(auto_axes))
-        super().__init__(
-            f"this jax release ({jax.__version__}) has no "
-            f"jax.shard_map; the legacy fallback cannot leave axes "
-            f"{list(self.auto_axes)} auto-partitioned inside the "
-            f"manual region (TP inside pipeline stages needs jax >= "
-            f"{_SHARD_MAP_MIN_JAX})")
-
-
-def _shard_map(f, mesh: Mesh, in_specs, out_specs, manual_axes):
-    """Version shim: ``jax.shard_map(..., axis_names=manual)`` on new
-    jax; on older releases fall back to
-    ``jax.experimental.shard_map.shard_map`` where the knob is inverted
-    (``auto`` = the NON-manual axes) and replication checking cannot
-    run with auto axes present.  Partial-auto on old jax raises the
-    typed :class:`ShardMapPartialAutoError` (refusing loudly beats the
-    legacy path's compiler abort)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=frozenset(manual_axes))
-    from jax.experimental.shard_map import shard_map as _legacy
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    if auto:
-        raise ShardMapPartialAutoError(auto)
-    return _legacy(f, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False, auto=auto)
-
-
 def _pipe_varying_zeros(like, axis):
-    """Zeros with the scan-carry type of a post-``ppermute`` value: on
-    new jax the carry must be pre-cast to pipe-varying (``lax.pcast``);
-    older releases have no varying-type tracking."""
-    z = jnp.zeros_like(like)
-    if hasattr(lax, "pcast"):
-        z = lax.pcast(z, (axis,), to="varying")
-    return z
+    """Zeros with the scan-carry type of a post-``ppermute`` value:
+    the carry must be pre-cast to pipe-varying."""
+    return lax.pcast(jnp.zeros_like(like), (axis,), to="varying")
 
 
 def stack_block_params(block_conf, n_blocks: int, key,
@@ -133,7 +76,7 @@ def gpipe_apply(mesh: Mesh, stacked_params, x, block_apply: Callable,
     (S-1)/(S-1+n_micro).  Returns [B, ...] with the pipeline semantics
     IDENTICAL to applying the blocks sequentially.
 
-    ``data_axis`` composes DP x PP (VERDICT r3 weak 4): x arrives
+    ``data_axis`` composes DP x PP (round-3 review weak 4): x arrives
     batch-sharded over that axis, every data group runs its own
     pipeline over its local microbatches, and gradient all-reduce over
     'data' falls out of autodiff through shard_map."""
@@ -161,8 +104,7 @@ def gpipe_apply(mesh: Mesh, stacked_params, x, block_apply: Callable,
         # stage index arrives as pipe-sharded DATA rather than
         # lax.axis_index: axis_index lowers to a PartitionId
         # instruction that GSPMD refuses to partition when non-manual
-        # (auto) axes remain — e.g. the DP x TP x PP composition on
-        # jax releases using the legacy shard_map fallback
+        # (auto) axes remain — e.g. the DP x TP x PP composition
         idx = stage_id[0]
         # the scan carry becomes pipe-varying after the first ppermute;
         # pre-cast the zeros so the carry type is stable across ticks
@@ -194,12 +136,12 @@ def gpipe_apply(mesh: Mesh, stacked_params, x, block_apply: Callable,
     # ('model', 'sequence') stays auto-partitioned, so GSPMD places
     # tensor-parallel collectives INSIDE the stage body from the
     # operands' shardings — this is what lets DP x TP x PP compose
-    # through one shard_map (VERDICT r4 item 7)
+    # through one shard_map (round-4 review item 7)
     manual = {axis} | ({data_axis} if data_axis else set())
-    out = _shard_map(
-        worker, mesh,
+    out = jax.shard_map(
+        worker, mesh=mesh,
         in_specs=(P(axis), x_spec, P(axis)), out_specs=x_spec,
-        manual_axes=manual)(stacked_params, x, jnp.arange(S))
+        axis_names=frozenset(manual))(stacked_params, x, jnp.arange(S))
     return out
 
 
@@ -207,7 +149,7 @@ class PipelinedTransformerLM:
     """Pipelined model trained through a normal fit path: replicated
     embedding + N pipelined ``TransformerEncoderBlock``s + replicated
     head, one jitted step over the mesh.  Composes DP x PP when the
-    mesh carries a 'data' axis (VERDICT r3 weak 4: a trainer feature,
+    mesh carries a 'data' axis (round-3 review weak 4: a trainer feature,
     not a demo) — batch sharded over 'data', block stack sharded over
     the pipe axis, gradient all-reduce by GSPMD/shard_map autodiff."""
 

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _block_attend(q, k, v, mask_k, m, l, o):
@@ -92,14 +91,14 @@ def ring_self_attention(mesh: Mesh, q, k, v,
     if mask is None:
         def shard_fn(q_, k_, v_):
             return fn(q_, k_, v_, None)
-        mapped = shard_map(shard_fn, mesh=mesh,
-                           in_specs=in_specs[:3], out_specs=qkv_spec)
+        mapped = jax.shard_map(shard_fn, mesh=mesh,
+                               in_specs=in_specs[:3], out_specs=qkv_spec)
         return mapped(q, k, v)
 
     def shard_fn(q_, k_, v_, mask_):
         return fn(q_, k_, v_, mask_)
-    mapped = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=qkv_spec)
+    mapped = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=qkv_spec)
     return mapped(q, k, v, mask)
 
 

@@ -1,8 +1,7 @@
 """Speculative multi-token decode — draft construction + acceptance.
 
 The decode tick is memory-bound: every single-token dispatch streams
-the full parameter set from HBM for ONE token of math per slot
-(GENERATION_r05.json measured ~31% of the params-bandwidth ideal).
+the full parameter set from HBM for ONE token of math per slot.
 Speculative sampling (Leviathan et al. / Chen et al., PAPERS.md)
 converts K cheap DRAFT steps plus ONE batched target-model
 verification into up to K+1 committed tokens per expensive target

@@ -24,6 +24,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu import telemetry
+from deeplearning4j_tpu.kernels import trace_mesh
 from deeplearning4j_tpu.optimize.fit_loop import run_fit
 from deeplearning4j_tpu.parallel.mesh import MeshConfig
 
@@ -542,8 +543,12 @@ class ShardedTrainer:
         prof = telemetry.get_profiler()
         with prof.measure("optimizer_step",
                           every=_PROFILE_STEP_EVERY) as pm:
+            # the attention router learns the mesh at TRACE time: a
+            # Mosaic kernel cannot be partitioned by GSPMD and has to
+            # map itself over the batch/head axes
             with tracer.span("train/sharded_step",
-                             mesh=str(dict(self.mesh.shape))), self.mesh:
+                             mesh=str(dict(self.mesh.shape))), \
+                    self.mesh, trace_mesh(self.mesh):
                 (m.params_tree, m.opt_state, m.state_tree, loss) = \
                     self.solver.step(
                         m.params_tree, m.opt_state, m.state_tree,

@@ -1,7 +1,7 @@
 """Unified telemetry: process-wide metrics registry + span tracing.
 
 The production-observability subsystem the training-only ``ui`` stack
-lacked (VERDICT r5 rec 10: serving-saturation visibility).  Three
+lacked (round-5 review rec 10: serving-saturation visibility).  Three
 pieces, all stdlib-only:
 
 * ``registry``  — thread-safe Counter/Gauge/Histogram families with

@@ -15,9 +15,6 @@ from typing import Optional
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
 from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
 
-# The MFU denominator default — TPU v5e bf16 peak, matching bench.py.
-V5E_PEAK_FLOPS = 197e12
-
 
 class TelemetryListener(TrainingListener):
     """Stream per-iteration training telemetry into a registry.
@@ -26,6 +23,9 @@ class TelemetryListener(TrainingListener):
     ``zoo.Bert.flops_per_token_train() * seq_len``) turns measured
     examples/sec into the ``mfu`` gauge against ``peak_flops``; without
     it the gauge is left untouched (never a made-up number).
+    ``peak_flops`` defaults to the attached device's entry in
+    ``runtime.backend.PEAK_BF16_FLOPS`` and raises on a device kind the
+    table does not hold — pass it explicitly for an MFU off the chip.
 
     ``storage`` (a ``ui.StatsStorage``) receives one registry snapshot
     record per epoch (``{"type": "telemetry_snapshot", ...}``) — the
@@ -33,14 +33,18 @@ class TelemetryListener(TrainingListener):
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  storage=None, flops_per_example: Optional[float] = None,
-                 peak_flops: float = V5E_PEAK_FLOPS):
+                 peak_flops: Optional[float] = None):
         if registry is None:
             from deeplearning4j_tpu import telemetry
             registry = telemetry.get_registry()
+        if flops_per_example and peak_flops is None:
+            from deeplearning4j_tpu.runtime.backend import (
+                peak_flops as device_peak_flops)
+            peak_flops = device_peak_flops()
         self.registry = registry
         self.storage = storage
         self.flops_per_example = flops_per_example
-        self.peak_flops = float(peak_flops)
+        self.peak_flops = peak_flops
         self._loss = registry.gauge(
             "train_loss", "last training loss (host-read)")
         self._ex_per_sec = registry.gauge(

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Real-data input-pipeline proof (VERDICT r2 item 5 / SURVEY hard
+"""Real-data input-pipeline proof (round-2 review item 5 / SURVEY hard
 part (c)): write an ImageNet-shaped on-disk JPEG tree, measure the
 host pipeline (ImageRecordReader -> RecordReaderDataSetIterator)
 throughput in isolation, then run the full path
@@ -88,6 +88,8 @@ def bench_end_to_end():
 
 def main():
     import jax
+    from deeplearning4j_tpu.runtime.backend import enable_compile_cache
+    enable_compile_cache()
     make_tree()
     pipe_ips = bench_pipeline_only()
     e2e_ips, loss = bench_end_to_end()

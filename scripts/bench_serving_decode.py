@@ -23,6 +23,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def main():
+    from deeplearning4j_tpu.runtime.backend import enable_compile_cache
+    enable_compile_cache()
     smoke = "--smoke" in sys.argv[1:]
     if not smoke:
         import jax

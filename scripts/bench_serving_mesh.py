@@ -32,9 +32,13 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 
 def main():
+    # multi-chip replicas: a decode program loaded from the persistent
+    # compile cache halts the chip on this installation (PERF.md, PR
+    # 21), and GenerationServer refuses to start with the cache on
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
     smoke = "--smoke" in sys.argv[1:]
     if not smoke:
-        import jax
         assert jax.default_backend() == "tpu", \
             "needs the real chips (or pass --smoke for the CPU config)"
     from bench import bench_serving_mesh

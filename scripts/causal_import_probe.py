@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Imported-causal-graph fine-tune ON SILICON (VERDICT r4 item 6's
+"""Imported-causal-graph fine-tune ON SILICON (round-4 review item 6's
 'done' bar): import the toy frozen GPT (t=512, additive tril mask),
 fuse to causal fused_attention, fine-tune with the flash kernel's
 CAUSAL path route-probe-verified, record CAUSAL_IMPORT_r05.json."""

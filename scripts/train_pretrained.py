@@ -88,7 +88,7 @@ def train_char_rnn(out_dir):
 
 def train_simple_cnn(out_dir):
     """SimpleCNN on (synthetic, see data/builtin.py) CIFAR-10 — the
-    conv-net-at-CIFAR-scale registry entry (VERDICT r3 item 9)."""
+    conv-net-at-CIFAR-scale registry entry (round-3 review item 9)."""
     from deeplearning4j_tpu.data.builtin import Cifar10DataSetIterator
     from deeplearning4j_tpu.optimize.updaters import Adam
     from deeplearning4j_tpu.zoo import SimpleCNN, save_pretrained
@@ -109,7 +109,7 @@ def train_simple_cnn(out_dir):
 
 def train_gpt_char(out_dir):
     """Small causal char-LM via zoo.Gpt + KV-cache sampling — the
-    transformer registry entry (VERDICT r3 item 9)."""
+    transformer registry entry (round-3 review item 9)."""
     import json
 
     from deeplearning4j_tpu.data.dataset import DataSet

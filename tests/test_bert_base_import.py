@@ -1,4 +1,4 @@
-"""Import at REAL scale (VERDICT r2 item 3): a BERT-base-SIZED
+"""Import at REAL scale (round-2 review item 3): a BERT-base-SIZED
 (12x768, 30522 vocab, ~110M params, 438 MB frozen pb) random-init
 graph must import, match TF goldens elementwise, rewrite to fused
 attention, and take a fine-tune step.
@@ -9,7 +9,7 @@ too large to commit (the ``dl4j-test-resources`` external-artifact
 pattern).  Generation lives in ``utils/bert_fixture.py``, shared with
 ``bench.py``'s imported-graph fine-tune benchmark.
 
-t=512 (VERDICT r3 item 1): >= kernels.flash_attention._FLASH_MIN_T,
+t=512 (round-3 review item 1): >= kernels.flash_attention._FLASH_MIN_T,
 so the imported fused path exercises the Pallas flash route — the
 r2-era t=64 fixture only ever hit the XLA fallback."""
 import numpy as np
@@ -50,7 +50,7 @@ def test_bert_base_fused_attention_parity(bert_base):
     fa.reset_route_log()
     out = sd.output({"i": g["ids"], "m": g["mask"], "t": g["tt"]},
                     ["Identity"])
-    # route-taken probe (VERDICT r3): at t=512 every one of the 12
+    # route-taken probe (round-3 review): at t=512 every one of the 12
     # imported sites must TRACE through the Pallas flash kernel, not
     # the XLA fallback — _flash_applicable's opinion is not trusted.
     routes = fa.route_log()

@@ -1,4 +1,4 @@
-"""Imported causal masks route to the causal flash kernel (VERDICT r4
+"""Imported causal masks route to the causal flash kernel (round-4 review
 item 6): a frozen GPT-style graph whose attention adds a [t, t]
 triangular -1e9 mask constant must fuse to ``fused_attention(causal=
 True)`` with the mask operand DROPPED — reaching the flash kernel's

@@ -1,4 +1,4 @@
-"""Control flow in the graph IR (VERDICT r2 item 4).
+"""Control flow in the graph IR (round-2 review item 4).
 
 ``while_loop``/``cond`` IR nodes carry sub-SameDiff graphs in their
 attrs and lower to ``jax.lax.while_loop``/``jax.lax.cond`` — the
@@ -181,7 +181,7 @@ def test_tf_control_flow_roundtrip(tf_loop_graph, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Round-4 (VERDICT r3 item 5): trainable bounded loops via lax.scan
+# Round-4 (round-3 review item 5): trainable bounded loops via lax.scan
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def tf_trainable_loop_graph():

@@ -285,7 +285,7 @@ def test_cifar10_synthetic_is_learnable():
 
 
 def test_cifar_real_binary_format_parses(tmp_path, monkeypatch):
-    """The real-file CIFAR branch (VERDICT r3 weak 7: dead code in CI)
+    """The real-file CIFAR branch (round-3 review weak 7: dead code in CI)
     against a self-written fixture in the exact CIFAR-10 binary layout:
     per record 1 label byte + 3072 CHW pixel bytes."""
     rng = np.random.default_rng(0)

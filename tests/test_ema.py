@@ -1,6 +1,6 @@
 """Ema wrapper updater — the model-averaging semantic
 (ParameterAveragingTrainingMaster analogue) as an optimizer-state
-transform usable from both trainers (VERDICT r2 item 9)."""
+transform usable from both trainers (round-2 review item 9)."""
 import jax.numpy as jnp
 import numpy as np
 

@@ -1,4 +1,4 @@
-"""The examples/ surface (VERDICT r3 item 4): every BASELINE-config
+"""The examples/ surface (round-3 review item 4): every BASELINE-config
 script must actually run in --smoke mode — this is dl4j-examples'
 CI-run-the-examples pattern."""
 import os

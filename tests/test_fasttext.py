@@ -1,4 +1,4 @@
-"""FastText subword embeddings (VERDICT r2 missing item 7): n-gram
+"""FastText subword embeddings (round-2 review missing item 7): n-gram
 hashing, subword-composed vectors, OOV handling, training quality."""
 import numpy as np
 import pytest

@@ -283,6 +283,52 @@ def test_attention_bthd_routes_and_falls_back():
                                atol=2e-5)
 
 
+def test_attention_maps_flash_over_a_declared_mesh():
+    """GSPMD cannot partition a Mosaic kernel (the chip's compiler
+    refuses the program; interpret mode never notices), so under a
+    DECLARED multi-device mesh attention() runs flash per (batch,
+    head) shard through shard_map: values and grads equal the plain
+    call.  A mesh axis the call cannot map over falls back to XLA,
+    counted by the long-t alarm."""
+    from jax.sharding import Mesh
+    from deeplearning4j_tpu import kernels, telemetry
+    rng = np.random.default_rng(2)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 512, 2, 8)), jnp.float32)
+               for _ in range(3))
+    bias = jnp.asarray(
+        np.where(rng.random((2, 512)) < 0.2, -1e9, 0.0), jnp.float32)
+
+    def loss(q, k, v):
+        out = kernels.attention(q, k, v, bias=bias, causal=True,
+                                layout="bthd")
+        return jnp.sum(out * out), out
+
+    (_, want), want_g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                           has_aux=True)(q, k, v)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                ("data", "model"))
+    kernels.reset_route_log()
+    with mesh, kernels.trace_mesh(mesh):
+        (_, got), got_g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    assert kernels.route_log() == (("flash", 512, 8),)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6)
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1e-5)
+
+    alarm = telemetry.get_registry().get(
+        "flash_fallback_above_threshold_total")
+    before = alarm.value
+    odd = Mesh(np.array(jax.devices()[:2]), ("sequence",))
+    kernels.reset_route_log()
+    with kernels.trace_mesh(odd):
+        kernels.attention(q, k, v, causal=True, layout="bthd")
+    assert kernels.route_log() == (("xla", 512, 8),)
+    assert alarm.value == before + 1
+
+
 # -- paged decode attention (PR 7) -------------------------------------
 def _paged_fixture(seed=0, B=3, h=4, dh=8, bs=4, mb=4, nb=9):
     rng = np.random.default_rng(seed)

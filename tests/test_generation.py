@@ -1,4 +1,4 @@
-"""KV-cache incremental decoding (VERDICT r3 item 2): the transformer
+"""KV-cache incremental decoding (round-3 review item 2): the transformer
 ``rnnTimeStep`` analogue.  Greedy decode through the cached one-step
 path must EXACTLY match greedy decode by full-prefix recompute."""
 import numpy as np

@@ -1,4 +1,4 @@
-"""Real-data image pipeline end-to-end (VERDICT r2 item 5): on-disk
+"""Real-data image pipeline end-to-end (round-2 review item 5): on-disk
 JPEG tree -> ImageRecordReader -> AsyncDataSetIterator ->
 ComputationGraph.fit, plus the process-pool decode path.  The full
 ImageNet-shaped throughput artifact is PIPELINE_r03.json

@@ -1,5 +1,5 @@
 """Gradient checks + shape inference for the round-2 layer additions
-(VERDICT item 8): PReLU, ElementWiseMultiplication, LocallyConnected1D/2D,
+(review item 8): PReLU, ElementWiseMultiplication, LocallyConnected1D/2D,
 SelfAttention/LearnedSelfAttention, Convolution3D/Subsampling3D,
 CenterLossOutputLayer, VariationalAutoencoder.
 

@@ -1,5 +1,5 @@
 """Observability tests: stats stream, storage, report, NaN debug mode,
-profiler hook (VERDICT item 9 — one flag turns on a per-iteration jsonl
+profiler hook (review item 9 — one flag turns on a per-iteration jsonl
 stream + trace dump)."""
 import json
 import os

@@ -1,4 +1,4 @@
-"""ONNX import (VERDICT r2 missing item: ``samediff-import-onnx``).
+"""ONNX import (round-2 review missing item: ``samediff-import-onnx``).
 
 No ``onnx`` package or onnxruntime exists in this image, so:
 - the wire codec round-trips are self-tested (encode -> decode),

@@ -1,4 +1,4 @@
-"""ONNX import of REAL exported models (VERDICT r3 item 3): files
+"""ONNX import of REAL exported models (round-3 review item 3): files
 produced by ``torch.onnx.export`` itself — not hand-built graphs — must
 import through the in-repo wire codec, match the torch forward
 elementwise, and take a fine-tune step.

@@ -177,7 +177,7 @@ def test_dp_conv_dag_matches_single_device():
 
 def test_tp_excludes_conv_and_recurrent_kernels():
     """Tensor-parallel heuristic shards plain Dense kernels only: conv
-    HWIO and LSTM fused-gate kernels must replicate (VERDICT weak-5)."""
+    HWIO and LSTM fused-gate kernels must replicate (review weak-5)."""
     from deeplearning4j_tpu.nn.conf.inputs import InputType
     from deeplearning4j_tpu.nn.conf.layers_recurrent import (
         LSTM, RnnOutputLayer)

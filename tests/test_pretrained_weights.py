@@ -1,4 +1,4 @@
-"""In-repo published pretrained weights (VERDICT r2 item 7): the
+"""In-repo published pretrained weights (round-2 review item 7): the
 ``initPretrained`` parity path exercised against REAL weight files
 (``zoo/weights/``, trained by ``scripts/train_pretrained.py``)."""
 import json

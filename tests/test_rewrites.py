@@ -1,7 +1,7 @@
 """Attention-fusion rewrite pass: pattern matching, parity, safety.
 
 The rewrite connects imported graphs to the Pallas flash kernel
-(VERDICT round-2 item 1a): matmul→scale→bias→softmax→matmul chains
+(round-2 review item 1a): matmul→scale→bias→softmax→matmul chains
 become one ``fused_attention`` node.
 """
 import numpy as np
@@ -165,7 +165,7 @@ def test_bert_import_fused_finetune_step():
 
 # ---------------------------------------------------------------------------
 # Round-4 canonicalization passes: qkv fusion, layer-norm, gelu
-# (VERDICT r3: imported graphs move +23% more HBM than the zoo step;
+# (round-3 review: imported graphs move +23% more HBM than the zoo step;
 # these collapse the frozen-TF decompositions)
 # ---------------------------------------------------------------------------
 
@@ -306,7 +306,7 @@ def test_fuse_gelu_rejects_wrong_sign():
 
 
 # ---------------------------------------------------------------------------
-# Round-5: Tensordot flatten-reshape folding (VERDICT r4 item 4 — the
+# Round-5: Tensordot flatten-reshape folding (round-4 review item 4 — the
 # imported train step carried +293 stablehlo reshapes vs the zoo model)
 # ---------------------------------------------------------------------------
 

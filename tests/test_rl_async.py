@@ -1,4 +1,4 @@
-"""Async RL learners (VERDICT r2 item 10): A3C and async n-step
+"""Async RL learners (round-2 review item 10): A3C and async n-step
 Q-learning with thread-parallel actors over a shared jitted learner —
 the rl4j ``learning.async`` family."""
 import numpy as np

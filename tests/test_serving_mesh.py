@@ -230,3 +230,32 @@ def test_mixed_fleet_parity_and_gauge(net, offline):
         body = telemetry.get_registry().render_prometheus()
     assert 'fleet_replica_devices{replica="1"} 2.0' in body
     assert 'fleet_replica_devices{replica="2"} 1.0' in body
+
+
+def test_multi_chip_replica_refuses_the_persistent_compile_cache(
+        tmp_path):
+    """PR 21, on the chip: a tp=2 decode program LOADED from JAX's
+    persistent compilation cache halts the TPU (freshly compiled it
+    serves).  JAX decides once per process whether it uses the cache,
+    so a multi-chip replica refuses to start beside it — a named error
+    at construction, not a halted chip on the second run.  One chip,
+    the cache off, or another platform all pass."""
+    import types
+
+    from deeplearning4j_tpu.parallel.generation_server import (
+        _refuse_cache_loaded_mesh_programs as refuse)
+    chip = types.SimpleNamespace(platform="tpu")
+    refuse([chip, chip])                      # no cache directory set
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    try:
+        with pytest.raises(RuntimeError, match="persistent compilation"):
+            refuse([chip, chip])
+        refuse([chip])                        # one chip loads fine
+        refuse(jax.devices()[:2])             # not a TPU
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            refuse([chip, chip])              # the cache switched off
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)

@@ -221,6 +221,24 @@ def _fit_with_listener(storage=None):
     return m
 
 
+def test_mfu_peak_comes_from_the_device_table_or_the_caller():
+    """One peaks table keyed by device_kind: a kind it does not hold
+    (this CPU) raises where a peak is needed and the caller passed
+    none — never a default chip's number."""
+    from deeplearning4j_tpu.runtime.backend import Backend, peak_flops
+    assert peak_flops("TPU v5 lite") == 197e12
+    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+        peak_flops()
+    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+        telemetry.TelemetryListener(flops_per_example=1000.0)
+    # no MFU asked for, or the caller's own peak: no table lookup
+    assert telemetry.TelemetryListener().peak_flops is None
+    assert telemetry.TelemetryListener(
+        flops_per_example=1000.0, peak_flops=1e12).peak_flops == 1e12
+    assert Backend(platform="tpu", n_devices=1).is_tpu
+    assert not Backend(platform="cpu", n_devices=1).is_tpu
+
+
 def test_fit_loop_and_listener_metrics():
     reg = telemetry.get_registry()
     iters = reg.get("train_iterations_total")

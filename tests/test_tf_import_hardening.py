@@ -1,4 +1,4 @@
-"""Importer hardening (VERDICT r2 item 3): trainable filter, SavedModel
+"""Importer hardening (round-2 review item 3): trainable filter, SavedModel
 directories, NCHW layout insertion, FusedBatchNorm aux-output refusal."""
 import os
 

@@ -1,4 +1,4 @@
-"""The config-4 quality pipeline at CPU scale (VERDICT r4 item 3):
+"""The config-4 quality pipeline at CPU scale (round-4 review item 3):
 hand-written sentiment corpus -> WordPiece -> BertIterator ->
 imported-frozen-BERT fine-tune -> held-out accuracy above chance.
 The TPU artifact (FINETUNE_r05.json, scripts/bench_imported_finetune)

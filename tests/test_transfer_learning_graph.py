@@ -1,4 +1,4 @@
-"""Graph-side TransferLearning (VERDICT r4 item 5): the
+"""Graph-side TransferLearning (round-4 review item 5): the
 ``TransferLearning.GraphBuilder`` equivalent on ComputationGraph —
 vertex-addressed freeze with ancestor closure, ``n_out_replace`` on a
 DAG layer, remove/add vertex + new head, fine-tune config — plus

@@ -1,4 +1,4 @@
-"""Word2Vec fidelity (VERDICT r2 item 8): unigram^0.75 negative
+"""Word2Vec fidelity (round-2 review item 8): unigram^0.75 negative
 sampling, Huffman hierarchical softmax, frequent-word subsampling, and
 an embedding-quality assertion on a corpus with known co-occurrence
 structure."""
