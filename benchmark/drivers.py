@@ -1,0 +1,525 @@
+"""The two window loops (``train``, ``serve_closed``), the seeded traffic
+generators and the weights' way into the program.  From the program a
+driver takes only the system under test (a zoo model's ``fit``, a
+``GenerationServer``) and its counters; everything that decides a
+number -- the clock, the traffic, the arithmetic -- is here.
+"""
+from __future__ import annotations
+
+import gc
+import importlib
+import itertools
+import time
+
+import numpy as np
+
+from benchmark import costs, reference
+
+
+def shape_of(config: dict) -> dict:
+    """The sizes the reference and the cost functions need, from the
+    configuration's constructor arguments."""
+    c = config["ctor"]
+    return {"d": c["d_model"], "layers": c["n_layers"], "heads": c["n_heads"],
+            "ff": c["d_ff"], "vocab": c["vocab_size"], "max_len": c["max_len"],
+            "n_out": c.get("n_classes", c["vocab_size"])}
+
+
+def build_net(config: dict):
+    """The zoo model's own configuration, initialised as ``init_graph()``
+    does -- after ``layer_overrides`` ({layer class: {field: value}})
+    set what the configuration states and the zoo class has no argument
+    for (the blocks' GELU: see PERF.md, Open questions)."""
+    from deeplearning4j_tpu.models.multi_layer_network import MultiLayerNetwork
+    module, cls = config["zoo_class"].rsplit(".", 1)
+    ctor = dict(config["ctor"])
+    if "updater" in config:          # {"type": "Adam", "learning_rate": ...}
+        from deeplearning4j_tpu.optimize.updaters import updater_from_dict
+        ctor["updater"] = updater_from_dict(config["updater"])
+    conf = getattr(importlib.import_module(module), cls)(**ctor).conf()
+    for ly in conf.layers:
+        for field, value in config.get("layer_overrides", {}).get(
+                type(ly).__name__, {}).items():
+            if not hasattr(ly, field):
+                raise AttributeError(f"{type(ly).__name__} has no {field!r}")
+            setattr(ly, field, value)
+    return MultiLayerNetwork(conf).init()
+
+
+def layer_kinds(net) -> tuple:
+    """'emb' | 'block' | 'none' | 'head' for each layer of the net."""
+    kinds = []
+    for i, ly in enumerate(net.layers):
+        if i == 0:
+            kinds.append("emb")
+        elif i == len(net.layers) - 1:
+            kinds.append("head")
+        else:
+            kinds.append("block" if ly.has_params() else "none")
+    return tuple(kinds)
+
+
+def _to_program(w, kinds):
+    tree, b = {}, 0
+    for i, kind in enumerate(kinds):
+        if kind == "block":
+            tree[f"layer_{i}"] = {k: v[b] for k, v in w["blocks"].items()}
+            b += 1
+        else:
+            tree[f"layer_{i}"] = dict(w[kind]) if kind != "none" else {}
+    return tree
+
+
+def _from_program(tree, kinds):
+    import jax.numpy as jnp
+    blocks = [tree[f"layer_{i}"] for i, k in enumerate(kinds) if k == "block"]
+    return {"emb": tree["layer_0"],
+            "blocks": {k: jnp.stack([b[k] for b in blocks]) for k in blocks[0]},
+            "head": tree[f"layer_{len(kinds) - 1}"]}
+
+
+def seed_weights(net, shape: dict, seed: int) -> None:
+    """The seed's weights, made on the device in one jitted call, in
+    the program's own layout and dtype (float32 master weights)."""
+    import jax
+    kinds = layer_kinds(net)
+    make = jax.jit(lambda key: _to_program(
+        reference.weights_from_key(shape, key), kinds))
+    net.params_tree = make(reference.seed_key(seed))
+    # a fresh optimizer too (the solver, once built, only builds it lazily once)
+    net.opt_state = (net._solver.init_opt_state(net.params_tree)
+                     if net._solver is not None else None)
+    net.iteration_count = 0
+
+
+def registry_snapshot() -> dict:
+    from deeplearning4j_tpu import telemetry
+    return telemetry.get_registry().snapshot()
+
+
+# ---------------------------------------------------------------------------
+# train: one call of net.fit(iterator) is the window
+# ---------------------------------------------------------------------------
+def train_batches(traffic: dict, vocab: int, seed: int) -> list:
+    """A ring of distinct host batches from the seed: token ids without
+    padding, one-hot labels.  ``labels: "one_class"`` gives every row
+    of the run the class the seed draws, so the rows' error signals add
+    up instead of cancelling (PERF.md, the look at the seeds that read
+    ten times the others); ``"mixed"`` draws a class per row."""
+    rng = np.random.default_rng(seed)
+    b, t, c = traffic["batch"], traffic["seq"], traffic["classes"]
+    one = int(rng.integers(0, c)) if traffic.get("labels") == "one_class" else None
+    return [(rng.integers(0, vocab, (b, t)).astype(np.int32),
+             np.eye(c, dtype=np.float32)[
+                 rng.integers(0, c, b) if one is None else np.full(b, one)])
+            for _ in range(traffic["ring"])]
+
+
+class RingIterator:
+    """Hands out the ring's batches from ``start`` on: ``count`` of
+    them, or until the host clock passes ``deadline``."""
+
+    def __init__(self, ring, start, count=None, deadline=None):
+        from deeplearning4j_tpu.data.dataset import DataSet
+        self.ring = [DataSet(x, y) for x, y in ring]
+        self.start, self.count, self.deadline = start, count, deadline
+        self.pre_processor = None
+
+    def __iter__(self):
+        i = self.start
+        while (i - self.start < self.count if self.count is not None
+               else time.perf_counter() < self.deadline):
+            yield self.ring[i % len(self.ring)]
+            i += 1
+
+    def reset(self):
+        pass
+
+    def batch_size(self):
+        return self.ring[0].num_examples()
+
+    def total_outcomes(self):
+        return None
+
+
+class LossTap:
+    """A listener as a logging job has one: keeps every step's loss (a
+    device scalar) and reads back the one of ``lag`` steps ago, so the
+    host never runs more than ``lag`` steps ahead of the device.  In a
+    traced run it also opens and closes the traced sub-window, each at
+    a step whose loss it has waited for."""
+
+    def __init__(self, lag: int = 2):
+        self.lag, self.losses = lag, []
+        self.on_step = None            # called with the step count
+        self._span = None              # host span: this step's end to the next's
+
+    def iteration_done(self, model, iteration, epoch, score):
+        import jax
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        self.losses.append(score)
+        if len(self.losses) > self.lag:
+            with jax.profiler.TraceAnnotation("bench/loss_readback"):
+                self.losses[-1 - self.lag].block_until_ready()
+        if self.on_step is not None:
+            self.on_step(len(self.losses))
+        self._span = jax.profiler.TraceAnnotation("bench/next_batch_and_dispatch")
+        self._span.__enter__()
+
+    def on_epoch_start(self, model, epoch):
+        pass
+
+    def on_epoch_end(self, model, epoch):
+        pass
+
+
+def first_steps(net, tap, ring, adam: dict, shape: dict, seed: int,
+                steps: int = 3) -> dict:
+    """Drive the net from the seed's weights through its first steps by
+    the window's own call and feed; read what the reference follows:
+    each loss, the first gradient's norms as Adam got them (its first
+    moment after one step is (1 - beta1) * g) and the norms of the
+    parameters' change after the last step."""
+    import jax
+    kinds = layer_kinds(net)
+    norms = jax.jit(lambda tree: reference.leaf_norms(
+        _from_program(tree, kinds)))
+    net.fit(RingIterator(ring, 0, count=1))
+    grad_norms = {k: np.asarray(v) / (1.0 - adam["beta1"])
+                  for k, v in jax.device_get(norms(net.opt_state["m"])).items()}
+    net.fit(RingIterator(ring, 1, count=steps - 1))
+    change = jax.jit(lambda tree, key: reference.leaf_norms(
+        jax.tree_util.tree_map(
+            jax.numpy.subtract, _from_program(tree, kinds),
+            reference.weights_from_key(shape, key))))
+    change_norms = jax.device_get(change(net.params_tree,
+                                         reference.seed_key(seed)))
+    return {"losses": [float(l) for l in tap.losses[:steps]],
+            "grad_norms": grad_norms, "change_norms": change_norms}
+
+
+def run_train(config, cell, seed, seconds, tracer, note_setup_done):
+    """Returns (facts, first-steps readings, a closure that frees the
+    program's state)."""
+    import jax
+    shape, traffic = shape_of(config), cell["traffic"]
+    net = build_net(config)
+    seed_weights(net, shape, seed)
+    ring = train_batches(traffic, shape["vocab"], seed)
+    tap = LossTap(traffic.get("loss_lag", 2))
+    net.set_listeners(tap)
+    first = first_steps(net, tap, ring, config["adam"], shape, seed)
+    done_before = len(tap.losses)
+
+    traced = {}
+    if tracer is not None:
+        a = done_before + traffic["trace"]["after_steps"]
+        b = a + traffic["trace"]["steps"]
+
+        def on_step(n):
+            if n in (a, b):
+                tap.losses[-1].block_until_ready()
+                if n == a:     # snapshots INSIDE the profiler's start and stop
+                    tracer.start()
+                    traced["before"] = registry_snapshot()
+                    traced["t0"] = time.perf_counter()
+                else:
+                    traced["t1"] = time.perf_counter()
+                    traced["after"] = registry_snapshot()
+                    tracer.stop()
+        tap.on_step = on_step
+
+    note_setup_done()
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("bench/fit"):
+        net.fit(RingIterator(ring, done_before, deadline=t0 + seconds))
+    t1 = time.perf_counter()         # fit has read the last loss back
+    tap._span.__exit__(None, None, None)
+    losses = np.asarray([float(l) for l in tap.losses[done_before:]])
+    tokens_per_step = traffic["batch"] * traffic["seq"]
+    facts = {"window_s": t1 - t0, "steps": len(losses),
+             "failed": int(np.sum(~np.isfinite(losses))),
+             "tokens": len(losses) * tokens_per_step,
+             "last_loss": float(losses[-1])}
+    if "t1" in traced:
+        n = traffic["trace"]["steps"]
+        facts["traced"] = {
+            "window_s": traced["t1"] - traced["t0"], "steps": n,
+            "tokens": n * tokens_per_step,
+            "flops": n * tokens_per_step * costs.train_flops_per_token(
+                shape, traffic["seq"]),
+            "before": traced["before"], "after": traced["after"]}
+
+    def free():
+        net.params_tree = net.opt_state = net._solver = None
+        tap.losses.clear()
+        gc.collect()
+    return facts, first, free
+
+
+# ---------------------------------------------------------------------------
+# serve: a closed loop of clients over one GenerationServer
+# ---------------------------------------------------------------------------
+def _draw(spec: dict, rng, n: int) -> np.ndarray:
+    if spec["dist"] == "uniform":
+        return rng.integers(spec["lo"], spec["hi"] + 1, n)
+    if spec["dist"] == "lognormal":
+        x = rng.lognormal(np.log(spec["median"]), spec["sigma"], n)
+        return np.clip(np.rint(x), spec.get("lo", 1), spec["cap"]).astype(int)
+    if spec["dist"] == "fixed":
+        return np.full(n, spec["value"], int)
+    raise ValueError(f"unknown distribution {spec['dist']!r}")
+
+
+def serve_requests(traffic: dict, vocab: int, seed: int) -> list:
+    """One sequence of (prompt ids, n_new) per client.  The sizes come
+    from the mix's own ``sizes_seed``: every seed sends the SAME
+    sequences of sizes, so every seed does the same work; the run's
+    seed decides which client gets which sequence and every token id.
+    ``shared_prefix`` tokens open every prompt alike."""
+    clients = traffic["clients"]
+    per = max(1, traffic["n_sizes"] // clients)
+    sizes = np.random.default_rng(traffic["sizes_seed"])
+    lens = _draw(traffic["prompt_len"], sizes, clients * per).reshape(clients, per)
+    news = _draw(traffic["n_new"], sizes, clients * per).reshape(clients, per)
+    rng = np.random.default_rng(seed)
+    prefix = rng.integers(0, vocab, traffic.get("shared_prefix", 0))
+    out = []
+    for row in rng.permutation(clients):
+        seq = []
+        for n, new in zip(lens[row], news[row]):
+            p = rng.integers(0, vocab, int(n)).astype(np.int32)
+            k = min(len(prefix), len(p) - 1)
+            p[:k] = prefix[:k]
+            seq.append((p, int(new)))
+        out.append(seq)
+    return out
+
+
+def admit_bucket(n: int, limit: int, block: int) -> int:
+    """The prefill length the server pads a prompt of ``n`` to: the
+    next power of two (at most ``limit``), rounded up to whole blocks.
+    A copy of the program's rule, used only to choose warm-up prompts."""
+    b = 1
+    while b < n and b < limit:
+        b *= 2
+    return -(-min(b, limit) // block) * block
+
+
+def warm_server(srv, traffic: dict, server_kw: dict, vocab: int) -> None:
+    """One solo request per admit bucket the mix's prompt lengths hit;
+    its 15 new tokens run the decode scans of 8, 4, 2 and 1 ticks."""
+    spec = traffic["prompt_len"]
+    lo, hi = spec.get("lo", 1), spec.get("hi", spec.get("cap"))
+    block, limit = server_kw["block_size"], server_kw["max_len"]
+    by_bucket = {}
+    for n in range(lo, hi + 1):
+        by_bucket[admit_bucket(n, limit, block)] = n
+    rng = np.random.default_rng(0)
+    for n in by_bucket.values():
+        srv.submit_async(rng.integers(0, vocab, n).astype(np.int32),
+                         n_new=15).result(timeout=900)
+    ran = {k for k, v in registry_snapshot()["counters"].items()
+           if k.startswith("generation_server_scan_ticks_total") and v > 0}
+    want = set()
+    k = 1
+    while k <= server_kw["tick_batch"]:
+        want.add(f'generation_server_scan_ticks_total{{k="{k}"}}')
+        k *= 2
+    if not want <= ran:
+        raise RuntimeError(f"warm-up left scan lengths cold: {want - ran}")
+
+
+class _Req:
+    __slots__ = ("prompt", "n_new", "h", "t_submit", "t_first", "t_done",
+                 "marks", "tokens", "error")
+
+
+def _submit(srv, item, now):
+    import jax
+    r = _Req()
+    r.prompt, r.n_new = item
+    r.t_submit, r.t_first, r.t_done = now, None, None
+    r.marks, r.tokens, r.error = {}, None, None
+    with jax.profiler.TraceAnnotation("bench/submit"):
+        r.h = srv.submit_async(r.prompt, n_new=r.n_new)
+    return r
+
+
+def closed_loop(srv, requests, ramp_s: float, seconds: float,
+                trace_s: float, tracer, on_open, poll_s: float = 0.001):
+    """One caller per sequence in ``requests``, each sending its next
+    request when the last one returned; one thread polls them all.  The window opens after
+    ``ramp_s`` seconds of the same traffic and closes ``seconds`` later.
+    Returns (finished, in_flight, marks): every request keeps, under
+    ``marks``, the tokens it had emitted at each boundary it was in
+    flight at ('open', 'close', and 'trace0'/'trace1' in a traced run).
+    """
+    import jax
+    feeds = [itertools.cycle(seq) for seq in requests]
+    clients = len(requests)
+    live = [None] * clients
+    finished, times = [], {}
+    t_begin = time.perf_counter()
+
+    def mark(name, now):
+        times[name] = now
+        for r in live:
+            if r is not None:
+                r.marks[name] = r.h.emitted
+
+    while True:
+        now = time.perf_counter()
+        if "open" not in times and now >= t_begin + ramp_s:
+            on_open()
+            now = time.perf_counter()
+            mark("open", now)
+            if tracer is not None:
+                tracer.start()
+                mark("trace0", time.perf_counter())
+        if (tracer is not None and "trace0" in times
+                and "trace1" not in times
+                and now >= times["trace0"] + trace_s):
+            mark("trace1", now)
+            tracer.stop()
+        if "open" in times and now >= times["open"] + seconds:
+            mark("close", now)
+            break
+        for c in range(clients):
+            r = live[c]
+            if r is not None:
+                if r.t_first is None and r.h.emitted > 0:
+                    r.t_first = now
+                if r.h.done():
+                    r.t_done = now
+                    if r.t_first is None:
+                        r.t_first = now
+                    try:
+                        with jax.profiler.TraceAnnotation("bench/result"):
+                            r.tokens = r.h.result(timeout=0)
+                    except Exception as e:  # a failed request is counted
+                        r.error = repr(e)
+                    finished.append(r)
+                    r = None
+            if r is None:
+                r = _submit(srv, next(feeds[c]), time.perf_counter())
+            live[c] = r
+        with jax.profiler.TraceAnnotation("bench/poll_sleep"):
+            time.sleep(poll_s)
+    return finished, [r for r in live if r is not None], times
+
+
+def _emitted_between(reqs, a: str, b: str, t_a: float, t_b: float):
+    """For each request, the new tokens it emitted between boundaries
+    ``a`` and ``b``: [(request, first new token, last new token)],
+    tokens counted from 1."""
+    out = []
+    for r in reqs:
+        if r.error is not None or r.t_submit >= t_b:
+            continue
+        if r.t_done is not None and r.t_done < t_a:
+            continue
+        lo = r.marks.get(a, 0)
+        hi = r.marks.get(b, r.n_new if r.t_done is not None else 0)
+        if hi > lo:
+            out.append((r, lo + 1, hi))
+    return out
+
+
+def _serve_work(shape, spans, t_a, t_b) -> dict:
+    """Tokens, forward operations and the decode kernel's context sum
+    of the work between two boundaries."""
+    tokens = flops = ctx_sum = 0.0
+    for r, lo, hi in spans:
+        t0 = len(r.prompt)
+        tokens += hi - lo + 1
+        if lo == 1:                     # the prefill made token 1
+            flops += costs.lm_forward_flops(shape, 1, t0, 1)
+            lo = 2
+        if hi >= lo:                    # token j attends t0 + j - 1 keys
+            flops += costs.lm_forward_flops(shape, t0 + lo - 1, t0 + hi - 1,
+                                            hi - lo + 1)
+            ctx_sum += (2 * t0 + lo + hi - 2) * (hi - lo + 1) / 2.0
+    return {"window_s": t_b - t_a, "tokens": tokens, "flops": flops,
+            "ctx_sum": ctx_sum}
+
+
+def run_serve(config, cell, seed, seconds, tracer, note_setup_done):
+    from deeplearning4j_tpu.parallel import GenerationServer
+    shape, traffic, server_kw = shape_of(config), cell["traffic"], cell["server"]
+    net = build_net(config)
+    seed_weights(net, shape, seed)
+    srv = GenerationServer(net, **server_kw)
+    snaps = {}
+    try:
+        warm_server(srv, traffic, server_kw, shape["vocab"])
+        requests = serve_requests(traffic, shape["vocab"], seed)
+
+        class Tr:                        # registry snapshots ride along
+            def start(self):      # inside the profiler's start and stop,
+                tracer.start()    # which take seconds themselves
+                snaps["before"] = registry_snapshot()
+
+            def stop(self):
+                snaps["after"] = registry_snapshot()
+                tracer.stop()
+
+        def on_open():
+            note_setup_done()
+            snaps["open"] = registry_snapshot()
+
+        finished, in_flight, times = closed_loop(
+            srv, requests, traffic["ramp_seconds"],
+            seconds, traffic["trace_seconds"],
+            Tr() if tracer is not None else None, on_open)
+        snaps["close"] = registry_snapshot()
+    finally:
+        srv.shutdown(drain=False, timeout=30.0)
+
+    t_open, t_close = times["open"], times["close"]
+    done = [r for r in finished if r.t_done >= t_open]
+    ok = [r for r in done if r.error is None]
+    inf = float("inf")
+    ttft = [r.t_first - r.t_submit if r.error is None else inf
+            for r in finished + in_flight
+            if r.t_first is not None and t_open <= r.t_first <= t_close
+            or (r.error is not None and r.t_done >= t_open)]
+    tpot = [(r.t_done - r.t_first) / max(1, r.n_new - 1)
+            if r.error is None else inf for r in done]
+    everyone = finished + in_flight
+    facts = {"requests_done": len(done), "failed": len(done) - len(ok),
+             "ttft_s": ttft, "tpot_s": tpot,
+             "tick_failures": _delta(snaps["open"], snaps["close"],
+                                     "generation_server_tick_failures_total"),
+             **_serve_work(shape, _emitted_between(
+                 everyone, "open", "close", t_open, t_close), t_open, t_close)}
+    if "trace1" in times:
+        facts["traced"] = {
+            **_serve_work(shape, _emitted_between(
+                everyone, "trace0", "trace1", times["trace0"],
+                times["trace1"]), times["trace0"], times["trace1"]),
+            "before": snaps["before"], "after": snaps["after"]}
+    sample = [(np.asarray(r.tokens), len(r.prompt), r.n_new)
+              for r in pick_sample(ok, traffic["compare_requests"], seed)]
+
+    def free():
+        nonlocal srv, net
+        srv = net = None
+        gc.collect()
+    return facts, sample, free
+
+
+def pick_sample(ok: list, n: int, seed: int) -> list:
+    """The requests that are compared: the longest finished one and
+    ``n - 1`` more drawn from the seed."""
+    by_len = sorted(ok, key=lambda r: -len(r.tokens))
+    if not by_len:
+        return []
+    rest = np.random.default_rng(seed).choice(
+        np.arange(1, len(by_len)), min(n - 1, len(by_len) - 1), replace=False)
+    return by_len[:1] + [by_len[i] for i in sorted(rest)]
+
+
+def _delta(before: dict, after: dict, series: str, kind="counters") -> float:
+    return after[kind].get(series, 0.0) - before[kind].get(series, 0.0)

@@ -1,0 +1,152 @@
+"""The yardstick's arithmetic, checked by hand: the trace reduction on a
+hand-built trace, the cost functions on a tiny shape, the percentile
+and rate with a stall in the window, every per-layer reader on made-up
+facts, and BENCHMARK.json's names and files."""
+import json
+import os
+import re
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from benchmark import costs, readers, run, trace_reduce  # noqa: E402
+
+OPS = [("%while.4 = (s32[], bf16[24,2049,16,16,64]", 0.0, 1.5),  # holds the next two (name cut short)
+       ("fusion.1", 0.0, 1.0), ("flash_kernel", 0.5, 1.0),   # overlap: 0..1.5
+       ("fusion.1", 3.0, 0.5), ("flash_kernel", 5.5, 1.0)]   # gaps 1.5 and 2.0
+TRACE = {"devices": {"/device:TPU:0": {
+    "XLA Ops": OPS, "XLA Modules": [("jit_scan_fn(1)", 0.0, 1.5),
+                                    ("jit_admit(2)", 3.0, 0.5)]}},
+    "host_spans": [("bench/fit", 0.0, 10.0), ("bench/poll_sleep", 1.6, 1.0)]}
+SHAPE = {"d": 8, "layers": 2, "heads": 2, "ff": 16, "vocab": 50,
+         "max_len": 32, "n_out": 50}
+PEAK = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
+
+
+def test_trace_reduction_on_a_hand_built_trace():
+    assert trace_reduce.busy_intervals(OPS) == [[0.0, 1.5], [3.0, 3.5], [5.5, 6.5]]
+    assert trace_reduce.busy_seconds(TRACE) == pytest.approx(3.0)
+    assert trace_reduce.time_of(TRACE, "flash") == (pytest.approx(2.0), 2)
+    assert trace_reduce.time_of(TRACE, "scan_fn", "XLA Modules") == (1.5, 1)
+    assert trace_reduce.time_of(TRACE, "no_such_kernel") == (None, 0)
+    assert trace_reduce.top_ops(TRACE, 1) == [["flash_kernel", 2.0]]
+    assert trace_reduce.op_group(
+        "%fusion.1683 = (pred[]{:T(512)}, bf16[1024,4096]{1,0:T(8,128)(2,1)}) "
+        "fusion(bf16[16,512,4096]{2,1,0} %get-tuple-element.1457)") == \
+        "%fusion = (pred[], bf16[1024,4096]) fusion(bf16[16,512,4096]"
+    # each gap is named by the INNERMOST benchmark span over its middle
+    assert trace_reduce.idle_gaps(TRACE) == [["bench/fit", 2.0],
+                                             ["bench/poll_sleep", 1.5]]
+
+
+def test_costs_against_hand_counts():
+    mm = 2 * (4 * 8 * 8 + 2 * 8 * 16)                      # 1024
+    assert costs.matmul_params(SHAPE) == mm
+    assert costs.train_flops_per_token(SHAPE, 4) == 6 * mm + 12 * 2 * 4 * 8
+    # three tokens at contexts 5, 6, 7, LM head on two of them
+    assert costs.lm_forward_flops(SHAPE, 5, 7, 2) == (
+        2 * mm * 3 + 4 * 2 * 8 * 18 + 2 * 8 * 50 * 2)
+    assert costs.lm_forward_flops(SHAPE, 5, 4, 0) == 0.0
+    fwd = costs.flash_cost(SHAPE, 3, 4, False, False)
+    assert fwd == {"flops": 2 * 2.0 * 3 * 4 * 4 * 8, "bytes": 4.0 * 3 * 4 * 8 * 2}
+    bwd = costs.flash_cost(SHAPE, 3, 4, True, True)
+    assert bwd == {"flops": fwd["flops"], "bytes": 2 * fwd["bytes"]}
+    paged = costs.paged_attention_cost(SHAPE, 10.0)
+    assert paged == {"flops": 4.0 * 2 * 8 * 10, "bytes": 2.0 * 2 * 8 * 2 * 10}
+    assert costs.roofline_seconds(paged, PEAK) == (64.0, "bandwidth")
+    assert costs.roofline_seconds({"flops": 1000.0, "bytes": 1.0}, PEAK)[1] == "compute"
+
+
+def test_a_stall_in_the_window_moves_the_tail_and_the_rate():
+    steady = [0.010] * 95 + [0.012] * 5
+    stalled = [0.010] * 90 + [0.500] * 10      # one stall caught ten requests
+    assert run.percentile(steady, 95) == pytest.approx(0.0101, abs=1e-4)
+    assert run.percentile(stalled, 95) == pytest.approx(0.5)
+    assert run.percentile([1.0, 2.0, 3.0, 4.0], 50) == 2.5
+    # a failed request never met any limit: past 5% of them the tail is lost
+    assert run.percentile([0.01] * 90 + [float("inf")] * 10, 95) == float("inf")
+    facts = {"tokens": 1000.0, "window_s": 10.0, "ttft_s": stalled, "tpot_s": steady}
+    same_work_with_stall = dict(facts, window_s=12.5)
+    a = run.end_to_end("serve_closed", facts, 3.0)
+    b = run.end_to_end("serve_closed", same_work_with_stall, 3.0)
+    assert a["serve_tokens_per_s"] == (100.0, "tokens/s")
+    assert b["serve_tokens_per_s"] == (80.0, "tokens/s")
+    assert a["ttft_p95_ms"][0] == pytest.approx(500.0) and a["setup_s"] == (3.0, "s")
+    assert run.end_to_end("train", {"tokens": 8192 * 5, "window_s": 2.0}, 1.0)[
+        "train_tokens_per_s"] == (20480.0, "tokens/s")
+
+
+def _manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_every_per_layer_reader_reads_made_up_facts():
+    """Each metric's file resolves to a reduction; on counters that did
+    not move or a pattern that matches nothing it returns None, never 0."""
+    snap = lambda ticks, scans, occ, wait: {
+        "counters": {"generation_server_ticks_total": ticks,
+                     'generation_server_scan_ticks_total{k="8"}': scans,
+                     'generation_server_scan_ticks_total{k="1"}': scans},
+        "histograms": {"generation_server_slot_occupancy": {"sum": occ, "count": 2 * scans},
+                       "train_data_wait_seconds": {"sum": wait, "count": 5}}}
+    ctx = {"facts": {"window_s": 10.0, "steps": 4, "tokens": 100.0, "flops": 250.0,
+                     "ctx_sum": 10.0},
+           "before": snap(0.0, 0.0, 0.0, 0.0), "after": snap(90.0, 5.0, 9.0, 0.5),
+           "trace": TRACE, "peak": PEAK, "shape": SHAPE,
+           "traffic": {"batch": 3, "seq": 4}}
+    got = {}
+    for m in _manifest()["per_layer"]:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", m["name"] + ".json")) as f:
+            spec = json.load(f)
+        assert (spec["layer"], spec["unit"], spec["moves"]) == (
+            m["layer"], m["unit"], m["moves"])
+        got[m["name"]] = readers.read(spec, ctx)
+    assert got["data_wait_share"] == pytest.approx(5.0)
+    assert got["train_mfu"] == got["serve_mfu"] == pytest.approx(25.0)
+    assert got["train_step_device_ms"] == pytest.approx(750.0)
+    assert got["device_idle_share.train"] == pytest.approx(70.0)
+    assert got["scan_len_mean"] == pytest.approx(9.0)
+    assert got["slot_occupancy"] == pytest.approx(90.0)
+    # the hand-built trace names no real kernel: silent, not 0
+    assert got["paged_attn_roofline"] is None and got["flash_fwd_roofline"] is None
+    spec = {"reader": {"kind": "roofline_of", "args": {
+        "pattern": "flash", "cost": "paged_attention"}}}
+    assert readers.read(spec, ctx) == pytest.approx(100.0 * 64.0 / 2.0)
+    # 1.5 s of scan programs over (2 "flash" events / 2 layers) = 1 tick
+    assert readers.read({"reader": {"kind": "trace_time_of", "args": {
+        "pattern": "scan_fn", "line": "XLA Modules", "scale": 1000.0,
+        "per_events_of": {"pattern": "flash", "each": "layers"}}}}, ctx) == 1500.0
+    assert got["decode_tick_device_ms"] is None
+    assert readers.read({"reader": {"kind": "registry_delta", "args": {
+        "of": {"series": "absent_total"}}}}, ctx) is None
+
+
+def test_manifest_names_units_and_files():
+    b = _manifest()
+    name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+    unit = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+    cells = {w["name"] for w in b["workloads"]}
+    configs = {c["name"]: c for c in b["configs"]}
+    for w in b["workloads"]:
+        assert name.match(w["name"]) and name.match(w["traffic"]) and len(w["why"]) <= 200
+        assert w["name"] == f'{w["config"]}.{w["traffic"]}' and w["chips"] in (1, 4)
+        with open(os.path.join(ROOT, "benchmark", "workloads", w["name"] + ".json")) as f:
+            cell = json.load(f)
+        assert cell["config"] == w["config"] and cell["driver"] in ("train", "serve_closed")
+        assert os.path.isfile(os.path.join(ROOT, configs[w["config"]]["file"]))
+    e2e = {m["name"]: m for m in b["end_to_end"]}
+    assert "setup_s" in e2e and all(0 < m["bound"] <= 0.1 for m in e2e.values())
+    for m in b["end_to_end"] + b["per_layer"]:
+        assert name.match(m["name"]) and unit.match(m["unit"])
+        assert m["better"] in ("lower", "higher") and set(m.get("workloads", [])) <= cells
+    for m in b["per_layer"]:
+        # every cell that reports the metric reports the end-to-end one it moves
+        assert set(m["workloads"]) <= set(e2e[m["moves"]].get("workloads", cells))
+        assert os.path.isfile(os.path.join(ROOT, "benchmark", "layer_metrics",
+                                           m["name"] + ".json"))
+    for cell in cells:
+        assert any("mfu" in m["name"] and cell in m["workloads"] for m in b["per_layer"])
