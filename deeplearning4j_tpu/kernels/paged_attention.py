@@ -197,6 +197,10 @@ def _verify_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = out.transpose(1, 0, 2)      # (h, W, dh) -> (W, h, dh)
 
 
+# the outer scope keeps the kernel's ``name=`` whole under any
+# transformation (``%paged_attention.N`` in a profile): see the note
+# at flash_attention._flash_fwd
+@jax.named_scope("paged_read")
 def _paged_verify_pallas(q, k_pool, v_pool, block_table, pos0,
                          scale: float):
     B, W, h, dh = q.shape
@@ -229,6 +233,7 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_table, pos0,
         out_shape=jax.ShapeDtypeStruct((B, W, h, dh), q.dtype),
         compiler_params=_dimsem("parallel", "arbitrary"),
         interpret=_interpret(),
+        name="paged_attention",
     )(block_table, pos0, qh, k_pool, v_pool)
 
 

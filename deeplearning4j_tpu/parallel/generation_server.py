@@ -210,7 +210,7 @@ log = logging.getLogger("deeplearning4j_tpu")
 # Serving-decode telemetry (the serve-side counterpart of the
 # parallel.inference series): slot occupancy answers "is the decode
 # pool saturated", queue depth is the backpressure a load balancer
-# watches, TTFT and per-request tokens/s are the caller-visible SLOs.
+# watches, TTFT is the caller-visible SLO.
 _ADMITTED = telemetry.counter(
     "generation_server_admitted_total",
     "requests admitted into a decode slot (prefill done)")
@@ -228,10 +228,28 @@ _HOST_SYNCS = telemetry.counter(
     "generation_server_host_syncs_total",
     "device->host polls by the scheduler (one per decode scan — the "
     "dispatch-overhead denominator; syncs/token ~ 1/k steady-state)")
-_TOK_PER_DISPATCH = telemetry.gauge(
-    "generation_server_tokens_per_dispatch",
-    "new tokens emitted by the last decode dispatch (active slots x "
-    "live scan ticks — the host-sync amortization factor)")
+# useful share of the decode pool: tokens / slot-ticks is the share of
+# slot-ticks in which a slot emitted (a slot whose request ended
+# mid-scan rides the scan out idle).  A speculative round can commit
+# several tokens, so there the ratio may pass 1.
+_TOKENS_EMITTED = telemetry.counter(
+    "generation_server_tokens_emitted_total",
+    "new tokens the decode dispatches emitted (summed over slots at "
+    "each scan's host poll)")
+_SLOT_TICKS = telemetry.counter(
+    "generation_server_slot_ticks_total",
+    "active slots at dispatch x ticks of the scan (speculative "
+    "dispatches: x rounds, as generation_server_ticks_total counts)")
+# the scheduler thread's own time between decode scans — while it
+# runs the device has nothing queued.  NOT the dispatch -> poll wait
+# (serve/tick) and NOT the blocked-on-an-empty-queue wait (serve/idle).
+_SCHED_HOST = telemetry.counter(
+    "generation_server_sched_host_seconds_total",
+    "scheduler-thread seconds with no decode scan in flight, by phase "
+    "(the durations of the serve/admit and serve/retire spans)",
+    labelnames=("phase",))
+_SCHED_HOST_PHASE = {p: _SCHED_HOST.labels(phase=p)
+                     for p in ("admit", "retire")}
 _SLOTS_BUSY = telemetry.gauge(
     "generation_server_slots_busy", "slots decoding at the last tick")
 _QDEPTH = telemetry.gauge(
@@ -245,10 +263,6 @@ _TTFT = telemetry.histogram(
     "generation_server_ttft_seconds",
     "submit -> first generated token per request (queue wait + "
     "prefill + first tick)")
-_RATE = telemetry.histogram(
-    "generation_server_request_tokens_per_sec",
-    "per-request generated tokens / residence seconds",
-    buckets=(1., 4., 16., 64., 256., 1024., 4096., 16384.))
 # Self-healing series: a load balancer drains on server_healthy == 0;
 # watchdog restarts at any steady rate are an incident, not noise.
 _HEALTHY = telemetry.gauge(
@@ -413,6 +427,37 @@ _PHASE = telemetry.histogram(
 #: samples every dispatch — that site host-syncs anyway, so its
 #: sample is free)
 _PROFILE_PREFILL_EVERY = 4
+
+
+class _SchedPhases:
+    """The scheduler thread's phases around its decode scans: each is
+    a scoped span ``serve/<phase>`` (so it lands in a running profile
+    on the device lines' clock), and the phases during which the
+    device has nothing queued — ``admit``, ``retire`` — add their
+    duration to ``generation_server_sched_host_seconds_total``.  One
+    phase is open at a time; :meth:`switch` closes it and opens the
+    next, so the many ``return`` and ``continue`` edges of the loop
+    need no scope of their own."""
+
+    def __init__(self, tracer, owner):
+        self._tracer, self._owner = tracer, owner
+        self._open = None    # (phase, its span's context, Span, t0)
+
+    def switch(self, phase: Optional[str]) -> None:
+        now = time.perf_counter()
+        if self._open is not None:
+            was, ctx, _, t0 = self._open
+            self._open = None
+            ctx.__exit__(None, None, None)
+            if was in _SCHED_HOST_PHASE:      # not serve/idle
+                _SCHED_HOST_PHASE[was].inc(now - t0)
+        if phase is not None:
+            ctx = self._tracer.span("serve/" + phase, owner=self._owner)
+            self._open = (phase, ctx, ctx.__enter__(), now)
+
+    def note(self, **args) -> None:
+        if self._open is not None:
+            self._open[2].note(**args)
 
 
 def _pow2_floor(n: int) -> int:
@@ -1771,9 +1816,11 @@ class GenerationServer:
         the same program, so one scan serves mixed greedy+sampled
         slots."""
 
+        @jax.named_scope("sample")
         def pick_greedy(state):
             return jnp.argmax(state["logits"], axis=-1), state["key"]
 
+        @jax.named_scope("sample")
         def pick_sampled(state):
             both = jax.vmap(jax.random.split)(state["key"])
             keys, subs = both[:, 0], both[:, 1]
@@ -1824,7 +1871,11 @@ class GenerationServer:
         bs = self.block_size
         shard = self._shard
 
-        def scan_fn(emb_p, blk_stack, head_p, kc, vc, state):
+        # the jitted callable's __name__ names the program in a
+        # profile (module ``jit_decode_scan``), the scope one tick's
+        # operations: the benchmark's readers find both by name
+        def decode_scan(emb_p, blk_stack, head_p, kc, vc, state):
+            @jax.named_scope("decode_tick")
             def step(carry, _):
                 kc, vc, state, emitted = carry
                 active = state["remaining"] > 0
@@ -1883,7 +1934,7 @@ class GenerationServer:
         # of copying both full [n_layers, B, h, L, dh] buffers per
         # dispatch (ignored with a warning on backends without
         # donation)
-        fn = self._scan_cache[key] = jax.jit(scan_fn,
+        fn = self._scan_cache[key] = jax.jit(decode_scan,
                                              donate_argnums=(3, 4, 5))
         return fn
 
@@ -2328,9 +2379,9 @@ class GenerationServer:
         spec = self._spec if use_draft else None
         shard = self._shard
 
-        def admit(emb_p, blk_stack, head_p, kc, vc, state, prompt, t0,
-                  slot, n_new, eos_id, key, temp, tk, tp, phys,
-                  table_row, dtable_row, *draft_ops):
+        def admit_miss(emb_p, blk_stack, head_p, kc, vc, state, prompt,
+                       t0, slot, n_new, eos_id, key, temp, tk, tp,
+                       phys, table_row, dtable_row, *draft_ops):
             # t0 picks the last REAL position's logits out of the
             # padded bucket
             logits, ks, vs = gen._prefill_rows(emb_p, blk_stack,
@@ -2356,7 +2407,7 @@ class GenerationServer:
                                    dtable_row)
             return kc, vc, state
 
-        fn = self._admit_cache[key] = jax.jit(admit,
+        fn = self._admit_cache[key] = jax.jit(admit_miss,
                                               donate_argnums=(3, 4, 5))
         return fn
 
@@ -2396,10 +2447,10 @@ class GenerationServer:
         spec = self._spec if use_draft else None
         shard = self._shard
 
-        def admit(emb_p, blk_stack, head_p, kc, vc, state, suffix, p0,
-                  last_ix, t0, slot, n_new, eos_id, key, temp, tk, tp,
-                  prefix_phys, phys, table_row, dtable_row,
-                  *extra_ops):
+        def admit_hit(emb_p, blk_stack, head_p, kc, vc, state, suffix,
+                      p0, last_ix, t0, slot, n_new, eos_id, key, temp,
+                      tk, tp, prefix_phys, phys, table_row, dtable_row,
+                      *extra_ops):
             if nfill:
                 # host-tier restore: land the spilled bytes in their
                 # claimed pool blocks BEFORE the prefix gather below
@@ -2455,7 +2506,7 @@ class GenerationServer:
                                    dtable_row)
             return kc, vc, state
 
-        fn = self._admit_cache[key] = jax.jit(admit,
+        fn = self._admit_cache[key] = jax.jit(admit_hit,
                                               donate_argnums=(3, 4, 5))
         return fn
 
@@ -2651,12 +2702,6 @@ class GenerationServer:
             with self._lock:
                 req._result = self._ids[slot,
                                         :req.t0 + req.emitted].copy()
-            dt = time.perf_counter() - req.t_submit
-            # prefill-only retires emit nothing by design — a 0.0
-            # sample per staged request would drag the fleet-wide
-            # tokens/s percentiles toward 0 on dashboards
-            if dt > 0 and not req.prefill_only:
-                _RATE.observe(req.emitted / dt)
         # close every phase span the request still holds, on WHATEVER
         # thread retires it (scheduler, watchdog recovery, shutdown) —
         # recovered requests produce complete traces instead of
@@ -2953,6 +2998,17 @@ class GenerationServer:
 
     def _run(self, my_epoch: int):
         tracer = telemetry.get_tracer()
+        # phase spans share the tick span's owner: this scheduler
+        # INCARNATION (id, epoch), not the raw thread ident — idents
+        # of dead threads are recycled, and the watchdog must never
+        # flush an unrelated thread's spans
+        phases = _SchedPhases(tracer, (id(self), my_epoch))
+        try:
+            self._run_loop(my_epoch, tracer, phases)
+        finally:
+            phases.switch(None)    # whichever edge left the loop
+
+    def _run_loop(self, my_epoch: int, tracer, phases: _SchedPhases):
         prof = telemetry.get_profiler()
         stop = False
         while True:
@@ -2962,7 +3018,9 @@ class GenerationServer:
                 idle = not self._active and not self._pending
             # ingest: block only when idle, else drain without waiting
             if idle and not stop:
+                phases.switch("idle")
                 item = self._queue.get()
+                phases.switch(None)
                 if self._superseded(my_epoch):
                     # recovered past us while we slept: hand the item
                     # to the live scheduler (sentinels included)
@@ -2973,6 +3031,10 @@ class GenerationServer:
                 else:
                     with self._lock:
                         self._pending.append(item)
+            # serve/admit: ingest, the admission block with its admit
+            # dispatches, and the choice of the scan length that
+            # depends on who was admitted — up to the scan's dispatch
+            phases.switch("admit")
             while True:          # opportunistic drain (also ingests
                 try:             # requests raced in behind a sentinel)
                     item = self._queue.get_nowait()
@@ -3044,6 +3106,7 @@ class GenerationServer:
                         admits.append((req, slot, plan))
                     n_pending = len(self._pending)
                     n_active = len(self._active)
+                phases.note(n=len(admits))
                 self._retire_reaped(reaped)
                 for req, slot, plan in admits:
                     t_adm = time.perf_counter()
@@ -3176,10 +3239,8 @@ class GenerationServer:
                 else:
                     k = (1 if queue_busy
                          else min(self.tick_batch, _pow2_floor(k_drain)))
-                # the tick span's owner is this scheduler INCARNATION
-                # (id, epoch), not the raw thread ident — idents of
-                # dead threads are recycled, and the watchdog must
-                # never flush an unrelated thread's spans
+                # serve/tick: dispatch -> the one host poll
+                phases.switch(None)
                 with tracer.span("serve/tick",
                                  owner=(id(self), my_epoch),
                                  active=n_active, queued=n_pending,
@@ -3251,6 +3312,8 @@ class GenerationServer:
                         n_acc = int(acc_h.sum())
                     _HOST_SYNCS.inc()
                     self._mark_tick(my_epoch, None)
+                # serve/retire: unpack the poll, retire, free blocks
+                phases.switch("retire")
                 # device-truth occupancy at scan end (the host view is
                 # reconciled below after retire/cancel bookkeeping)
                 _SLOTS_BUSY.set(alive_h)
@@ -3312,7 +3375,8 @@ class GenerationServer:
                 else:
                     _TICKS.inc(k)
                     _SCANS.labels(k=str(k)).inc()
-                _TOK_PER_DISPATCH.set(float(emit_h.sum()))
+                _TOKENS_EMITTED.inc(int(emit_h.sum()))
+                _SLOT_TICKS.inc(n_active * (R if use_spec else k))
                 _OCC.observe(n_active / self.n_slots)
                 now_p = time.perf_counter()
                 now_m = time.monotonic()
@@ -3399,7 +3463,9 @@ class GenerationServer:
                 # update the gauges)
                 _SLOTS_BUSY.set(n_active)
                 _QDEPTH.set(n_pending + self._queue.qsize())
+                phases.switch(None)
             except Exception as e:  # surface to the implicated callers
+                phases.switch(None)
                 self._mark_tick(my_epoch, None)
                 with self._lock:
                     if self._epoch != my_epoch:

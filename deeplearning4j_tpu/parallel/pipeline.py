@@ -222,7 +222,7 @@ class PipelinedTransformerLM:
             lp = jax.nn.log_softmax(logits, -1)
             return -jnp.mean(jnp.sum(labels * lp, -1))
 
-        def step(params, opt_state, ids, labels, it):
+        def train_step(params, opt_state, ids, labels, it):
             loss, grads = jax.value_and_grad(loss_fn)(params, ids,
                                                       labels)
             updates, opt_state = self._updater.update(grads, opt_state,
@@ -233,7 +233,7 @@ class PipelinedTransformerLM:
             return params, opt_state, loss
 
         self._forward = jax.jit(forward)
-        self._step = jax.jit(step)
+        self._step = jax.jit(train_step)
         self._it = 0
         _PIPE_BUBBLE.set((mesh.shape[p_axis] - 1)
                          / (mesh.shape[p_axis] - 1 + n_micro))

@@ -317,7 +317,7 @@ class ShardedTrainer:
         from deeplearning4j_tpu.optimize.solver import (
             apply_updates_if, finite_step_ok, select_step)
 
-        def step(params, opt_state, it, batch, lr_scale):
+        def train_step(params, opt_state, it, batch, lr_scale):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             # same bad-step guard as Solver._step_impl (shared
             # helpers): a non-finite loss/grad step must not move
@@ -332,7 +332,8 @@ class ShardedTrainer:
             opt_state = select_step(ok, opt_state, old_opt_state)
             return params, opt_state, loss
 
-        self._pipe_step = jax.jit(step, donate_argnums=(0, 1))
+        # module ``jit_train_step`` in a profile, as the Solver's
+        self._pipe_step = jax.jit(train_step, donate_argnums=(0, 1))
         # ADVICE r5 perf: unstacking every pipelined block back into
         # the model tree after EVERY step is host-side overhead on the
         # hot path that grows with model size.  Sync lazily instead:

@@ -10,6 +10,14 @@ guarantees.  For per-op DEVICE timelines use ``ui.ProfilerListener``
 where Python time goes between program launches (data wait, dispatch,
 queue drain, serve batching).
 
+ONE CLOCK WITH THE DEVICE (ISSUE 25): a scoped span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so while a profile
+runs ``train/step``, ``serve/tick`` and every other ``with``-span land
+in the capture's host plane beside the device lines, on the profiler's
+clock; with no profile running the annotation is a flag test.  Tracked
+spans are NOT bridged — a TraceMe cannot cross threads — and keep
+their ``trace=`` ids in the jsonl export.
+
 Beyond the ``with``-scoped form there are TRACKED spans
 (:meth:`SpanTracer.begin` -> :class:`Span`), the request-tracing
 primitive: a span opened on one thread may be ENDED on any other —
@@ -55,6 +63,7 @@ import itertools
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Dict, Iterator, List, Optional
@@ -90,6 +99,12 @@ class Span:
         if self._tracer is not None:
             self._tracer._end(self._sid, extra)
 
+    def note(self, **args) -> None:
+        """Add args learned while the span is open (how many requests
+        an admission block admitted); a no-op on a no-op span."""
+        if self._tracer is not None:
+            self.args.update(args)
+
     def __enter__(self) -> "Span":
         return self
 
@@ -100,6 +115,17 @@ class Span:
 
 #: the disabled-tracer span: every method is a no-op
 _NULL_SPAN = Span(None, -1, "", 0.0, 0, False, None, {})
+
+
+def _profiler_annotation(name: str):
+    """The profiler's own host span (a TraceMe) for a scoped span.
+    jax is looked up, never imported: a profile can only be running in
+    a process that has imported it, and this module stays importable
+    without it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 class SpanTracer:
@@ -190,24 +216,28 @@ class SpanTracer:
 
     # -- scoped spans ---------------------------------------------------
     @contextlib.contextmanager
-    def span(self, name: str, owner=None, **args) -> Iterator[None]:
-        """Time a block; records one complete ("X") event on exit.
+    def span(self, name: str, owner=None, **args) -> Iterator[Span]:
+        """Time a block; records one complete ("X") event on exit and
+        yields the open :class:`Span` (for :meth:`Span.note`).
         Exceptions propagate; the span still records with an
         ``"error"`` arg so a trace shows where a request died.
         Implemented over a BOUND tracked span (``owner`` as in
         :meth:`begin`), so a thread that hangs inside the block can
-        still have the span flushed by :meth:`end_owned_by`."""
+        still have the span flushed by :meth:`end_owned_by`.  While a
+        ``jax.profiler`` trace runs the block is also a host span of
+        the same name in that capture (module docstring)."""
         if not self.enabled:
-            yield
+            yield _NULL_SPAN
             return
         sp = self.begin(name, bound=True, owner=owner, **args)
-        try:
-            yield
-        except BaseException as e:
-            sp.end(error=type(e).__name__)
-            raise
-        finally:
-            sp.end()
+        with _profiler_annotation(name):
+            try:
+                yield sp
+            except BaseException as e:
+                sp.end(error=type(e).__name__)
+                raise
+            finally:
+                sp.end()
 
     def _snapshot_events(self) -> List[Dict]:
         """Copy the event buffer safely: deque APPENDS are atomic but

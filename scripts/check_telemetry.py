@@ -1041,7 +1041,10 @@ def main() -> int:
         # guarantees a k=4 fused scan ran and was host-polled once
         "generation_server_host_syncs_total",
         'generation_server_scan_ticks_total{k="4"}',
-        "generation_server_tokens_per_dispatch",
+        "generation_server_tokens_emitted_total",
+        "generation_server_slot_ticks_total",
+        'generation_server_sched_host_seconds_total{phase="admit"}',
+        'generation_server_sched_host_seconds_total{phase="retire"}',
         # continuous device-phase profile (ISSUE 13): the serve/spec
         # runs above sampled all three serve phases on this process
         'fleet_device_phase_seconds_bucket{device="cpu:0",'

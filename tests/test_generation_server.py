@@ -282,6 +282,59 @@ def test_host_syncs_amortized_by_scan(net):
     assert ticks.value - t0 == 16
 
 
+def test_useful_share_and_scheduler_host_counters(net):
+    """ISSUE 25's counters, where the work happens: tokens emitted ==
+    what the handles returned, slot-ticks == sum over dispatches of
+    active slots x scan length, scheduler host seconds within the
+    run's wall time — and the scheduler's phases as scoped spans."""
+    reg, tracer = telemetry.get_registry(), telemetry.get_tracer()
+    emitted = reg.counter("generation_server_tokens_emitted_total")
+    slot_ticks = reg.counter("generation_server_slot_ticks_total")
+    host = reg.counter("generation_server_sched_host_seconds_total",
+                       labelnames=("phase",))
+    host_s = lambda: sum(host.labels(phase=p).value
+                         for p in ("admit", "retire"))
+    occ_sum = lambda: reg.snapshot()["histograms"][
+        "generation_server_slot_occupancy"]["sum"]
+
+    # solo, K=8: two 8-tick scans of one active slot
+    with GenerationServer(net, n_slots=1, max_len=32, tick_batch=8,
+                          tick_timeout_s=None) as srv:
+        e0, s0 = emitted.value, slot_ticks.value
+        srv.submit(np.asarray([1, 2, 3], np.int32), n_new=16, timeout=300)
+    assert (emitted.value - e0, slot_ticks.value - s0) == (16, 16)
+
+    # three requests through two slots, single ticks: every dispatch
+    # adds its active slots, which the occupancy histogram also sums
+    seq0 = max((ev["seq"] for ev in tracer.events()), default=-1)
+    prompts = [np.asarray(p, np.int32) for p in ([4, 5], [6, 7, 8], [9])]
+    budgets = [5, 3, 7]
+    t_wall = time.perf_counter()
+    with GenerationServer(net, n_slots=2, max_len=32, tick_batch=1,
+                          tick_timeout_s=None) as srv:
+        e0, s0, h0, o0 = (emitted.value, slot_ticks.value, host_s(),
+                          occ_sum())
+        handles = [srv.submit_async(p, n_new=n)
+                   for p, n in zip(prompts, budgets)]
+        outs = [h.result(timeout=300) for h in handles]
+    t_wall = time.perf_counter() - t_wall
+    returned = sum(len(o) - len(p) for o, p in zip(outs, prompts))
+    assert emitted.value - e0 == returned == sum(budgets)
+    assert slot_ticks.value - s0 == round((occ_sum() - o0) * 2) >= returned
+    assert 0.0 < host_s() - h0 <= t_wall
+    snap = reg.snapshot()
+    assert not [k for kind in ("counters", "gauges", "histograms")
+                for k in snap[kind] if "request_tokens_per_sec" in k
+                or "tokens_per_dispatch" in k]
+    spans = [ev for ev in tracer.events() if ev["seq"] > seq0]
+    names = {ev["name"] for ev in spans}
+    assert {"serve/idle", "serve/admit", "serve/tick",
+            "serve/retire"} <= names
+    assert sum(ev["args"].get("n", 0) for ev in spans
+               if ev["name"] == "serve/admit") == len(prompts)
+    assert not [n for n in names if n.startswith("bench/")]
+
+
 def test_sampling_and_tick_batch_validation(net):
     with pytest.raises(ValueError, match="tick_batch"):
         GenerationServer(net, n_slots=1, max_len=32, tick_batch=0)

@@ -302,6 +302,63 @@ def test_span_tracer_nesting_and_export(tmp_path):
     assert len(doc["traceEvents"]) == 3
 
 
+def _profile(path):
+    """The profiler as the benchmark runs it: host spans and device
+    lines, no Python call stacks."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    return jax.profiler.trace(str(path), profiler_options=opts)
+
+
+def _host_span_names(path) -> set:
+    import glob
+    from jax.profiler import ProfileData
+    (pb,) = glob.glob(os.path.join(str(path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return {e.name for plane in ProfileData.from_file(pb).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_scoped_span_lands_in_a_running_profile(tmp_path):
+    """ISSUE 25: a ``with``-span is also a host span of the same name
+    in a running jax.profiler capture (one clock with the device
+    lines); a tracked ``begin()`` span, which may end on another
+    thread, is not.  The jsonl export holds both, as before."""
+    tr = SpanTracer()
+    with _profile(tmp_path / "prof"):
+        with tr.span("x/y", k=1) as sp:
+            sp.note(n=3)
+            tracked = tr.begin("x/tracked", trace="r-1")
+        ender = threading.Thread(target=tracked.end)
+        ender.start()
+        ender.join(timeout=10)
+    names = _host_span_names(tmp_path / "prof")
+    assert "x/y" in names and "x/tracked" not in names
+    by_name = {e["name"]: e for e in tr.events()}
+    assert by_name["x/y"]["args"] == {"k": 1, "n": 3}
+    assert by_name["x/tracked"]["args"] == {"trace": "r-1"}
+    assert tr.open_spans() == []
+
+
+def test_scoped_span_without_a_profile_is_the_span_it_was(tmp_path):
+    """No capture running: the same events, nothing written anywhere,
+    and a disabled tracer still yields a span that takes notes."""
+    tr = SpanTracer()
+    with tr.span("outer", phase="fit") as sp:
+        assert sp.name == "outer"
+    (ev,) = tr.events()
+    assert (ev["name"], ev["ph"], ev["args"]) == ("outer", "X",
+                                                  {"phase": "fit"})
+    assert os.listdir(tmp_path) == []
+    off = SpanTracer(enabled=False)
+    with off.span("quiet") as sp:
+        sp.note(n=1)
+    assert off.events() == [] and sp.args == {}
+
+
 def test_report_embeds_telemetry_and_trace_link(tmp_path):
     storage = InMemoryStatsStorage()
     _fit_with_listener(storage)
