@@ -2,7 +2,9 @@
 generators and the weights' way into the program.  From the program a
 driver takes only the system under test (a zoo model's ``fit``, a
 ``GenerationServer``) and its counters; everything that decides a
-number -- the clock, the traffic, the arithmetic -- is here.
+number -- the clock, the traffic, the arithmetic -- is here, or in the
+configuration's family (``families/post_ln.py`` says what that gives):
+the harness knows no architecture.
 """
 from __future__ import annotations
 
@@ -13,16 +15,20 @@ import time
 
 import numpy as np
 
-from benchmark import costs, reference
+
+def family_of(config: dict):
+    """The module the configuration's ``family`` names: shape, weights,
+    reference and costs of its architecture."""
+    if "family" not in config:
+        raise KeyError("the configuration names no \"family\" "
+                       "(e.g. \"benchmark.families.post_ln\")")
+    return importlib.import_module(config["family"])
 
 
-def shape_of(config: dict) -> dict:
-    """The sizes the reference and the cost functions need, from the
-    configuration's constructor arguments."""
-    c = config["ctor"]
-    return {"d": c["d_model"], "layers": c["n_layers"], "heads": c["n_heads"],
-            "ff": c["d_ff"], "vocab": c["vocab_size"], "max_len": c["max_len"],
-            "n_out": c.get("n_classes", c["vocab_size"])}
+def master_dtype(config: dict) -> str:
+    """The dtype the configuration states for the weights the program
+    is handed (``precision.master_weights``)."""
+    return config.get("precision", {}).get("master_weights", "float32")
 
 
 def build_net(config: dict):
@@ -46,46 +52,33 @@ def build_net(config: dict):
     return MultiLayerNetwork(conf).init()
 
 
-def layer_kinds(net) -> tuple:
-    """'emb' | 'block' | 'none' | 'head' for each layer of the net."""
-    kinds = []
-    for i, ly in enumerate(net.layers):
-        if i == 0:
-            kinds.append("emb")
-        elif i == len(net.layers) - 1:
-            kinds.append("head")
-        else:
-            kinds.append("block" if ly.has_params() else "none")
-    return tuple(kinds)
-
-
-def _to_program(w, kinds):
-    tree, b = {}, 0
-    for i, kind in enumerate(kinds):
-        if kind == "block":
-            tree[f"layer_{i}"] = {k: v[b] for k, v in w["blocks"].items()}
-            b += 1
-        else:
-            tree[f"layer_{i}"] = dict(w[kind]) if kind != "none" else {}
-    return tree
-
-
-def _from_program(tree, kinds):
-    import jax.numpy as jnp
-    blocks = [tree[f"layer_{i}"] for i, k in enumerate(kinds) if k == "block"]
-    return {"emb": tree["layer_0"],
-            "blocks": {k: jnp.stack([b[k] for b in blocks]) for k in blocks[0]},
-            "head": tree[f"layer_{len(kinds) - 1}"]}
-
-
-def seed_weights(net, shape: dict, seed: int) -> None:
-    """The seed's weights, made on the device in one jitted call, in
-    the program's own layout and dtype (float32 master weights)."""
+def seed_tree(family, shape: dict, key, dtype: str = "float32"):
+    """The family's reference tree from a key (jit-safe).  Under a
+    master dtype below float32 every leaf is rounded to it and carried
+    as float32: the reference gets the values the program is handed."""
     import jax
-    kinds = layer_kinds(net)
-    make = jax.jit(lambda key: _to_program(
-        reference.weights_from_key(shape, key), kinds))
-    net.params_tree = make(reference.seed_key(seed))
+    import jax.numpy as jnp
+    w = family.weights_from_key(shape, key)
+    if dtype == "float32":
+        return w
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(dtype).astype(jnp.float32), w)
+
+
+def seed_weights(net, family, shape: dict, seed: int,
+                 dtype: str = "float32") -> None:
+    """The seed's weights, made on the device in one jitted call, in
+    the program's own layout and in the master dtype the configuration
+    states: only leaves of that dtype leave the call."""
+    import jax
+    layout = family.layout_of(net)
+
+    def make(key):
+        w = family.weights_from_key(shape, key)
+        if dtype != "float32":
+            w = jax.tree_util.tree_map(lambda a: a.astype(dtype), w)
+        return family.to_program(w, layout)
+    net.params_tree = jax.jit(make)(family.seed_key(seed))
     # a fresh optimizer too (the solver, once built, only builds it lazily once)
     net.opt_state = (net._solver.init_opt_state(net.params_tree)
                      if net._solver is not None else None)
@@ -174,7 +167,7 @@ class LossTap:
         pass
 
 
-def first_steps(net, tap, ring, adam: dict, shape: dict, seed: int,
+def first_steps(net, family, tap, ring, adam: dict, shape: dict, seed: int,
                 steps: int = 3) -> dict:
     """Drive the net from the seed's weights through its first steps by
     the window's own call and feed; read what the reference follows:
@@ -182,34 +175,34 @@ def first_steps(net, tap, ring, adam: dict, shape: dict, seed: int,
     moment after one step is (1 - beta1) * g) and the norms of the
     parameters' change after the last step."""
     import jax
-    kinds = layer_kinds(net)
-    norms = jax.jit(lambda tree: reference.leaf_norms(
-        _from_program(tree, kinds)))
+    layout = family.layout_of(net)
+    norms = jax.jit(lambda tree: family.leaf_norms(
+        family.from_program(tree, layout)))
     net.fit(RingIterator(ring, 0, count=1))
     grad_norms = {k: np.asarray(v) / (1.0 - adam["beta1"])
                   for k, v in jax.device_get(norms(net.opt_state["m"])).items()}
     net.fit(RingIterator(ring, 1, count=steps - 1))
-    change = jax.jit(lambda tree, key: reference.leaf_norms(
+    change = jax.jit(lambda tree, key: family.leaf_norms(
         jax.tree_util.tree_map(
-            jax.numpy.subtract, _from_program(tree, kinds),
-            reference.weights_from_key(shape, key))))
+            jax.numpy.subtract, family.from_program(tree, layout),
+            family.weights_from_key(shape, key))))
     change_norms = jax.device_get(change(net.params_tree,
-                                         reference.seed_key(seed)))
+                                         family.seed_key(seed)))
     return {"losses": [float(l) for l in tap.losses[:steps]],
             "grad_norms": grad_norms, "change_norms": change_norms}
 
 
-def run_train(config, cell, seed, seconds, tracer, note_setup_done):
+def run_train(family, config, cell, seed, seconds, tracer, note_setup_done):
     """Returns (facts, first-steps readings, a closure that frees the
     program's state)."""
     import jax
-    shape, traffic = shape_of(config), cell["traffic"]
+    shape, traffic = family.shape_of(config), cell["traffic"]
     net = build_net(config)
-    seed_weights(net, shape, seed)
+    seed_weights(net, family, shape, seed, master_dtype(config))
     ring = train_batches(traffic, shape["vocab"], seed)
     tap = LossTap(traffic.get("loss_lag", 2))
     net.set_listeners(tap)
-    first = first_steps(net, tap, ring, config["adam"], shape, seed)
+    first = first_steps(net, family, tap, ring, config["adam"], shape, seed)
     done_before = len(tap.losses)
 
     traced = {}
@@ -247,7 +240,7 @@ def run_train(config, cell, seed, seconds, tracer, note_setup_done):
         facts["traced"] = {
             "window_s": traced["t1"] - traced["t0"], "steps": n,
             "tokens": n * tokens_per_step,
-            "flops": n * tokens_per_step * costs.train_flops_per_token(
+            "flops": n * tokens_per_step * family.train_flops_per_token(
                 shape, traffic["seq"]),
             "before": traced["before"], "after": traced["after"]}
 
@@ -333,7 +326,7 @@ def warm_server(srv, traffic: dict, server_kw: dict, vocab: int) -> None:
 
 class _Req:
     __slots__ = ("prompt", "n_new", "h", "t_submit", "t_first", "t_done",
-                 "marks", "tokens", "error")
+                 "marks", "tokens", "error", "seen")
 
 
 def _submit(srv, item, now):
@@ -341,17 +334,30 @@ def _submit(srv, item, now):
     r = _Req()
     r.prompt, r.n_new = item
     r.t_submit, r.t_first, r.t_done = now, None, None
-    r.marks, r.tokens, r.error = {}, None, None
+    r.marks, r.tokens, r.error, r.seen = {}, None, None, 0
     with jax.profiler.TraceAnnotation("bench/submit"):
         r.h = srv.submit_async(r.prompt, n_new=r.n_new)
     return r
 
 
 def closed_loop(srv, requests, ramp_s: float, seconds: float,
-                trace_s: float, tracer, on_open, poll_s: float = 0.001):
+                trace_s: float, tracer, on_open, poll_s: float = 0.004,
+                settle_s: float = 1.0):
     """One caller per sequence in ``requests``, each sending its next
-    request when the last one returned; one thread polls them all.  The window opens after
-    ``ramp_s`` seconds of the same traffic and closes ``seconds`` later.
+    request when the last one returned; one thread polls them all, every
+    ``poll_s`` seconds (the mix's ``poll_ms``).  Each poll takes the
+    interpreter lock from the scheduler's thread: at 1 ms that cost cell
+    ``closed-decode`` nothing on one machine and 6% of its tokens on
+    another, at 4 ms nothing on either (PERF.md, PR 27), so a first
+    token is stamped up to 4 ms late.  The window opens ``ramp_s``
+    seconds into the same traffic and closes ``seconds`` later, each
+    boundary ON THE FIRST POLL THAT SEES NO NEW TOKEN AFTER ONE THAT
+    DID: tokens land a whole decode scan at a time (up to 8 ticks x 64
+    slots), and a boundary at a random moment of a scan miscounts the
+    window's tokens by up to a scan at each end (0.55% of 30 s of
+    ``closed-decode``); just after a landing, the tokens between two
+    boundaries are those of the time between them.  A boundary waits at
+    most ``settle_s`` for that.
     Returns (finished, in_flight, marks): every request keeps, under
     ``marks``, the tokens it had emitted at each boundary it was in
     flight at ('open', 'close', and 'trace0'/'trace1' in a traced run).
@@ -361,37 +367,24 @@ def closed_loop(srv, requests, ramp_s: float, seconds: float,
     clients = len(requests)
     live = [None] * clients
     finished, times = [], {}
-    t_begin = time.perf_counter()
+    t_begin, t_traced, landed_before = time.perf_counter(), None, False
 
-    def mark(name, now):
-        times[name] = now
+    def mark(name):
+        times[name] = time.perf_counter()
         for r in live:
             if r is not None:
                 r.marks[name] = r.h.emitted
 
     while True:
-        now = time.perf_counter()
-        if "open" not in times and now >= t_begin + ramp_s:
-            on_open()
-            now = time.perf_counter()
-            mark("open", now)
-            if tracer is not None:
-                tracer.start()
-                mark("trace0", time.perf_counter())
-        if (tracer is not None and "trace0" in times
-                and "trace1" not in times
-                and now >= times["trace0"] + trace_s):
-            mark("trace1", now)
-            tracer.stop()
-        if "open" in times and now >= times["open"] + seconds:
-            mark("close", now)
-            break
+        now, landed = time.perf_counter(), False
         for c in range(clients):
             r = live[c]
             if r is not None:
-                if r.t_first is None and r.h.emitted > 0:
+                done, emitted = r.h.done(), r.h.emitted
+                landed, r.seen = landed or emitted != r.seen, emitted
+                if r.t_first is None and emitted > 0:
                     r.t_first = now
-                if r.h.done():
+                if done:
                     r.t_done = now
                     if r.t_first is None:
                         r.t_first = now
@@ -405,8 +398,29 @@ def closed_loop(srv, requests, ramp_s: float, seconds: float,
             if r is None:
                 r = _submit(srv, next(feeds[c]), time.perf_counter())
             live[c] = r
-        with jax.profiler.TraceAnnotation("bench/poll_sleep"):
-            time.sleep(poll_s)
+        settled, landed_before = landed_before and not landed, landed
+
+        def due(when):
+            return now >= when and (settled or now >= when + settle_s)
+        if "open" not in times:
+            if due(t_begin + ramp_s):
+                mark("open")
+                on_open()
+                if tracer is not None:
+                    tracer.start()
+                    t_traced = time.perf_counter()
+        elif tracer is not None and "trace0" not in times:
+            if due(t_traced):
+                mark("trace0")
+        elif tracer is not None and "trace1" not in times:
+            if due(times["trace0"] + trace_s):
+                mark("trace1")
+                tracer.stop()
+        if "open" in times and due(times["open"] + seconds):
+            mark("close")
+            break
+        time.sleep(poll_s)      # no span: an idle gap is then named by
+                                # what the scheduler's thread was doing
     return finished, [r for r in live if r is not None], times
 
 
@@ -427,29 +441,20 @@ def _emitted_between(reqs, a: str, b: str, t_a: float, t_b: float):
     return out
 
 
-def _serve_work(shape, spans, t_a, t_b) -> dict:
-    """Tokens, forward operations and the decode kernel's context sum
-    of the work between two boundaries."""
-    tokens = flops = ctx_sum = 0.0
-    for r, lo, hi in spans:
-        t0 = len(r.prompt)
-        tokens += hi - lo + 1
-        if lo == 1:                     # the prefill made token 1
-            flops += costs.lm_forward_flops(shape, 1, t0, 1)
-            lo = 2
-        if hi >= lo:                    # token j attends t0 + j - 1 keys
-            flops += costs.lm_forward_flops(shape, t0 + lo - 1, t0 + hi - 1,
-                                            hi - lo + 1)
-            ctx_sum += (2 * t0 + lo + hi - 2) * (hi - lo + 1) / 2.0
-    return {"window_s": t_b - t_a, "tokens": tokens, "flops": flops,
-            "ctx_sum": ctx_sum}
+def _serve_work(family, shape, spans, t_a, t_b) -> dict:
+    """Tokens of the work between two boundaries, and what the family
+    counts for them: forward operations and its kernels' further facts."""
+    triples = [(len(r.prompt), lo, hi) for r, lo, hi in spans]
+    return {"window_s": t_b - t_a,
+            "tokens": float(sum(hi - lo + 1 for _, lo, hi in triples)),
+            **family.serve_work(shape, triples)}
 
 
-def run_serve(config, cell, seed, seconds, tracer, note_setup_done):
+def run_serve(family, config, cell, seed, seconds, tracer, note_setup_done):
     from deeplearning4j_tpu.parallel import GenerationServer
-    shape, traffic, server_kw = shape_of(config), cell["traffic"], cell["server"]
+    shape, traffic, server_kw = family.shape_of(config), cell["traffic"], cell["server"]
     net = build_net(config)
-    seed_weights(net, shape, seed)
+    seed_weights(net, family, shape, seed, master_dtype(config))
     srv = GenerationServer(net, **server_kw)
     snaps = {}
     try:
@@ -472,7 +477,8 @@ def run_serve(config, cell, seed, seconds, tracer, note_setup_done):
         finished, in_flight, times = closed_loop(
             srv, requests, traffic["ramp_seconds"],
             seconds, traffic["trace_seconds"],
-            Tr() if tracer is not None else None, on_open)
+            Tr() if tracer is not None else None, on_open,
+            poll_s=1e-3 * traffic.get("poll_ms", 4.0))
         snaps["close"] = registry_snapshot()
     finally:
         srv.shutdown(drain=False, timeout=30.0)
@@ -490,15 +496,26 @@ def run_serve(config, cell, seed, seconds, tracer, note_setup_done):
     everyone = finished + in_flight
     facts = {"requests_done": len(done), "failed": len(done) - len(ok),
              "ttft_s": ttft, "tpot_s": tpot,
+             # what the program counted over the window, for the run's
+             # stderr: the scheduler's host seconds, scans by length ...
+             "counted": {k: v - snaps["open"]["counters"].get(k, 0.0)
+                         for k, v in snaps["close"]["counters"].items()
+                         if v != snaps["open"]["counters"].get(k, 0.0)},
              "tick_failures": _delta(snaps["open"], snaps["close"],
                                      "generation_server_tick_failures_total"),
-             **_serve_work(shape, _emitted_between(
+             **_serve_work(family, shape, _emitted_between(
                  everyone, "open", "close", t_open, t_close), t_open, t_close)}
     if "trace1" in times:
         facts["traced"] = {
-            **_serve_work(shape, _emitted_between(
+            **_serve_work(family, shape, _emitted_between(
                 everyone, "trace0", "trace1", times["trace0"],
                 times["trace1"]), times["trace0"], times["trace1"]),
+            # submitted and answered inside the sub-window: the
+            # profiler's start and stop stall whoever spans them
+            "ttft_s": [r.t_first - r.t_submit for r in everyone
+                       if r.error is None and r.t_first is not None
+                       and times["trace0"] <= r.t_submit
+                       and r.t_first <= times["trace1"]],
             "before": snaps["before"], "after": snaps["after"]}
     sample = [(np.asarray(r.tokens), len(r.prompt), r.n_new)
               for r in pick_sample(ok, traffic["compare_requests"], seed)]

@@ -2,14 +2,31 @@
 ``layer_metrics/`` that names one of the reductions below and its
 arguments; a new metric that an existing reduction can read is a new
 file.  Every reader works on the traced sub-window: ``ctx`` holds its
-facts (``window_s``, ``steps``, ``tokens``, ``flops``, ``ctx_sum``),
-the registry snapshots taken at its two ends, the loaded trace, the
-chip's peaks, and the cell's shape and traffic.  A reader that finds
-nothing to read returns None and the metric is left out of the line.
+facts (``window_s``, ``steps``, ``tokens``, ``flops`` and what else
+the cell's family counts for its kernels' costs), the registry
+snapshots taken at its two ends, the loaded trace, the chip's peaks,
+and the cell's family, shape and traffic.  A reader that finds nothing
+to read returns None and the metric is left out of the line.
 """
 from __future__ import annotations
 
 from benchmark import costs, trace_reduce
+
+
+def percentile(values, q: float) -> float:
+    """The q-th percentile by linear interpolation over ALL values; a
+    failed request's infinite latency stays infinite."""
+    import math
+    v = sorted(float(x) for x in values)
+    if not v:
+        raise ValueError("no request finished in the window")
+    k = (len(v) - 1) * q / 100.0
+    lo, hi = math.floor(k), math.ceil(k)
+    if lo == hi or v[lo] == v[hi]:
+        return v[lo]
+    if math.isinf(v[hi]):
+        return v[hi]
+    return v[lo] + (v[hi] - v[lo]) * (k - lo)
 
 
 def _series_delta(ctx, sel: dict):
@@ -71,16 +88,14 @@ def trace_time_of(ctx, a):
 
 
 def _kernel_cost(ctx, a, events: int):
-    shape, tr = ctx["shape"], ctx["traffic"]
-    if a["cost"] == "paged_attention":
-        return costs.paged_attention_cost(shape, ctx["facts"]["ctx_sum"])
-    if a["cost"] in ("flash_fwd", "flash_bwd"):
-        # one kernel call covers every row and head of a layer's batch
-        calls = events / a.get("events_per_call", 1)
-        c = costs.flash_cost(shape, tr["batch"], tr["seq"], a.get("causal", False),
-                             a["cost"] == "flash_bwd")
-        return {k: v * calls for k, v in c.items()}
-    raise ValueError(f"unknown cost function {a['cost']!r}")
+    """A metric file's ``"cost": name`` is a cost function of the
+    cell's family: operations and bytes of the kernel's ``events``."""
+    family = ctx["family"]
+    if a["cost"] not in family.KERNEL_COSTS:
+        raise ValueError(f"unknown cost function {a['cost']!r}: family "
+                         f"{family.__name__} has {sorted(family.KERNEL_COSTS)}")
+    return family.KERNEL_COSTS[a["cost"]](ctx["shape"], ctx["facts"],
+                                          ctx["traffic"], events, a)
 
 
 def roofline_of(ctx, a):
@@ -94,6 +109,13 @@ def roofline_of(ctx, a):
     return 100.0 * least / secs
 
 
+def fact_percentile(ctx, a):
+    """The ``q``-th percentile of a list the driver kept for the
+    sub-window (``of``: ``ttft_s``)."""
+    values = ctx["facts"].get(a["of"])
+    return a.get("scale", 1.0) * percentile(values, a["q"]) if values else None
+
+
 def mfu_of(ctx, a):
     f = ctx["facts"]
     return 100.0 * f["flops"] / f["window_s"] / ctx["peak"]["bf16_flops_per_s"]
@@ -101,7 +123,7 @@ def mfu_of(ctx, a):
 
 READERS = {f.__name__: f for f in (registry_delta, registry_ratio,
                                    trace_busy_share, trace_time_of,
-                                   roofline_of, mfu_of)}
+                                   roofline_of, mfu_of, fact_percentile)}
 
 
 def read(metric: dict, ctx: dict):

@@ -216,14 +216,15 @@ def _change_norms(w, w0):
 
 
 def follow_training(shape: dict, adam: dict, seed: int, batches, rows: int,
-                    quant=None, batch_rows=None):
+                    quant=None, batch_rows=None, make=make_weights):
     """The first ``len(batches)`` Adam steps from the seed's weights.
     Returns the losses, the first step's gradient norms and the norms
     of the parameters' change after the last step, per leaf.
     ``batch_rows`` keeps only those rows of every batch (the planted
-    half-batch fault)."""
+    half-batch fault); ``make(shape, seed)`` is where the weights come
+    from (a family with this block and other weights gives its own)."""
     with jax.default_matmul_precision("highest"):
-        w = make_weights(shape, seed)
+        w = make(shape, seed)
         zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, w)
         m, v = zeros(), zeros()
         losses, grad_norms = [], None
@@ -239,7 +240,7 @@ def follow_training(shape: dict, adam: dict, seed: int, batches, rows: int,
             lr = adam["lr"] * min(1.0, t / adam.get("warmup", 1))
             w, m, v = _adam(w, m, v, g, jnp.float32(t), lr,
                             adam["beta1"], adam["beta2"], adam["eps"])
-        change = jax.device_get(_change_norms(w, make_weights(shape, seed)))
+        change = jax.device_get(_change_norms(w, make(shape, seed)))
     return {"losses": losses, "grad_norms": grad_norms,
             "change_norms": change}
 

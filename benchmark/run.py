@@ -15,7 +15,10 @@ cell's end-to-end metrics, ``--trace 1`` its per-layer metrics.
 
 A cell is data: ``workloads/<cell>.json`` (driver, traffic, limits),
 the configuration file BENCHMARK.json names for it, and one file under
-``layer_metrics/`` for each per-layer metric.
+``layer_metrics/`` for each per-layer metric.  Whatever depends on the
+architecture -- shape, weights, reference, costs -- comes from the
+module the configuration's ``family`` names (``families/post_ln.py``
+says what a family gives).
 """
 from __future__ import annotations
 
@@ -26,12 +29,16 @@ T_PROCESS = time.perf_counter()
 import argparse
 import json
 import os
+import re
 import shutil
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
+
+
+from benchmark.readers import percentile  # noqa: E402
 
 
 def load(*parts) -> dict:
@@ -49,31 +56,31 @@ def cell_files(name: str) -> tuple:
     return manifest, load("benchmark", "workloads", name + ".json"), load(cfg["file"])
 
 
-def percentile(values, q: float) -> float:
-    """The q-th percentile by linear interpolation over ALL values; a
-    failed request's infinite latency stays infinite."""
-    import math
-    v = sorted(float(x) for x in values)
-    if not v:
-        raise ValueError("no request finished in the window")
-    k = (len(v) - 1) * q / 100.0
-    lo, hi = math.floor(k), math.ceil(k)
-    if lo == hi or v[lo] == v[hi]:
-        return v[lo]
-    if math.isinf(v[hi]):
-        return v[hi]
-    return v[lo] + (v[hi] - v[lo]) * (k - lo)
+_TAIL = re.compile(r"^(ttft|tpot)_p(\d+)_ms$")
 
 
-def end_to_end(driver: str, facts: dict, setup_s: float) -> dict:
-    m = {"setup_s": (setup_s, "s")}
-    if driver == "train":
-        m["train_tokens_per_s"] = (facts["tokens"] / facts["window_s"], "tokens/s")
-    else:
-        m["serve_tokens_per_s"] = (facts["tokens"] / facts["window_s"], "tokens/s")
-        m["ttft_p95_ms"] = (1e3 * percentile(facts["ttft_s"], 95), "ms")
-        m["tpot_p95_ms"] = (1e3 * percentile(facts["tpot_s"], 95), "ms")
-    return m
+def end_to_end(manifest: dict, name: str, facts: dict, setup_s: float) -> dict:
+    """The end-to-end metrics BENCHMARK.json lists for the cell, each
+    by its name: ``setup_s``; ``*tokens_per_s``, all the window's
+    tokens over all its seconds; ``ttft_p<q>_ms`` / ``tpot_p<q>_ms``,
+    that percentile of ALL the window's requests."""
+    out = {}
+    for m in manifest["end_to_end"]:
+        if name not in m.get("workloads", [name]):
+            continue
+        tail = _TAIL.match(m["name"])
+        if m["name"] == "setup_s":
+            value = setup_s
+        elif m["name"].endswith("tokens_per_s"):
+            value = facts["tokens"] / facts["window_s"]
+        elif tail:
+            value = 1e3 * percentile(facts[tail.group(1) + "_s"],
+                                     int(tail.group(2)))
+        else:
+            raise SystemExit(f"run.py: no arithmetic for the end-to-end "
+                             f"metric {m['name']!r}")
+        out[m["name"]] = (value, m["unit"])
+    return out
 
 
 class Tracer:
@@ -96,14 +103,14 @@ class Tracer:
         jax.profiler.stop_trace()
 
 
-def per_layer(manifest, name, shape, traffic, facts, tracer, peak):
+def per_layer(manifest, name, family, shape, traffic, facts, tracer, peak):
     """The cell's per-layer metrics over the traced sub-window, and the
     numbers the result line's ``device`` and ``breakdown`` want."""
     from benchmark import readers, trace_reduce
     trace = trace_reduce.load(tracer.path)
     ctx = {"facts": facts["traced"], "before": facts["traced"]["before"],
            "after": facts["traced"]["after"], "trace": trace, "peak": peak,
-           "shape": shape, "traffic": traffic}
+           "family": family, "shape": shape, "traffic": traffic}
     metrics = {}
     for m in manifest["per_layer"]:
         if name in m.get("workloads", [name]):
@@ -121,23 +128,25 @@ def per_layer(manifest, name, shape, traffic, facts, tracer, peak):
         "idle_gaps": trace_reduce.idle_gaps(trace)}
 
 
-def compare(driver, config, cell, seed, shape, produced) -> dict:
-    """{name: (value, what it is about)} against the plain reference."""
-    from benchmark import correct, drivers, reference
+def compare(driver, family, config, cell, seed, shape, produced) -> dict:
+    """{name: (value, what it is about)}: what the timed path produced
+    against the plain reference of the cell's family."""
+    from benchmark import correct, drivers
     import numpy as np
     if driver == "train":
         ring = drivers.train_batches(cell["traffic"], shape["vocab"], seed)
-        ref = reference.follow_training(
+        ref = family.follow_training(
             shape, config["adam"], seed, ring[:len(produced["losses"])],
             cell["reference_rows"])
         return correct.training_numbers(produced, ref)
-    w = reference.make_weights(shape, seed)
+    w = drivers.seed_tree(family, shape, family.seed_key(seed),
+                          drivers.master_dtype(config))
     worst, about, tokens = -1.0, "no request finished", 0
     for i, (seq, t0, n_new) in enumerate(produced):
         if len(seq) != t0 + n_new:
             return {"token_gap": (float("inf"),
                                   f"request {i}: {len(seq) - t0} of {n_new} tokens")}
-        gaps = reference.served_token_gaps(w, shape["heads"], seq, t0)
+        gaps = family.served_token_gaps(w, shape, seq, t0)
         tokens += len(gaps)
         if float(gaps.max()) > worst:
             worst, about = float(gaps.max()), \
@@ -166,23 +175,35 @@ def run_cell(name, manifest, cell, config, seed, seconds, trace, devices,
     jax.monitoring.register_event_duration_secs_listener(
         lambda event, secs, **kw: compiles.append(time.perf_counter())
         if event.endswith("backend_compile_duration") else None)
-    shape = drivers.shape_of(config)
+    family = drivers.family_of(config)
+    if cell["driver"] == "train" and not hasattr(family, "follow_training"):
+        raise SystemExit(f"run.py: {name} is a train cell, and its family "
+                         f"{family.__name__} has no training reference "
+                         f"(follow_training)")
+    shape = family.shape_of(config)
     tracer = (Tracer(os.path.join(ROOT, ".bench_trace", name))
               if trace else None)
     setup = {}
     run = {"train": drivers.run_train, "serve_closed": drivers.run_serve}[cell["driver"]]
     facts, produced, free = run(
-        config, cell, seed, seconds, tracer,
+        family, config, cell, seed, seconds, tracer,
         lambda: setup.setdefault("s", time.perf_counter() - t_process))
     t_closed = time.perf_counter()
     in_window = sum(1 for t in compiles
                     if t_process + setup["s"] < t <= t_closed)
     print(f"run.py: memory_stats {devices[0].memory_stats()}", file=sys.stderr)
+    if "counted" in facts:       # a trace-0 run's look at the layers
+        print(f"run.py: counted in the window {json.dumps(facts['counted'])}",
+              file=sys.stderr)
+        print(f"run.py: ttft ms at 50/90/95/99/100% of {len(facts['ttft_s'])}: "
+              + " ".join(f"{1e3 * percentile(facts['ttft_s'], q):.1f}"
+                         for q in (50, 90, 95, 99, 100)), file=sys.stderr)
     peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                      for d in devices)
     free()
 
-    numbers = compare(cell["driver"], config, cell, seed, shape, produced)
+    numbers = compare(cell["driver"], family, config, cell, seed, shape,
+                      produced)
     ok, compared = correct.judge(numbers, cell["limits"])
     if cell["driver"] == "train":
         attempted, failed = facts["steps"], facts["failed"]
@@ -196,13 +217,13 @@ def run_cell(name, manifest, cell, config, seed, seconds, trace, devices,
     result = {"correct": bool(ok), "attempted": attempted, "failed": failed}
     if trace:
         metrics, dev, breakdown = per_layer(
-            manifest, name, shape, cell["traffic"], facts, tracer,
+            manifest, name, family, shape, cell["traffic"], facts, tracer,
             peak)
         device.update(dev)
         result["breakdown"] = breakdown
         shutil.rmtree(tracer.path, ignore_errors=True)
     else:
-        metrics = end_to_end(cell["driver"], facts, setup["s"])
+        metrics = end_to_end(manifest, name, facts, setup["s"])
     result["metrics"] = {k: {"value": v, "unit": u} for k, (v, u) in metrics.items()}
     result["device"] = device
     result["window_s"] = facts["window_s"]

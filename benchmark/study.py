@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
 """Where the limits of ``correct`` come from.  In ONE process on the
 chip, over several seeds at the cell's own size, read every compared
-number three ways: the program against the reference (the lower
-reading), the control -- the reference one precision down, float8
-matmul operands -- against the reference, and for training the planted
-half-batch fault (the upper readings).  One JSON line per seed:
+number three ways: the program against the family's reference (the
+lower reading), the control -- that reference one precision down, the
+family's ``CONTROL`` -- against the reference, and for training the
+planted half-batch fault (the upper readings).  One JSON line per seed:
 
     python3 benchmark/study.py <cell> <first seed> <seeds> [<seconds>]
 
@@ -20,7 +20,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark import correct, drivers, reference, run  # noqa: E402
+from benchmark import correct, drivers, run  # noqa: E402
 
 
 def _values(numbers: dict) -> dict:
@@ -38,19 +38,21 @@ def _worst(program: dict, ref: dict, n: int = 4) -> list:
 
 
 def study_train(config, cell, seeds):
-    shape, traffic = drivers.shape_of(config), cell["traffic"]
+    family = drivers.family_of(config)
+    shape, traffic = family.shape_of(config), cell["traffic"]
     net = drivers.build_net(config)
     tap = drivers.LossTap(traffic.get("loss_lag", 2))
     net.set_listeners(tap)
     for seed in seeds:
         t = time.perf_counter()
-        drivers.seed_weights(net, shape, seed)
+        drivers.seed_weights(net, family, shape, seed, drivers.master_dtype(config))
         tap.losses.clear()
         ring = drivers.train_batches(traffic, shape["vocab"], seed)
-        program = drivers.first_steps(net, tap, ring, config["adam"], shape, seed)
+        program = drivers.first_steps(net, family, tap, ring, config["adam"],
+                                      shape, seed)
         net.params_tree = net.opt_state = None      # room for the reference
         gc.collect()
-        follow = lambda **kw: reference.follow_training(
+        follow = lambda **kw: family.follow_training(
             shape, config["adam"], seed, ring[:3], cell["reference_rows"], **kw)
         ref = follow()
         raw = os.environ.get("STUDY_RAW_DIR")     # every leaf's norms, to look at
@@ -58,11 +60,12 @@ def study_train(config, cell, seeds):
             flat = lambda r: {k: correct._flat(r[k]) for k in ("grad_norms", "change_norms")}
             with open(os.path.join(raw, f"norms_{seed}.json"), "w") as f:
                 json.dump({"program": flat(program), "ref": flat(ref),
-                           "control_fp8": flat(follow(quant="fp8")),
+                           "control_fp8": flat(follow(quant=family.CONTROL)),
                            "half_batch": flat(follow(batch_rows=traffic["batch"] // 2))}, f)
         yield {"seed": seed, "losses": program["losses"], "ref_losses": ref["losses"],
                "program": _values(correct.training_numbers(program, ref)),
-               "control_fp8": _values(correct.training_numbers(follow(quant="fp8"), ref)),
+               "control_fp8": _values(correct.training_numbers(
+                   follow(quant=family.CONTROL), ref)),
                "half_batch": _values(correct.training_numbers(
                    follow(batch_rows=traffic["batch"] // 2), ref)),
                "quiet_leaves": sorted(correct.quiet_leaves(ref["grad_norms"])),
@@ -72,14 +75,15 @@ def study_train(config, cell, seeds):
 
 def study_serve(config, cell, seeds, seconds):
     from deeplearning4j_tpu.parallel import GenerationServer
-    shape, traffic, kw = drivers.shape_of(config), cell["traffic"], cell["server"]
+    family, dtype = drivers.family_of(config), drivers.master_dtype(config)
+    shape, traffic, kw = family.shape_of(config), cell["traffic"], cell["server"]
     net = drivers.build_net(config)
-    drivers.seed_weights(net, shape, seeds[0])
+    drivers.seed_weights(net, family, shape, seeds[0], dtype)
     with GenerationServer(net, **kw) as srv:
         drivers.warm_server(srv, traffic, kw, shape["vocab"])
         for seed in seeds:
             t = time.perf_counter()
-            drivers.seed_weights(net, shape, seed)
+            drivers.seed_weights(net, family, shape, seed, dtype)
             srv.refresh_params()
             finished, in_flight, _ = drivers.closed_loop(
                 srv, drivers.serve_requests(traffic, shape["vocab"], seed),
@@ -91,10 +95,10 @@ def study_serve(config, cell, seeds, seconds):
             picked = drivers.pick_sample(
                 [r for r in finished if r.error is None],
                 traffic["compare_requests"], seed)
-            w = reference.make_weights(shape, seed)
-            gaps = lambda quant: [reference.served_token_gaps(
-                w, shape["heads"], r.tokens, len(r.prompt), quant) for r in picked]
-            served, control = gaps(None), gaps("fp8")
+            w = drivers.seed_tree(family, shape, family.seed_key(seed), dtype)
+            gaps = lambda quant: [family.served_token_gaps(
+                w, shape, r.tokens, len(r.prompt), quant) for r in picked]
+            served, control = gaps(None), gaps(family.CONTROL)
             yield {"seed": seed, "finished": len(finished),
                    "failed": sum(r.error is not None for r in finished),
                    "tokens_compared": int(sum(len(g) for g in served)),

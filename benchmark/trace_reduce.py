@@ -15,8 +15,10 @@ import re
 
 #: the device plane's lines: operations, and whole jitted programs
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
-#: host spans the benchmark's own files write (TraceAnnotation)
-HOST_SPAN_PREFIX = "bench/"
+#: host spans kept from a capture: the benchmark's own (TraceAnnotation)
+#: and the program's scoped phases (``telemetry.span``: the scheduler's
+#: ``serve/admit|tick|retire|idle``, the fit loop's ``train/step``)
+HOST_SPAN_PREFIX = ("bench/", "serve/", "train/")
 #: an operation's name in the trace is its whole HLO line: keep the
 #: instruction's name, its shape and its opcode, not its operands
 NAME_CHARS = 160
@@ -110,7 +112,7 @@ def top_ops(trace: dict, n: int = 10) -> list:
 def idle_gaps(trace: dict, n: int = 10) -> list:
     """[[what the host was doing, seconds], ...]: the longest gaps
     between device operations on the first device, each named by the
-    innermost benchmark span that covers its middle."""
+    innermost kept host span that covers its middle."""
     if not trace["devices"]:
         return []
     lines = trace["devices"][sorted(trace["devices"])[0]]

@@ -13,6 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 from benchmark import costs, readers, run, trace_reduce  # noqa: E402
+from benchmark.families import post_ln  # noqa: E402
 
 OPS = [("%while.4 = (s32[], bf16[24,2049,16,16,64]", 0.0, 1.5),  # holds the next two (name cut short)
        ("fusion.1", 0.0, 1.0), ("flash_kernel", 0.5, 1.0),   # overlap: 0..1.5
@@ -40,6 +41,12 @@ def test_trace_reduction_on_a_hand_built_trace():
     # each gap is named by the INNERMOST benchmark span over its middle
     assert trace_reduce.idle_gaps(TRACE) == [["bench/fit", 2.0],
                                              ["bench/poll_sleep", 1.5]]
+    # the program's own scoped phases name a gap too, other spans do not
+    spans = [("serve/retire", 4.0, 1.0), ("request/queue", 4.2, 0.2),
+             ("train/step", 1.5, 1.0)]
+    kept = [s for s in spans if s[0].startswith(trace_reduce.HOST_SPAN_PREFIX)]
+    assert trace_reduce.idle_gaps(dict(TRACE, host_spans=kept)) == [
+        ["serve/retire", 2.0], ["train/step", 1.5]]
 
 
 def test_costs_against_hand_counts():
@@ -70,13 +77,34 @@ def test_a_stall_in_the_window_moves_the_tail_and_the_rate():
     assert run.percentile([0.01] * 90 + [float("inf")] * 10, 95) == float("inf")
     facts = {"tokens": 1000.0, "window_s": 10.0, "ttft_s": stalled, "tpot_s": steady}
     same_work_with_stall = dict(facts, window_s=12.5)
-    a = run.end_to_end("serve_closed", facts, 3.0)
-    b = run.end_to_end("serve_closed", same_work_with_stall, 3.0)
+    # a cell reports the end-to-end metrics the manifest lists for it,
+    # each worked out from its name
+    listed = {"end_to_end": [
+        {"name": "setup_s", "unit": "s"},
+        {"name": "serve_tokens_per_s", "unit": "tokens/s", "workloads": ["s"]},
+        {"name": "ttft_p95_ms", "unit": "ms", "workloads": ["s"]},
+        {"name": "ttft_p50_ms", "unit": "ms", "workloads": ["s"]},
+        {"name": "tpot_p95_ms", "unit": "ms", "workloads": ["s"]},
+        {"name": "train_tokens_per_s", "unit": "tokens/s", "workloads": ["t"]}]}
+    a = run.end_to_end(listed, "s", facts, 3.0)
+    b = run.end_to_end(listed, "s", same_work_with_stall, 3.0)
+    assert set(a) == {"setup_s", "serve_tokens_per_s", "ttft_p95_ms",
+                      "ttft_p50_ms", "tpot_p95_ms"}
     assert a["serve_tokens_per_s"] == (100.0, "tokens/s")
     assert b["serve_tokens_per_s"] == (80.0, "tokens/s")
     assert a["ttft_p95_ms"][0] == pytest.approx(500.0) and a["setup_s"] == (3.0, "s")
-    assert run.end_to_end("train", {"tokens": 8192 * 5, "window_s": 2.0}, 1.0)[
-        "train_tokens_per_s"] == (20480.0, "tokens/s")
+    assert a["ttft_p50_ms"][0] == pytest.approx(10.0)
+    assert a["tpot_p95_ms"][0] == pytest.approx(10.1, abs=0.1)
+    assert run.end_to_end(listed, "t", {"tokens": 8192 * 5, "window_s": 2.0}, 1.0) == {
+        "setup_s": (1.0, "s"), "train_tokens_per_s": (20480.0, "tokens/s")}
+    with pytest.raises(SystemExit, match="no arithmetic"):
+        run.end_to_end({"end_to_end": [{"name": "goodput", "unit": "%"}]}, "s", facts, 1.0)
+    # the tail beside a median that is end to end: a per-layer reader
+    tail = {"reader": {"kind": "fact_percentile", "args": {
+        "of": "ttft_s", "q": 95, "scale": 1000.0}}}
+    assert readers.read(tail, {"facts": {"ttft_s": stalled}}) == pytest.approx(500.0)
+    assert readers.read(tail, {"facts": {"ttft_s": []}}) is None
+    assert readers.read(tail, {"facts": {}}) is None
 
 
 def _manifest():
@@ -96,7 +124,7 @@ def test_every_per_layer_reader_reads_made_up_facts():
     ctx = {"facts": {"window_s": 10.0, "steps": 4, "tokens": 100.0, "flops": 250.0,
                      "ctx_sum": 10.0},
            "before": snap(0.0, 0.0, 0.0, 0.0), "after": snap(90.0, 5.0, 9.0, 0.5),
-           "trace": TRACE, "peak": PEAK, "shape": SHAPE,
+           "trace": TRACE, "peak": PEAK, "family": post_ln, "shape": SHAPE,
            "traffic": {"batch": 3, "seq": 4}}
     got = {}
     for m in _manifest()["per_layer"]:
@@ -112,7 +140,8 @@ def test_every_per_layer_reader_reads_made_up_facts():
     assert got["scan_len_mean"] == pytest.approx(9.0)
     assert got["slot_occupancy"] == pytest.approx(90.0)
     # the hand-built trace names no real kernel: silent, not 0
-    assert got["paged_attn_roofline"] is None and got["flash_fwd_roofline"] is None
+    assert got["paged_attention_roofline"] is None
+    assert got["flash_forward_roofline"] is None
     spec = {"reader": {"kind": "roofline_of", "args": {
         "pattern": "flash", "cost": "paged_attention"}}}
     assert readers.read(spec, ctx) == pytest.approx(100.0 * 64.0 / 2.0)
@@ -120,7 +149,7 @@ def test_every_per_layer_reader_reads_made_up_facts():
     assert readers.read({"reader": {"kind": "trace_time_of", "args": {
         "pattern": "scan_fn", "line": "XLA Modules", "scale": 1000.0,
         "per_events_of": {"pattern": "flash", "each": "layers"}}}}, ctx) == 1500.0
-    assert got["decode_tick_device_ms"] is None
+    assert got["decode_scan_tick_device_ms"] is None
     assert readers.read({"reader": {"kind": "registry_delta", "args": {
         "of": {"series": "absent_total"}}}}, ctx) is None
 

@@ -11,15 +11,17 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 from benchmark import drivers, reference  # noqa: E402
+from benchmark.families import post_ln  # noqa: E402
 
 GELU = {"TransformerEncoderBlock": {"activation": "gelu"}}
 TINY = dict(n_layers=2, d_model=32, n_heads=4, d_ff=64, vocab_size=64,
             max_len=64, seq_len=16, compute_dtype=None)
+FAMILY = "benchmark.families.post_ln"
 CONFIGS = {
-    "finetune": {"zoo_class": "deeplearning4j_tpu.zoo.bert.Bert",
+    "finetune": {"family": FAMILY, "zoo_class": "deeplearning4j_tpu.zoo.bert.Bert",
                  "ctor": dict(TINY, n_classes=2), "layer_overrides": GELU},
-    "causal": {"zoo_class": "deeplearning4j_tpu.zoo.gpt.Gpt", "ctor": TINY,
-               "layer_overrides": GELU},
+    "causal": {"family": FAMILY, "zoo_class": "deeplearning4j_tpu.zoo.gpt.Gpt",
+               "ctor": TINY, "layer_overrides": GELU},
 }
 
 
@@ -28,10 +30,11 @@ def test_reference_agrees_with_the_package_in_float32(which):
     import jax
     from deeplearning4j_tpu.data.dataset import DataSet
     config = CONFIGS[which]
-    shape = drivers.shape_of(config)
+    assert drivers.family_of(config) is post_ln
+    shape = post_ln.shape_of(config)
     seed = 2 ** 31 + 5                 # the driver's seeds are this large
     net = drivers.build_net(config)
-    drivers.seed_weights(net, shape, seed)
+    drivers.seed_weights(net, post_ln, shape, seed)
     w = reference.make_weights(shape, seed)
     x, onehot = drivers.train_batches(
         {"batch": 4, "seq": 16, "classes": 2, "ring": 1}, shape["vocab"], seed)[0]

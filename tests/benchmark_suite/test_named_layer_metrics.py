@@ -11,7 +11,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import readers  # noqa: E402
+from benchmark import readers, trace_reduce  # noqa: E402
+from benchmark.families import post_ln  # noqa: E402
 
 SHAPE = {"d": 8, "layers": 2, "heads": 2, "ff": 16, "vocab": 50,
          "max_len": 32, "n_out": 50}
@@ -59,7 +60,8 @@ def ctx_of(trace, before, after):
     return {"facts": {"window_s": 10.0, "steps": 1, "tokens": 100.0, "flops": 250.0,
                       "ctx_sum": 10.0},
             "before": before, "after": after, "trace": trace, "peak": PEAK,
-            "shape": SHAPE, "traffic": {"batch": 3, "seq": 4}}
+            "family": post_ln, "shape": SHAPE,
+            "traffic": {"batch": 3, "seq": 4}}
 
 
 def metric(name):
@@ -99,30 +101,49 @@ def test_named_metric_is_silent_without_its_name_or_series(name):
 def test_a_fusion_that_only_consumes_a_kernel_is_not_the_kernel():
     """``%fusion.9 = ... fusion(... %flash_fwd.3)`` names the kernel as
     an operand: the pattern is anchored at the instruction's own name."""
-    from benchmark import trace_reduce
     pattern = metric("flash_forward_roofline")["reader"]["args"]["pattern"]
     assert trace_reduce.time_of(TRACE, pattern) == (pytest.approx(2.0), 2)
 
 
+@pytest.mark.parametrize("name, args", [
+    ("paged_attention_roofline", lambda a: a),
+    ("decode_scan_tick_device_ms", lambda a: a["per_events_of"])])
+def test_the_decode_kernel_is_found_by_its_own_name_alone(name, args):
+    """The decode kernel returns three results, and the 160 characters
+    the benchmark keeps of its HLO line end before ``custom-call(``:
+    the two patterns that find it anchor on ``%paged_attention = ``."""
+    line = ("%paged_attention.12 = (bf16[1024,64]{1,0:T(8,128)(2,1)}, "
+            "bf16[786816,16,128]{2,1,0:T(8,128)(2,1)}, bf16[786816,16,128]"
+            "{2,1,0:T(8,128)(2,1)}, f32[64,16,128]{2,1,0:T(8,128)}) "
+            "custom-call(bf16[1024,64]{1,0} %fusion.1)")       # a fourth result
+    cut = line[:trace_reduce.NAME_CHARS]
+    assert "custom-call(" not in cut
+    trace = {"devices": {"/device:TPU:0": {"XLA Ops": [(cut, 0.0, 0.5)]}},
+             "host_spans": []}
+    pattern = args(metric(name)["reader"]["args"])["pattern"]
+    assert trace_reduce.time_of(trace, pattern) == (0.5, 1)
+    assert trace_reduce.time_of(TRACE, pattern) == (pytest.approx(2.0), 4)
+
+
 def test_manifest_entries_of_the_named_metrics():
-    """Six of the eight are in BENCHMARK.json.  The two that read
-    admissions wait as files: the cell's traced sub-window holds no
-    admission today, and a metric is listed only where its reader
-    finds something to read."""
+    """All eight are in BENCHMARK.json, which alone says which cells
+    report a metric: a metric's file carries no ``workloads``.  The
+    four that matched JAX's positional names are gone from both."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
     trace_read = {"flash_forward_roofline", "flash_backward_roofline",
-                  "paged_attention_roofline", "decode_scan_tick_device_ms"}
-    waiting = {"admit_device_ms", "queue_wait_mean_ms"}
-    assert waiting.isdisjoint(per_layer)
-    for name in set(HAND) - waiting:
+                  "paged_attention_roofline", "decode_scan_tick_device_ms",
+                  "admit_device_ms"}
+    assert per_layer["ttft_p95_ms.closed"]["source"] == "host_clock"
+    for name in HAND:
         m, spec = per_layer[name], metric(name)
         assert m["source"] == ("device_trace" if name in trace_read
                                else "program_counter")
-        assert (m["layer"], m["unit"], m["moves"], m["workloads"]) == (
-            spec["layer"], spec["unit"], spec["moves"], spec["workloads"])
-    for name in waiting:
-        assert "not_in_manifest" in metric(name)
-    # the four positional metrics stay until a benchmark issue retires them
-    assert {"flash_fwd_roofline", "flash_bwd_roofline", "paged_attn_roofline",
-            "decode_tick_device_ms"} <= set(per_layer)
+        assert (m["layer"], m["unit"], m["moves"]) == (
+            spec["layer"], spec["unit"], spec["moves"])
+        assert "workloads" not in spec and "not_in_manifest" not in spec
+    for name in ("flash_fwd_roofline", "flash_bwd_roofline",
+                 "paged_attn_roofline", "decode_tick_device_ms"):
+        assert name not in per_layer
+        assert not os.path.exists(os.path.join(
+            ROOT, "benchmark", "layer_metrics", name + ".json"))
