@@ -22,7 +22,11 @@ that caller.
 
 Works over any MultiLayerNetwork whose stack is
 ``EmbeddingSequenceLayer -> N x TransformerEncoderBlock(causal=True)
--> (Rnn)OutputLayer`` (e.g. ``zoo.Gpt``).  IMPORTED graphs (SameDiff
+-> (Rnn)OutputLayer`` (e.g. ``zoo.Gpt``), and -- through
+``generation_runs.RunsGenerator``, which ``TransformerGenerator(net)``
+returns for it -- over ``EmbeddingSequenceLayer -> runs of pre-norm
+Mamba / attention blocks -> TiedLMHead`` (``zoo.HybridDecoder``), whose
+decode also carries a recurrent state per row.  IMPORTED graphs (SameDiff
 IR) are NOT decodable here yet: they fine-tune through
 ``fused_attention`` but have no cached-step form — a known gap (the
 toy imported GPT is pre-LN, so it cannot be mapped onto the post-LN
@@ -391,6 +395,27 @@ def _filter_logits(logits, top_k, top_p):
     return logits
 
 
+def _cast_floating(tree, dtype):
+    """Every floating leaf of ``tree`` in ``dtype`` (a leaf already in
+    it is returned as it is: nothing is copied)."""
+    return jax.tree_util.tree_map(
+        lambda a: (a.astype(dtype)
+                   if jnp.issubdtype(a.dtype, jnp.floating) else a), tree)
+
+
+def _draw_token(logits, key, temperature, top_k, top_p):
+    """The offline scan's next token [b] from ``logits`` [b, V]: the
+    argmax, or at ``temperature`` > 0 a draw from the filtered
+    distribution.  Returns (token, key)."""
+    if temperature > 0.0:
+        key, sub = jax.random.split(key)
+        lg = _filter_logits(logits / temperature, top_k, top_p)
+        nxt = jax.random.categorical(sub, lg, axis=-1)
+    else:
+        nxt = jnp.argmax(logits, axis=-1)
+    return nxt.astype(jnp.int32), key
+
+
 class TransformerGenerator:
     """Greedy / temperature / top-k / nucleus sampling with KV caches
     over a decoder MLN.  The prompt is prefilled in ONE batched causal
@@ -401,6 +426,26 @@ class TransformerGenerator:
     >>> out = gen.generate(prompt_ids, n_new=64, temperature=0.8,
     ...                    top_k=40, top_p=0.95)
     """
+
+    def __new__(cls, net, compute_dtype: Optional[str] = None):
+        """A net whose middle layers are block RUNS of two kinds (Mamba
+        and attention: ``nn/conf/layers_hybrid.py``) decodes through
+        ``generation_runs.RunsGenerator``, which keeps this class's
+        surface; the conf-identical rule then holds within a run."""
+        if cls is TransformerGenerator:
+            from deeplearning4j_tpu.models import generation_runs
+            if generation_runs.is_run_stack(net):
+                return super().__new__(generation_runs.RunsGenerator)
+        return super().__new__(cls)
+
+    # what a server sizes its K/V pool by; ``recurrent`` describes the
+    # per-row state a stack of runs keeps besides (None here)
+    recurrent = None
+    kv_layers = property(lambda self: len(self.blocks))
+    kv_heads = property(lambda self: self.blocks[0].n_heads)
+    head_dim = property(lambda self: self.emb.n_out // self.kv_heads)
+    vocab_size = property(
+        lambda self: int(np.shape(self._params()[2]["W"])[-1]))
 
     def __init__(self, net, compute_dtype: Optional[str] = None):
         layers = list(net.layers)
@@ -639,7 +684,7 @@ class TransformerGenerator:
             # top_k=0 / top_k>vocab would SILENTLY disable filtering
             # (kth becomes the min logit); top_k is static per jit key,
             # so a plain Python check catches it here.
-            vocab = int(np.shape(self._params()[2]["W"])[-1])
+            vocab = self.vocab_size
             if not 1 <= int(top_k) <= vocab:
                 raise ValueError(
                     f"top_k={top_k} out of range [1, {vocab}] "
@@ -725,31 +770,19 @@ class TransformerGenerator:
             # bf16 math actually performed (measured 840 -> 969
             # steps/s on zoo.Gpt; the tick also carries per-op
             # overheads the byte halving cannot remove)
-            cast = lambda t: jax.tree_util.tree_map(
-                lambda a: (a.astype(self.compute_dtype)
-                           if jnp.issubdtype(a.dtype, jnp.floating)
-                           else a), t)
-            emb_p, blk_ps, head_p = cast(emb_p), cast(blk_ps), \
-                cast(head_p)
+            emb_p, blk_ps, head_p = _cast_floating(
+                (emb_p, blk_ps, head_p), self.compute_dtype)
         blk_stack = self._stack_blocks(blk_ps)
         prompt = ids[:, :t0]
         logits0, kc, vc = self._prefill(emb_p, blk_stack, head_p,
                                         prompt, L)
 
-        def sample(logits, key):
-            if temperature > 0.0:
-                key, sub = jax.random.split(key)
-                lg = _filter_logits(logits / temperature, top_k, top_p)
-                nxt = jax.random.categorical(sub, lg, axis=-1)
-            else:
-                nxt = jnp.argmax(logits, axis=-1)
-            return nxt.astype(jnp.int32), key
-
         def body(carry, pos):
             # sample the token AT pos from the previous logits, write
             # it, embed it, advance the caches
             ids, kc, vc, key, logits = carry
-            nxt, key = sample(logits, key)
+            nxt, key = _draw_token(logits, key, temperature, top_k,
+                                   top_p)
             ids = jax.lax.dynamic_update_slice(ids, nxt[:, None],
                                                (0, pos))
             logits, kc, vc = self._step(emb_p, blk_stack, head_p,

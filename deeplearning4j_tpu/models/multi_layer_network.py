@@ -110,6 +110,9 @@ class MultiLayerNetwork:
             kwargs = {}
             if getattr(ly, "USES_MASK", False):
                 kwargs["mask"] = mask
+            if getattr(ly, "tied_to", None) is not None:
+                # a head that reads another layer's table (TiedLMHead)
+                kwargs["tied"] = params[f"layer_{ly.tied_to}"]
             x, s = ly.apply(
                 params[f"layer_{i}"], state[f"layer_{i}"], x,
                 training=training, rng=keys[i], compute_dtype=compute_dtype,

@@ -251,6 +251,20 @@ _SCHED_HOST = telemetry.counter(
     labelnames=("phase",))
 _SCHED_HOST_PHASE = {p: _SCHED_HOST.labels(phase=p)
                      for p in ("admit", "retire")}
+# a prompt is prefilled at its admit bucket's length: a padded position
+# costs a matmul row in every layer and, in a recurrent layer, a step
+# of the time scan
+_PREFILL_TOKENS = telemetry.counter(
+    "generation_server_prefill_tokens_total",
+    "prompt positions the admit programs were dispatched over, by kind "
+    "(real: the prompt's or suffix's own; pad: the bucket's tail)",
+    labelnames=("kind",))
+_PREFILL_REAL = _PREFILL_TOKENS.labels(kind="real")
+_PREFILL_PAD = _PREFILL_TOKENS.labels(kind="pad")
+_REC_BYTES = telemetry.gauge(
+    "generation_server_recurrent_state_bytes",
+    "device bytes of the per-slot recurrent state (state-space layers' "
+    "h and convolution window, all slots); 0 for a K/V-only net")
 _SLOTS_BUSY = telemetry.gauge(
     "generation_server_slots_busy", "slots decoding at the last tick")
 _QDEPTH = telemetry.gauge(
@@ -713,6 +727,19 @@ class GenerationServer:
                  retry_backoff_s: float = 0.05):
         self._gen = TransformerGenerator(net, compute_dtype=compute_dtype)
         gen = self._gen
+        # a net with recurrent (state-space) layers keeps a fixed-size
+        # state per slot that K/V blocks do not hold: whatever would
+        # have to restore it from blocks refuses, by name
+        self._rec = gen.recurrent
+        if self._rec is not None:
+            n_dev = len(list(devices)) if devices is not None else 1
+            for on, what in (
+                    (prefix_cache, "prefix_cache=True"),
+                    (speculative is not None, "speculative decode"),
+                    (host_tier_blocks, "host_tier_blocks > 0"),
+                    ((tp or n_dev) > 1, "tp > 1")):
+                if on:
+                    raise ValueError(self._refusal(what))
         self.n_slots = int(n_slots)
         if self.n_slots < 1:
             raise ValueError("n_slots must be >= 1")
@@ -771,7 +798,7 @@ class GenerationServer:
         if (top_k is not None or top_p is not None) and temperature <= 0:
             raise ValueError("top_k/top_p need temperature > 0 "
                              "(greedy ignores the filtered tail)")
-        self._vocab = int(np.shape(gen._params()[2]["W"])[-1])
+        self._vocab = gen.vocab_size
         if top_k is not None and not 1 <= int(top_k) <= self._vocab:
             raise ValueError(f"top_k={top_k} out of range "
                              f"[1, {self._vocab}] (vocab size)")
@@ -801,7 +828,7 @@ class GenerationServer:
         if devices is not None:
             _refuse_cache_loaded_mesh_programs(devices)
             ctx = TpShardCtx(serving_mesh(devices, tp))
-            h = gen.blocks[0].n_heads
+            h = gen.kv_heads
             if h % ctx.tp:
                 raise ValueError(
                     f"n_heads={h} must divide by tp={ctx.tp} (the KV "
@@ -908,6 +935,14 @@ class GenerationServer:
                                               daemon=True)
             self._watchdog.start()
 
+    @staticmethod
+    def _refusal(what: str) -> str:
+        return (f"{what} is not supported for a net with recurrent "
+                "(state-space) layers: shared or restored K/V blocks "
+                "cannot restore a slot's recurrent state (snapshots of "
+                "it at block boundaries are later work), and the state "
+                "is not sharded")
+
     def _fresh_pool(self):
         """(Re)allocate the KV block pool and per-slot device state —
         every slot inactive, every block free, the prefix cache empty.
@@ -916,8 +951,9 @@ class GenerationServer:
         already be invalidated."""
         gen = self._gen
         B = self.n_slots
-        h, dh = gen.blocks[0].n_heads, self._head_dim
-        n_layers = len(gen.blocks)
+        # the pool is sized by the layers and heads that HOLD K/V
+        h, dh = gen.kv_heads, self._head_dim
+        n_layers = gen.kv_layers
         cd = gen.compute_dtype
         nb = self.kv_blocks + 1      # + block 0, the never-read
                                      # scratch sink for masked writes
@@ -968,6 +1004,14 @@ class GenerationServer:
         if self._shard is not None:
             state = {k: self._shard.put_batch(v)
                      for k, v in state.items()}
+        if self._rec is not None:
+            # the recurrent layers' per-slot state, [layers, slots, ..],
+            # d_inner on the lanes: carried, donated and reset with the
+            # pool (zeros: a slot that has seen nothing)
+            rec = gen.fresh_rec(B)
+            state["rec_h"], state["rec_conv"] = rec["h"], rec["conv"]
+        _REC_BYTES.set(sum(state[k].nbytes for k in self._REC_KEYS
+                           if k in state))
         # commit atomically: this also runs on the watchdog's recovery
         # path while the (fenced) scheduler may still be snapshotting.
         # The host allocator truth resets WITH the device pool — free
@@ -996,6 +1040,9 @@ class GenerationServer:
         _POOL_FREE.set(self.kv_blocks)
         _POOL_EVICTABLE.set(0)
 
+    #: the state leaves that are [layers, slots, ...], not [slots, ...]
+    _REC_KEYS = ("rec_h", "rec_conv")
+
     # -- public API ----------------------------------------------------
     def refresh_params(self):
         """Snapshot the net's params for serving: block params stacked
@@ -1003,7 +1050,9 @@ class GenerationServer:
         bf16) every floating leaf cast ONCE — the decode tick re-reads
         every parameter each tick, and streaming f32-stored weights
         would cost 2x the bytes of the math performed.  Call again
-        after the underlying net's weights change."""
+        after the underlying net's weights change.  A stack of block
+        runs is stacked as the net holds it: its snapshot IS the tree
+        (a same-dtype cast copies nothing), one copy of the weights."""
         gen = self._gen
         emb_p, blk_ps, head_p = gen._params()
         blk_stack = gen._stack_blocks(blk_ps)
@@ -1052,6 +1101,9 @@ class GenerationServer:
         replication, so odd vocab sizes etc. cost memory, never
         parity."""
         shard = self._shard
+        if self._rec is not None:     # one device: replicated is placed
+            return jax.tree_util.tree_map(shard.put,
+                                          (emb_p, blk_stack, head_p))
         emb_p = dict(emb_p)
         for k, axes in (("W", ("tp", None)), ("P", ("tp", None))):
             if k in emb_p:
@@ -1210,6 +1262,8 @@ class GenerationServer:
         donating dispatch on accelerator backends, so it retries
         (bounded by ``max_wait_s``) until a committed pool snapshot
         reads clean."""
+        if self._rec is not None:
+            raise ValueError(self._refusal("export_prefix"))
         prompt = np.asarray(prompt_ids, np.int32)
         if prompt.ndim != 1 or prompt.size == 0:
             return []
@@ -1252,6 +1306,8 @@ class GenerationServer:
         every later same-prefix admission then maps them copy-free.
         Entries whose chain hash is already device-resident (verified)
         are skipped.  Returns how many blocks landed."""
+        if self._rec is not None:
+            raise ValueError(self._refusal("import_blocks"))
         n = n_bytes = 0
         tier = None
         for hsh, tok, k, v in payload:
@@ -1673,6 +1729,8 @@ class GenerationServer:
         the decode replica's :meth:`import_blocks` — whose admission
         of the same prompt then prefills only the last partial
         block."""
+        if self._rec is not None:
+            raise ValueError(self._refusal("prefill_async"))
         if not self.prefix_cache:
             raise ValueError("prefill_async needs prefix_cache=True "
                              "(a prefill-only request's sole product "
@@ -1873,6 +1931,7 @@ class GenerationServer:
         pick = self._sampler(sampled)
         bs = self.block_size
         shard = self._shard
+        recurrent = self._rec is not None
 
         # the jitted callable's __name__ names the program in a
         # profile (module ``jit_decode_scan``), the scope one tick's
@@ -1894,9 +1953,16 @@ class GenerationServer:
                     tbl, (pos // bs)[:, None], axis=1)[:, 0]
                 wblk = jnp.where(active, bidx, 0)
                 woff = jnp.where(active, pos % bs, 0)
-                new_logits, kc, vc = gen._step_paged(
+                # a stack with recurrent layers also takes and gives
+                # their state; inactive slots keep theirs bit for bit (a
+                # retired slot's may not drift to inf)
+                carried = ({"rec": {"h": state["rec_h"],
+                                    "conv": state["rec_conv"]},
+                            "active": active} if recurrent else {})
+                new_logits, kc, vc, *rec = gen._step_paged(
                     emb_p, blk_stack, head_p, kc, vc, tok, pos, tbl,
-                    wblk, woff, shard=shard, kernel_writes=True)
+                    wblk, woff, shard=shard, kernel_writes=True,
+                    **carried)
                 hit_eos = active & (tok == state["eos"])
                 remaining = jnp.where(active, state["remaining"] - 1, 0)
                 remaining = jnp.where(hit_eos, 0, remaining)
@@ -1923,6 +1989,9 @@ class GenerationServer:
                     "rawlg": ((state["rawlg"] & ~active)
                               if sampled else state["rawlg"]),
                 }
+                if recurrent:
+                    state["rec_h"], state["rec_conv"] = (rec[0]["h"],
+                                                         rec[0]["conv"])
                 emitted = emitted + active.astype(jnp.int32)
                 return (kc, vc, state, emitted), tok
 
@@ -2348,7 +2417,7 @@ class GenerationServer:
 
     @property
     def _head_dim(self) -> int:
-        return self._gen.emb.n_out // self._gen.blocks[0].n_heads
+        return self._gen.head_dim
 
     def _block_to_host(self, pool, blk: int):
         """Pool block ``blk`` of every layer as host bytes
@@ -2368,9 +2437,17 @@ class GenerationServer:
         return rows if width == dh else rows[..., :dh]
 
     def _arm_slot(self, state, logits, slot, t0, n_new, eos_id, key,
-                  temp, tk, tp, table_row, dtable_row):
-        """Slot device-state update shared by both admit programs."""
+                  temp, tk, tp, table_row, dtable_row, rec=None):
+        """Slot device-state update shared by both admit programs.
+        ``rec`` (a net with recurrent layers): the prefill's recurrent
+        state of the one admitted row, as after its last real token."""
+        armed = {k: state[k] for k in self._REC_KEYS if k in state}
+        if rec is not None:
+            for k, rows in (("rec_h", rec["h"]), ("rec_conv", rec["conv"])):
+                armed[k] = jax.lax.dynamic_update_slice(
+                    state[k], rows.astype(state[k].dtype), (0, slot, 0, 0))
         return {
+            **armed,
             "pos": state["pos"].at[slot].set(t0),
             "remaining": state["remaining"].at[slot].set(n_new),
             "eos": state["eos"].at[slot].set(eos_id),
@@ -2408,10 +2485,10 @@ class GenerationServer:
                        t0, slot, n_new, eos_id, key, temp, tk, tp,
                        phys, table_row, dtable_row, *draft_ops):
             # t0 picks the last REAL position's logits out of the
-            # padded bucket
-            logits, ks, vs = gen._prefill_rows(emb_p, blk_stack,
-                                               head_p, prompt, t0,
-                                               shard=shard)
+            # padded bucket (and, of a recurrent layer, the state as
+            # after that position)
+            logits, ks, vs, *rec = gen._prefill_rows(
+                emb_p, blk_stack, head_p, prompt, t0, shard=shard)
             kc = self._scatter_rows(kc, ks, phys)
             vc = self._scatter_rows(vc, vs, phys)
             if spec is not None:
@@ -2429,7 +2506,7 @@ class GenerationServer:
                 vc = self._scatter_rows(vc, dvs, dphys)
             state = self._arm_slot(state, logits, slot, t0, n_new,
                                    eos_id, key, temp, tk, tp, table_row,
-                                   dtable_row)
+                                   dtable_row, *rec)
             return kc, vc, state
 
         fn = self._admit_cache[key] = jax.jit(admit_miss,
@@ -2584,6 +2661,8 @@ class GenerationServer:
                 sb = -(-_bucket(len(suffix), self.max_len) // bs) * bs
                 padded = np.zeros((1, sb), np.int32)
                 padded[0, :len(suffix)] = suffix
+                _PREFILL_REAL.inc(len(suffix))
+                _PREFILL_PAD.inc(sb - len(suffix))
                 n_sc = sb // bs
                 fresh = plan.phys[matched:matched + n_sc]
                 scatter_phys = np.zeros((n_sc,), np.int32)
@@ -2644,6 +2723,8 @@ class GenerationServer:
                 tb = -(-_bucket(req.t0, self.max_len) // bs) * bs
                 padded = np.zeros((1, tb), np.int32)
                 padded[0, :req.t0] = req.prompt
+                _PREFILL_REAL.inc(req.t0)
+                _PREFILL_PAD.inc(tb - req.t0)
                 n_sc = tb // bs
                 scatter_phys = np.zeros((n_sc,), np.int32)
                 head = plan.phys[:n_sc]
@@ -2848,6 +2929,12 @@ class GenerationServer:
                         & jnp.isfinite(vc).all(axis=(0, 2, 3, 4)))
                     log_fin = np.asarray(
                         jnp.isfinite(state["logits"]).all(axis=1))
+                    # a slot's recurrent state is salvaged with its
+                    # blocks; a non-finite one implicates the slot
+                    for k in self._REC_KEYS:
+                        if k in state:
+                            log_fin = log_fin & np.asarray(jnp.isfinite(
+                                state[k]).all(axis=(0, 2, 3)))
                     pos_h = np.asarray(state["pos"])
                     rem_h = np.asarray(state["remaining"])
             except (RuntimeError, ValueError):
@@ -2955,6 +3042,9 @@ class GenerationServer:
                         # with its flag (finite by the -1e30 clamp, so
                         # log_fin kept it); victims reset to plain
                         "rawlg": jnp.where(m, state["rawlg"], False),
+                        **{k: jnp.where(m[None, :, None, None], state[k],
+                                        0)
+                           for k in self._REC_KEYS if k in state},
                     }
                     n_blk_salvaged = int(bmask.sum())
                     n_blk_dropped = len(used_before

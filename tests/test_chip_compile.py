@@ -32,6 +32,7 @@ flash_mod = importlib.import_module(
     "deeplearning4j_tpu.kernels.flash_attention")
 paged_mod = importlib.import_module(
     "deeplearning4j_tpu.kernels.paged_attention")
+ssm_mod = importlib.import_module("deeplearning4j_tpu.kernels.ssm_step")
 
 # (n_heads, d_head) at d_model 768: the zoo.Gpt default and GPT-2's
 HEADS = [(6, 128), (12, 64)]
@@ -72,6 +73,7 @@ def chip_paths(monkeypatch):
     compile ``jax.default_backend()`` still says cpu."""
     monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
     monkeypatch.setattr(paged_mod, "_interpret", lambda: False)
+    monkeypatch.setattr(ssm_mod, "_interpret", lambda: False)
 
 
 def _compiled_text(fn, *shapes):
@@ -248,15 +250,19 @@ def _server_operands(srv, one_chip, n_layers=None, n_blocks=None):
     """(emb_p, blk_stack, head_p, kc, vc, state) of ``srv`` as shapes
     on the described chip.  ``n_layers`` / ``n_blocks`` DESCRIBE a
     deeper stack and a larger pool than the server staged on the host:
-    the programs take both from their operands' shapes."""
+    the programs take both from their operands' shapes (those of a
+    stack of runs take the depth from the net: leave ``n_layers``)."""
     emb_p, blk_stack, head_p = srv._params
     L = n_layers or srv._kc.shape[0]
     nb = n_blocks or srv._kc.shape[1]
     pool = jax.ShapeDtypeStruct((L, nb) + srv._kc.shape[2:],
                                 srv._kc.dtype, sharding=one_chip)
-    blk_stack = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct((L,) + np.shape(a)[1:], a.dtype,
-                                       sharding=one_chip), blk_stack)
+    if n_layers:
+        blk_stack = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct((L,) + np.shape(a)[1:], a.dtype,
+                                           sharding=one_chip), blk_stack)
+    else:
+        blk_stack = _on_chip(blk_stack, one_chip)
     return (_on_chip(emb_p, one_chip), blk_stack,
             _on_chip(head_p, one_chip), pool, pool,
             _on_chip(srv._state, one_chip))
@@ -279,6 +285,8 @@ def named_programs(one_chip):
     mp.setattr(flash_mod, "_interpret", lambda: False)
     mp.setattr(paged_mod, "_interpret", lambda: False)
     mp.setattr(paged_mod, "_route", lambda: "pallas")
+    mp.setattr(ssm_mod, "_interpret", lambda: False)
+    mp.setattr(ssm_mod, "ssm_route", lambda: "pallas")
     out = {}
 
     def S(shape, dt=jnp.int32):
@@ -321,6 +329,27 @@ def named_programs(one_chip):
                 S((1,)), *rows).compile())
         finally:
             srv.shutdown(drain=False, timeout=30.0)
+
+        # a stack of runs: Mamba x 2, attention (2 query heads on 1 K/V
+        # head of 128), Mamba -- per-slot recurrent state beside the pool
+        from deeplearning4j_tpu.zoo.hybrid_decoder import HybridDecoder
+        hybrid = MultiLayerNetwork(HybridDecoder(
+            vocab_size=128, d_model=256, n_layers=4, d_ff=512, n_heads=2,
+            n_kv_heads=1, attn_period=4, attn_offset=2, seq_len=64,
+            dtype="bfloat16").conf()).init()
+        srv = GenerationServer(hybrid, n_slots=8, max_len=64, block_size=16,
+                               tick_batch=2, compute_dtype="bfloat16",
+                               prefix_cache=False)
+        try:
+            pool = _server_operands(srv, one_chip)
+            out["hybrid_decode_scan"] = _trace_names(
+                srv._decode_scan(2, False).lower(*pool).compile())
+            out["hybrid_admit_miss"] = _trace_names(
+                srv._admit_miss_fn(64).lower(
+                    *pool, S((1, 64)), S(()), *slot, S((4,)),
+                    *rows).compile())
+        finally:
+            srv.shutdown(drain=False, timeout=30.0)
     finally:
         mp.undo()
     return out
@@ -337,7 +366,9 @@ def test_programs_and_their_kernels_carry_the_package_s_names(
         "train_step": "jit_train_step(1)",
         "decode_scan": "jit_decode_scan(1)",
         "admit_miss": "jit_admit_miss(1)",
-        "admit_hit": "jit_admit_hit(1)"}
+        "admit_hit": "jit_admit_hit(1)",
+        "hybrid_decode_scan": "jit_decode_scan(1)",
+        "hybrid_admit_miss": "jit_admit_miss(1)"}
     _, train = named_programs["train_step"]
     _, scan = named_programs["decode_scan"]
     for kernel, lines, n in (("flash_fwd", train, 2),
@@ -354,17 +385,38 @@ def test_programs_and_their_kernels_carry_the_package_s_names(
     assert "/decode_tick/" in text and "/sample/" in text
 
 
+def test_a_stack_of_runs_carries_both_kernels_names(named_programs):
+    """The hybrid net's decode scan: ``%ssm_step`` once a Mamba run
+    (two runs), the grouped ``%paged_attention`` once (one attention
+    run), each found in the 160 characters the benchmark keeps; the
+    stacked recurrent state is produced by that kernel alone -- no
+    slice, copy or write-back of a layer's state."""
+    _, scan = named_programs["hybrid_decode_scan"]
+    for kernel, n in (("ssm_step", 2), ("paged_attention", 1)):
+        assert len(_matches(rf"^%{kernel}[.\d]* = ", scan)) == n, kernel
+    both = _metric_args("ssm_tick_device_ms")["per_events_of"]["pattern"]
+    assert len(_matches(both, scan)) == 3
+    state = r"f32\[(3,8|24),16,512\]"       # [layers, slots, 16, d_inner]
+    made = [ln for ln in scan if re.match(rf"%[\w.\-]+ = \(?[^=]*{state}", ln)
+            and not re.search(r" (parameter|get-tuple-element|bitcast|tuple|"
+                              r"while|custom-call)\(", ln)]
+    assert not made, made[:3]
+    assert all(re.search(state, ln) for ln in _matches(r"^%ssm_step", scan))
+    text = "\n".join(scan)
+    assert "/ssm_update/" in text and "/paged_read/" in text
+    # the admission is plain jax.lax (a sequential scan over time)
+    _, admit = named_programs["hybrid_admit_miss"]
+    assert not _matches(both, admit)
+
+
 def _name_keyed_metrics():
     """Every file of benchmark/layer_metrics/ that finds its events by
-    a pattern, but for the four PR 24 wrote against JAX's positional
-    names (a benchmark issue retires those)."""
-    positional = {"flash_fwd_roofline", "flash_bwd_roofline",
-                  "paged_attn_roofline", "decode_tick_device_ms"}
+    a pattern."""
     names = []
     for path in sorted(glob.glob(os.path.join(
             ROOT, "benchmark", "layer_metrics", "*.json"))):
         name = os.path.basename(path)[:-len(".json")]
-        if name not in positional and "pattern" in _metric_args(name):
+        if "pattern" in _metric_args(name):
             names.append(name)
     return names
 
