@@ -9,7 +9,8 @@ from deeplearning4j_tpu.kernels.flash_attention import (
 from deeplearning4j_tpu.kernels.paged_attention import (
     pad_head_dim, paged_decode_attention, paged_decode_attention_reference,
     paged_decode_write_attention, paged_gather, paged_pool_width,
-    paged_route, paged_verify_attention, paged_verify_attention_reference)
+    paged_route, paged_verify_attention, paged_verify_attention_reference,
+    paged_walk_blocks, paged_walk_extent)
 from deeplearning4j_tpu.kernels.ssm_step import (ssm_route, ssm_step,
                                                  ssm_step_reference)
 
@@ -18,6 +19,7 @@ __all__ = ["attention", "flash_attention", "mask_to_bias", "pad_head_dim",
            "paged_decode_write_attention", "paged_gather",
            "paged_pool_width", "paged_route",
            "paged_verify_attention",
-           "paged_verify_attention_reference", "reset_route_log",
+           "paged_verify_attention_reference", "paged_walk_blocks",
+           "paged_walk_extent", "reset_route_log",
            "route_log", "ssm_route", "ssm_step", "ssm_step_reference",
            "trace_mesh", "xla_attention"]

@@ -192,7 +192,9 @@ from deeplearning4j_tpu.analysis import sanitize as _sanitize
 #: allocator spill/fetch and watchdog transitions land in the
 #: black-box ring a postmortem bundle freezes
 _FLIGHT = telemetry.get_flight_recorder()
-from deeplearning4j_tpu.kernels import pad_head_dim, paged_pool_width
+from deeplearning4j_tpu.kernels import (pad_head_dim, paged_pool_width,
+                                        paged_route, paged_walk_blocks,
+                                        paged_walk_extent)
 from deeplearning4j_tpu.models.generation import (TransformerGenerator,
                                                   _filter_logits_rows,
                                                   _filtered_logprobs_rows)
@@ -241,6 +243,18 @@ _SLOT_TICKS = telemetry.counter(
     "generation_server_slot_ticks_total",
     "active slots at dispatch x ticks of the scan (speculative "
     "dispatches: x rounds, as generation_server_ticks_total counts)")
+# how much of the decode read's walk over the block tables is context:
+# the kernel walks a slot's table in whole chunks of blocks
+# (kernels.paged_walk_blocks), the reference routes gather all of it
+_PAGED_BLOCKS = telemetry.counter(
+    "generation_server_paged_blocks_total",
+    "block-table entries the decode scans' reads covered, by kind "
+    "(live: at or before the slot's position; dead: the rest of the "
+    "walk's last chunk, or of the table on the reference routes), "
+    "summed over each active slot's live ticks at the scan's host poll",
+    labelnames=("kind",))
+_PAGED_LIVE = _PAGED_BLOCKS.labels(kind="live")
+_PAGED_DEAD = _PAGED_BLOCKS.labels(kind="dead")
 # the scheduler thread's own time between decode scans — while it
 # runs the device has nothing queued.  NOT the dispatch -> poll wait
 # (serve/tick) and NOT the blocked-on-an-empty-queue wait (serve/idle).
@@ -511,6 +525,17 @@ _AdmitPlan = namedtuple("_AdmitPlan", ("phys", "matched", "hashes",
                                        "n_fresh", "dphys", "reg_from",
                                        "fills", "dmatched"),
                         defaults=((), 0, (), 0))
+
+
+def _paged_blocks_walked(pos0, ticks, bs: int, chunk: int):
+    """(live, dead) table entries of a decode scan's reads: slot i
+    starts at position ``pos0[i]`` and runs ``ticks[i]`` live ticks, a
+    position a tick — all slots and ticks at once, by the function the
+    kernel sizes its loop with."""
+    tick = np.arange(int(ticks.max(initial=0)))[None, :]
+    live, covered = paged_walk_extent(pos0[:, None] + tick, bs, chunk)
+    ran = tick < ticks[:, None]
+    return int(live[ran].sum()), int((covered - live)[ran].sum())
 
 
 def _kill_slots(state, mask):
@@ -962,6 +987,12 @@ class GenerationServer:
         shape = (n_layers, nb, h, self.block_size,
                  paged_pool_width(dh, self._shard))
         kc, vc = jnp.zeros(shape, cd), jnp.zeros(shape, cd)
+        # table entries a decode read covers at a time: the kernel's
+        # chunk, or the whole table where the reference gathers it
+        self._walk_chunk = (
+            paged_walk_blocks(self.block_size, h, shape[-1], cd,
+                              self.max_blocks)[0]
+            if paged_route(self._shard) == "pallas" else self.max_blocks)
         if self._shard is not None:
             # pool HEADS shard along tp (each chip holds its head
             # slice of every block); the block axis stays GLOBAL —
@@ -3288,6 +3319,9 @@ class GenerationServer:
                     live_items = list(self._active.items())
                     live = [r for _, r in live_items]
                     k_drain = max(r.n_new - r.emitted for r in live)
+                    slots_h = np.fromiter(self._active, np.int64)
+                    pos_h = np.fromiter(
+                        (r.t0 + r.emitted for r in live), np.int64)
                     sampled = any(r.temperature > 0.0 for r in live)
                     spec_off = self._spec_off
                     draft_cap = self._draft_k_cap
@@ -3485,6 +3519,11 @@ class GenerationServer:
                 else:
                     _TICKS.inc(k)
                     _SCANS.labels(k=str(k)).inc()
+                    n_live, n_dead = _paged_blocks_walked(
+                        pos_h, emit_h[slots_h], self.block_size,
+                        self._walk_chunk)
+                    _PAGED_LIVE.inc(n_live)
+                    _PAGED_DEAD.inc(n_dead)
                 _TOKENS_EMITTED.inc(int(emit_h.sum()))
                 _SLOT_TICKS.inc(n_active * (R if use_spec else k))
                 _OCC.observe(n_active / self.n_slots)

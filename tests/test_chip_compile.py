@@ -545,6 +545,40 @@ def test_decode_scan_at_the_benchmark_geometry_holds_one_pool_layout(
         assert len(_matches(pattern, lines)) == 1, name
 
 
+def test_grouped_decode_kernel_compiles_at_the_reasoning_cell_s_geometry(
+        one_chip, chip_paths):
+    """``jamba2-3b.closed-reasoning``'s read: 256 slots, 20 query heads
+    on ONE K/V head of 128, 8-entry tables of 128-token blocks, a pool
+    of 2 layers x 2,049 blocks, bf16 — the walk is four blocks a chunk
+    there, through eight buffers (``paged_walk_blocks``).  The pool goes through the kernel
+    aliased, in one layout, and the name-keyed metrics find the kernel
+    in what the benchmark keeps of its name."""
+    B, hq, h, dh, bs, mb = 256, 20, 1, 128, 128, 8
+    pool_shape = (2, 2049, h, bs, 128)
+    assert paged_mod.paged_walk_blocks(bs, h, 128, jnp.bfloat16, mb) == (4, 8)
+
+    def S(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = S(pool_shape, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, kn, vn, kp, vp, t, p, wb, wo, lay:
+        paged_mod._paged_decode_write_pallas(q, kn, vn, kp, vp, t, p, wb,
+                                             wo, lay, dh ** -0.5),
+        donate_argnums=(3, 4)).lower(
+        S((B, hq, dh), jnp.bfloat16), S((B, h, dh), jnp.bfloat16),
+        S((B, h, dh), jnp.bfloat16), pool, pool, S((B, mb)), S((B,)),
+        S((B,)), S((B,)), S(())).compile()
+    _, lines = _trace_names(compiled)
+    assert _pool_producers(lines, pool_shape) == []
+    for name in ("paged_attention_roofline", "ssm_tick_device_ms"):
+        args = _metric_args(name)
+        pattern = args.get("per_events_of", args)["pattern"]
+        assert len(_matches(pattern, lines)) == 1, name
+    kernel, = _matches(r"^%paged_attention[.\d]* = .*custom-call\(", lines)
+    assert kernel.count("bf16[4098,128,128]") >= 2      # both pools, whole
+
+
 def test_admission_at_the_benchmark_geometry_scatters_in_place(
         benchmark_geometry_programs):
     """``jit_admit_miss`` paid the same entry and exit copies (39 ms an

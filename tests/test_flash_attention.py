@@ -396,9 +396,9 @@ _WRITE_CASES = {
 
 
 def _write_case(case, L=3, h=4, dh=8, bs=4, mb=4, dtype=jnp.float32,
-                seed=0, width=None):
+                seed=0, width=None, g=1):
     """``width`` > dh: a kernel-route pool, its rows padded with zeros
-    to the lane width."""
+    to the lane width; ``g`` query heads a K/V head."""
     rng = np.random.default_rng(seed)
     B = len(case)
     nb = B * mb + 1
@@ -406,8 +406,8 @@ def _write_case(case, L=3, h=4, dh=8, bs=4, mb=4, dtype=jnp.float32,
     pad = [(0, 0)] * 4 + [(0, (width or dh) - dh)]
     kp = jnp.pad(jnp.asarray(rng.normal(size=shape), dtype), pad)
     vp = jnp.pad(jnp.asarray(rng.normal(size=shape), dtype), pad)
-    q, kn, vn = (jnp.asarray(rng.normal(size=(B, h, dh)), dtype)
-                 for _ in range(3))
+    q, kn, vn = (jnp.asarray(rng.normal(size=(B, heads, dh)), dtype)
+                 for heads in (h * g, h, h))
     tbl = np.zeros((B, mb), np.int32)
     pos, wblk, woff = (np.zeros((B,), np.int32) for _ in range(3))
     for s, (p, state) in enumerate(case):
@@ -451,6 +451,114 @@ def test_paged_write_kernel_matches_scatter_then_reference(case):
     bit, the output the reference's on the scattered pool."""
     _assert_write_read_matches_scatter_then_reference(
         _write_case(_WRITE_CASES[case]), layer=1, atol=2e-5)
+
+
+# the walk's own edges: 24-entry tables of 16-token blocks under four
+# K/V heads, which the kernel walks 8 blocks (128 positions) a chunk,
+# so a context of 384 spans three chunks
+_WALK = dict(L=2, h=4, dh=8, bs=16, mb=24)
+_WALK_CASES = {
+    "ends_on_a_block_s_last_row": [(63, "live"), (15, "live"),
+                                   (271, "live")],
+    "ends_on_a_chunk_s_last_block": [(117, "live"), (255, "live"),
+                                     (127, "live")],
+    "ends_in_a_chunk_s_first_block": [(128, "live"), (259, "live"),
+                                      (143, "live")],
+    "one_live_block": [(0, "live"), (9, "live"), (15, "live")],
+    "full_table": [(383, "live"), (382, "live")],
+    "free_and_retired_between_live": [(200, "live"), (0, "free"),
+                                      (300, "live"), (0, "retired"),
+                                      (130, "live")],
+    # block 8 is alone in the second chunk, block 16 in the third
+    "write_block_first_and_last_in_its_chunk": [(129, "live"),
+                                                (256, "live"),
+                                                (140, "live")],
+    "every_slot_free": [(0, "free"), (0, "free")],
+}
+
+
+# ONE K/V head (``jamba2-3b``'s): a chunk wants 512 positions, and is
+# 24 of the 48 table entries here
+_WALK_ONE_HEAD = {**_WALK, "h": 1, "dh": 64, "mb": 48}
+_WALK_ONE_HEAD_CASES = {
+    "around_the_chunk_s_edge": [(383, "live"), (384, "live"),
+                                (399, "live")],
+    "full_table_and_one_block": [(767, "live"), (7, "live")],
+    "free_and_retired_between_live": [(400, "live"), (0, "free"),
+                                      (0, "retired"), (20, "live")],
+}
+
+
+def test_walk_cases_span_chunks():
+    from deeplearning4j_tpu.kernels import paged_walk_blocks
+    assert paged_walk_blocks(_WALK["bs"], _WALK["h"], _WALK["dh"],
+                             jnp.float32, _WALK["mb"])[0] == 8
+    assert paged_walk_blocks(16, 1, 128, jnp.bfloat16, 48)[0] == 24
+
+
+@pytest.mark.parametrize("g", [1, 3], ids=["a_head_a_head", "grouped"])
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_paged_write_kernel_walks_live_chunks_only(case, g):
+    """Tables longer than a chunk: the kernel's own copies, the chunk
+    loop's trip count and the patched block's way back, at the edges
+    the walk has; grouped, 3 query heads read each K/V head."""
+    _assert_write_read_matches_scatter_then_reference(
+        _write_case(_WALK_CASES[case], g=g, **_WALK), layer=1, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_ONE_HEAD_CASES))
+def test_paged_write_kernel_grouped_on_one_kv_head(case):
+    """Multi-query attention as ``jamba2-3b`` runs it: ONE K/V head, 5
+    query heads on it, bfloat16 in a lane-wide pool."""
+    _assert_write_read_matches_scatter_then_reference(
+        _write_case(_WALK_ONE_HEAD_CASES[case], g=5, dtype=jnp.bfloat16,
+                    width=128, **_WALK_ONE_HEAD), layer=0, atol=2e-2)
+
+
+# (bs, h, width, mb, slots' query heads): the benchmark's two serving
+# cells, then the server's defaults (float32 pool, 128-entry tables)
+@pytest.mark.parametrize("bs,h,width,dtype,mb,walk", [
+    (16, 16, 128, jnp.bfloat16, 32, (8, 3)),
+    (128, 1, 128, jnp.bfloat16, 8, (4, 8)),
+    (16, 12, 128, jnp.float32, 128, (8, 3)),
+    (16, 2, 8, jnp.float32, 3, (3, 8)),
+    (16, 64, 256, jnp.float32, 32, (2, 2))],
+    ids=["closed_decode", "closed_reasoning", "server_default",
+         "table_shorter_than_a_chunk", "vmem_bound"])
+def test_paged_walk_blocks_reads_the_walk_from_the_shapes(bs, h, width,
+                                                          dtype, mb, walk):
+    """(blocks a chunk, buffers): a chunk divides the table (or is all
+    of it) and holds 128 positions a K/V head, 512 where one head is
+    all there is, as far as the table and the VMEM budget allow; two
+    buffers at least, and all of them inside the budget."""
+    from deeplearning4j_tpu.kernels import paged_attention as pa
+    chunk, buffers = pa.paged_walk_blocks(bs, h, width, dtype, mb)
+    assert (chunk, buffers) == walk and mb % chunk == 0
+    block = 2 * h * bs * width * jnp.dtype(dtype).itemsize
+    assert 2 <= buffers <= 8
+    assert buffers * chunk * block <= pa._WALK_VMEM_BYTES
+    assert chunk == mb or chunk * bs <= 128 * max(1, 4 // h)
+
+
+@pytest.mark.parametrize("chunk", [1, 8, 32])
+def test_scheduler_counts_the_blocks_the_kernel_s_loop_covers(chunk):
+    """``generation_server_paged_blocks_total``: live + dead of a scan
+    are the entries the kernel's chunk loop covers, tick by tick, for
+    slots that start at made-up positions and stop after made-up
+    numbers of ticks; ``chunk`` = the table: the reference's gather."""
+    from deeplearning4j_tpu.parallel.generation_server import \
+        _paged_blocks_walked
+    bs, mb = 16, 32
+    pos0 = np.asarray([0, 15, 16, 127, 128, 300, 503])
+    ticks = np.asarray([8, 1, 0, 3, 8, 8, 8])
+    live = dead = 0
+    for p0, n in zip(pos0, ticks):
+        for p in range(p0, p0 + n):
+            here = sum(j * bs <= p for j in range(mb))
+            loops = -(-here // chunk)            # the kernel's trip count
+            live, dead = live + here, dead + loops * chunk - here
+    assert _paged_blocks_walked(pos0, ticks, bs, chunk) == (live, dead)
+    assert _paged_blocks_walked(pos0[:0], ticks[:0], bs, chunk) == (0, 0)
 
 
 @pytest.mark.parametrize("layer", [0, 1, 2])

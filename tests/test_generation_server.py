@@ -317,6 +317,39 @@ def test_host_syncs_amortized_by_scan(net):
     assert ticks.value - t0 == 16
 
 
+@pytest.mark.parametrize("route", ["reference", "pallas"])
+def test_paged_blocks_counter_follows_the_route_s_walk(net, monkeypatch,
+                                                       route):
+    """``generation_server_paged_blocks_total``: ``live`` counts the
+    table entries at or before each decoded position; ``dead`` the
+    rest of what the read covered — the whole 4-entry table on the
+    reference route (a gather), the kernel's chunks on the kernel
+    route (here the 32-position table is one chunk: the same count,
+    by ``kernels.paged_walk_blocks``)."""
+    from deeplearning4j_tpu.kernels import paged_walk_blocks
+    monkeypatch.setenv("DL4J_TPU_PAGED_KERNEL", route)
+    blocks = telemetry.get_registry().get(
+        "generation_server_paged_blocks_total")
+    count = lambda: (blocks.labels(kind="live").value,
+                     blocks.labels(kind="dead").value)
+    reqs = [([1, 2, 3], 16), ([4, 5, 6, 7, 8, 9, 10, 11, 12], 9)]
+    with GenerationServer(net, n_slots=2, max_len=32, tick_batch=8,
+                          block_size=8, tick_timeout_s=None) as srv:
+        chunk = srv._walk_chunk
+        assert chunk == (4 if route == "reference" else paged_walk_blocks(
+            8, srv._kc.shape[2], srv._kc.shape[-1], srv._kc.dtype, 4)[0])
+        before = count()
+        for h in [srv.submit_async(np.asarray(p, np.int32), n)
+                  for p, n in reqs]:
+            h.result(timeout=600)
+        live, dead = (a - b for a, b in zip(count(), before))
+    # a request's tokens are written at positions t0 .. t0 + n_new - 1
+    want = [p // 8 + 1 for t0, n in ((3, 16), (9, 9))
+            for p in range(t0, t0 + n)]
+    assert live == sum(want)
+    assert dead == sum(-(-w // chunk) * chunk - w for w in want)
+
+
 def test_useful_share_and_scheduler_host_counters(net):
     """ISSUE 25's counters, where the work happens: tokens emitted ==
     what the handles returned, slot-ticks == sum over dispatches of
