@@ -163,8 +163,8 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_table,
     """One-query-per-slot attention through the block table, stripe
     math: gather the table into the contiguous view, then the same
     f32-score / -1e9-mask / f32-softmax sequence as the stripe decode
-    step (``_block_decode_step``) — byte parity with offline decode
-    depends on mirroring it exactly."""
+    step (``TransformerGenerator._step``'s dense ``attend``) — byte
+    parity with offline decode depends on mirroring it exactly."""
     kl = paged_gather(k_pool, block_table)
     vl = paged_gather(v_pool, block_table)
     L = kl.shape[2]

@@ -1,39 +1,52 @@
 """KV-cache incremental decoding — the transformer analogue of DL4J's
 ``rnnTimeStep`` (``MultiLayerNetwork.rnnTimeStep`` keeps per-layer
 recurrent state between calls; here the state is each block's key/value
-cache).
+cache and, for a state-space block, its recurrent state).
 
 TPU-first design: generation is ONE jitted ``lax.scan`` over time with
-static shapes — the KV caches are preallocated [n_layers, b, h,
-max_len, dh] buffers written via ``lax.dynamic_update_slice``, the
-prompt prefills in ONE batched causal forward (matmul-rate, not the
-per-step params-bandwidth floor), and sampling scans one token per
-tick — the whole decode is a single XLA program, no per-token Python
-dispatch or retrace.  The homogeneous block params are stacked on a
-leading [n_layers] axis and BOTH the prefill and the decode tick
-``lax.scan`` over layers, so the program size is O(1) in depth instead
-of inlining n_layers copies of the block body.
+static shapes — the KV caches are preallocated [kv_layers, b, kv_heads,
+max_len, head_dim] buffers, the prompt prefills in ONE batched causal
+forward (matmul-rate, not the per-step params-bandwidth floor), and
+sampling scans one token per tick — the whole decode is a single XLA
+program, no per-token Python dispatch or retrace.
 
-Concurrent serving over this machinery (many callers multiplexed onto
-one decode tick, Orca-style continuous batching) lives in
-``parallel/generation_server.py`` — ``_embed_token``/
-``_block_decode_step`` accept per-row position VECTORS for exactly
-that caller.
+Nothing of a block is written here.  The stack between the embedding
+and the head is a list of RUNS of identical blocks, the parameters of a
+run stacked on a leading [layers] axis: prefill scans each run's
+``sequence()`` and a decode tick scans each run's ``step()``, so the
+program size is O(1) in a run's depth instead of inlining a copy of the
+block body per layer.  What differs between the callers is how
+attention reaches its keys, and that is handed to ``step()`` as
+``attend``: a dense cache offline (``_step``), the paged pool in
+``parallel/generation_server.py`` (``_step_paged``: many callers
+multiplexed onto one decode tick, Orca-style continuous batching), the
+W rows of a speculative verify (``_verify_rows_paged``).
 
-Works over any MultiLayerNetwork whose stack is
-``EmbeddingSequenceLayer -> N x TransformerEncoderBlock(causal=True)
--> (Rnn)OutputLayer`` (e.g. ``zoo.Gpt``), and -- through
-``generation_runs.RunsGenerator``, which ``TransformerGenerator(net)``
-returns for it -- over ``EmbeddingSequenceLayer -> runs of pre-norm
-Mamba / attention blocks -> TiedLMHead`` (``zoo.HybridDecoder``), whose
-decode also carries a recurrent state per row.  IMPORTED graphs (SameDiff
-IR) are NOT decodable here yet: they fine-tune through
-``fused_attention`` but have no cached-step form — a known gap (the
-toy imported GPT is pre-LN, so it cannot be mapped onto the post-LN
-zoo blocks either).
+Two stacks decode here:
+
+* ``EmbeddingSequenceLayer -> N x TransformerEncoderBlock(causal=True)
+  -> (Rnn)OutputLayer`` (e.g. ``zoo.Gpt``): the N conf-identical post-LN
+  blocks are ONE run, their parameters stacked by ``_stack_blocks``;
+* ``EmbeddingSequenceLayer -> runs of pre-norm Mamba / attention blocks
+  -> TiedLMHead`` (``nn/conf/layers_hybrid.py``; e.g.
+  ``zoo.HybridDecoder``), stacked as the net holds them, so a snapshot
+  of them is the tree itself.  Beside the K/V cache of its attention
+  layers its decode carries a fixed-size RECURRENT state per row:
+
+      rec = {"h":    [rec_layers, b, d_state, d_inner] float32,
+             "conv": [rec_layers, b, d_conv - 1, d_inner] compute dtype}
+
+  ``rec`` is ``None`` wherever a stack keeps no such state.
+
+IMPORTED graphs (SameDiff IR) are NOT decodable here yet: they fine-tune
+through ``fused_attention`` but have no cached-step form — a known gap
+(the toy imported GPT is pre-LN with biases, so it maps onto neither
+kind of block).
 """
 from __future__ import annotations
 
+import dataclasses
+import math
 import time
 from typing import Optional
 
@@ -43,6 +56,9 @@ import numpy as np
 
 from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.nn.conf.layers_core import OutputLayer
+from deeplearning4j_tpu.nn.conf.layers_hybrid import (AttentionBlockRun,
+                                                      MambaBlockRun,
+                                                      TiedLMHead)
 from deeplearning4j_tpu.nn.conf.layers_transformer import (
     EmbeddingSequenceLayer, TransformerEncoderBlock, _layer_norm)
 
@@ -68,7 +84,8 @@ _GEN_TIME = telemetry.histogram(
 def _embed_token(ly: EmbeddingSequenceLayer, params, tok, pos):
     """[b] int token -> [b, d].  ``pos`` is a scalar (one shared
     position, the offline decode scan) or a [b] int32 vector (per-row
-    positions, the continuous-batching server's slots)."""
+    positions: the server's slots, a verify's flat rows).  Also [b, s]
+    tokens at a [s] vector of positions (a prompt's uncached suffix)."""
     y = jnp.take(params["W"], tok.astype(jnp.int32), axis=0)
     if ly.add_positional:
         if jnp.ndim(pos) == 0:
@@ -81,168 +98,6 @@ def _embed_token(ly: EmbeddingSequenceLayer, params, tok, pos):
     return y
 
 
-def _block_decode_step(ly: TransformerEncoderBlock, params, kcache,
-                       vcache, x, pos):
-    """One cached decoder step.  x: [b, d] new-token hidden; caches
-    [b, h, L, dh]; writes position ``pos``, attends over <= pos.
-    ``pos`` may be a scalar (whole batch at one position) or a [b]
-    vector (per-row positions — slots in the generation server decode
-    at independent depths inside ONE static-shape program).
-    Returns (y [b, d], kcache, vcache)."""
-    b, d = x.shape
-    h, dh = ly.n_heads, d // ly.n_heads
-    L = kcache.shape[2]
-    cast = lambda w: w.astype(x.dtype)
-
-    qkv = x @ cast(params["Wqkv"]) + cast(params["bqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    split = lambda z: z.reshape(b, h, 1, dh)
-    q, k, v = split(q), split(k), split(v)
-    if jnp.ndim(pos) == 0:
-        kcache = jax.lax.dynamic_update_slice(kcache, k, (0, 0, pos, 0))
-        vcache = jax.lax.dynamic_update_slice(vcache, v, (0, 0, pos, 0))
-        valid = (jnp.arange(L) <= pos)[None, None, None, :]
-    else:
-        write = jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
-            c, n, (0, p, 0)))
-        kcache = write(kcache, k, pos)
-        vcache = write(vcache, v, pos)
-        valid = (jnp.arange(L)[None, :]
-                 <= pos[:, None])[:, None, None, :]
-
-    scale = 1.0 / (dh ** 0.5)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, kcache).astype(jnp.float32)
-    s = s * scale
-    s = jnp.where(valid, s, -1e9)
-    p = jax.nn.softmax(s, axis=-1).astype(vcache.dtype)
-    att = jnp.einsum("bhqk,bhkd->bhqd", p, vcache)
-    att = att.transpose(0, 2, 1, 3).reshape(b, d)
-    att = att @ cast(params["Wo"]) + cast(params["bo"])
-    hdn = _layer_norm(x + att, params["ln1_g"], params["ln1_b"], ly.eps)
-
-    from deeplearning4j_tpu.nn.activations import get_activation
-    act = get_activation(ly.activation or "gelu")
-    ffn = act(hdn @ cast(params["W1"]) + cast(params["b1"]))
-    ffn = ffn @ cast(params["W2"]) + cast(params["b2"])
-    y = _layer_norm(hdn + ffn, params["ln2_g"], params["ln2_b"], ly.eps)
-    return y, kcache, vcache
-
-
-def _block_decode_step_paged(ly: TransformerEncoderBlock, params,
-                             kpool, vpool, x, pos, table, wblk, woff,
-                             shard=None, layer=None):
-    """Paged-cache variant of ``_block_decode_step``: the slot's K/V
-    live in pool blocks routed by a block table instead of a
-    contiguous stripe.  x: [b, d] new-token hidden; ``kpool``/``vpool``
-    [n_blocks, h, block_size, dh]; ``table`` [b, max_blocks] int32;
-    the new K/V row lands at (``wblk``, ``woff``) per slot — the
-    caller masks inactive slots to the scratch block 0 — and attention
-    reads THROUGH the table (``kernels.paged_decode_attention``; the
-    reference path mirrors the stripe step's f32-score/-1e9-mask math
-    exactly, which is what byte parity with offline decode rests on).
-
-    ``shard`` (a ``parallel.mesh.TpShardCtx``, or None = identity) is
-    the mesh-sharded tick's parity contract: weights arrive with their
-    OUTPUT columns sharded along ``tp`` (heads ride along when qkv
-    splits), and ``shard.rep`` gathers the feature axis back to full
-    replication at EXACTLY the points where the math reduces over it —
-    before ``@ Wo``, both layer norms, and ``@ W2`` — so no device
-    ever sums a partial feature axis.
-
-    ``layer`` (a traced index, kernel route only) says the pools are
-    the WHOLE [n_layers, n_blocks, h, block_size, width] ones: the
-    kernel then writes the row itself and XLA never touches the pool
-    (``kernels.paged_decode_write_attention``).  A kernel-route pool's
-    rows are ``kernels.paged_pool_width(dh)`` wide, zero past ``dh``.
-    Returns (y [b, d], kpool, vpool)."""
-    from deeplearning4j_tpu.kernels import (pad_head_dim,
-                                            paged_decode_attention,
-                                            paged_decode_write_attention)
-    rep = shard.rep if shard is not None else (lambda t: t)
-    b, d = x.shape
-    h, dh = ly.n_heads, d // ly.n_heads
-    cast = lambda w: w.astype(x.dtype)
-
-    qkv = x @ cast(params["Wqkv"]) + cast(params["bqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    split = lambda z: z.reshape(b, h, dh)
-    q, k, v = split(q), split(k), split(v)
-    if layer is None:
-        width = kpool.shape[-1]     # past dh on the kernel route
-        kpool = kpool.at[wblk, :, woff, :].set(pad_head_dim(k, width))
-        vpool = vpool.at[wblk, :, woff, :].set(pad_head_dim(v, width))
-        att = paged_decode_attention(q, kpool, vpool, table, pos,
-                                     scale=1.0 / (dh ** 0.5), shard=shard)
-    else:
-        att, kpool, vpool = paged_decode_write_attention(
-            q, k, v, kpool, vpool, table, pos, wblk, woff, layer,
-            scale=1.0 / (dh ** 0.5))
-    att = rep(att.reshape(b, d))
-    att = att @ cast(params["Wo"]) + cast(params["bo"])
-    hdn = _layer_norm(rep(x + att), params["ln1_g"], params["ln1_b"],
-                      ly.eps)
-
-    from deeplearning4j_tpu.nn.activations import get_activation
-    act = get_activation(ly.activation or "gelu")
-    ffn = act(hdn @ cast(params["W1"]) + cast(params["b1"]))
-    ffn = rep(ffn) @ cast(params["W2"]) + cast(params["b2"])
-    y = _layer_norm(rep(hdn + ffn), params["ln2_g"], params["ln2_b"],
-                    ly.eps)
-    return y, kpool, vpool
-
-
-def _block_verify_step_paged(ly: TransformerEncoderBlock, params,
-                             kpool, vpool, x, table, wblk, woff, pos0,
-                             shard=None):
-    """W-token verification step for speculative decode: one block's
-    forward over a chunk of W tokens per slot, K/V written through the
-    block table at (``wblk``, ``woff``) [B, W] and attention read back
-    through :func:`~deeplearning4j_tpu.kernels.paged_verify_attention`
-    with query row j at position ``pos0 + j``.
-
-    ``x`` is FLAT [B*W, d] — every matmul and layer norm here runs at
-    the 2-D shapes that are row-bitwise-stable on the backends (the
-    decode step's [b, d] @ W and a [B*W, d] @ W agree per row where a
-    [B, W, d] batched contraction need not), and the attention unrolls
-    per query row inside the kernel's reference path.  Together that
-    makes this chunked step's outputs AND cache writes byte-identical
-    to W sequential ``_block_decode_step_paged`` ticks — the invariant
-    speculative greedy parity rests on.  ``shard`` replicates feature
-    axes before their reductions exactly as in
-    ``_block_decode_step_paged`` (the flat [B*W, d] rows keep their
-    batch axis on ``data``)."""
-    rep = shard.rep if shard is not None else (lambda t: t)
-    BW, d = x.shape
-    B, W = wblk.shape
-    h, dh = ly.n_heads, d // ly.n_heads
-    from deeplearning4j_tpu.kernels import (pad_head_dim,
-                                            paged_verify_attention)
-    cast = lambda w: w.astype(x.dtype)
-
-    qkv = x @ cast(params["Wqkv"]) + cast(params["bqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    split = lambda z: z.reshape(B, W, h, dh)
-    q, k, v = split(q), split(k), split(v)
-    width = kpool.shape[-1]         # past dh on the kernel route
-    kpool = kpool.at[wblk, :, woff, :].set(pad_head_dim(k, width))
-    vpool = vpool.at[wblk, :, woff, :].set(pad_head_dim(v, width))
-
-    att = paged_verify_attention(q, kpool, vpool, table, pos0,
-                                 scale=1.0 / (dh ** 0.5), shard=shard)
-    att = rep(att.reshape(BW, d))
-    att = att @ cast(params["Wo"]) + cast(params["bo"])
-    hdn = _layer_norm(rep(x + att), params["ln1_g"], params["ln1_b"],
-                      ly.eps)
-
-    from deeplearning4j_tpu.nn.activations import get_activation
-    act = get_activation(ly.activation or "gelu")
-    ffn = act(hdn @ cast(params["W1"]) + cast(params["b1"]))
-    ffn = rep(ffn) @ cast(params["W2"]) + cast(params["b2"])
-    y = _layer_norm(rep(hdn + ffn), params["ln2_g"], params["ln2_b"],
-                    ly.eps)
-    return y, kpool, vpool
-
-
 def _embed_prompt(ly: EmbeddingSequenceLayer, params, ids):
     """[b, t0] int prompt -> [b, t0, d] (positions 0..t0-1)."""
     y = jnp.take(params["W"], ids.astype(jnp.int32), axis=0)
@@ -251,90 +106,6 @@ def _embed_prompt(ly: EmbeddingSequenceLayer, params, ids):
     if ly.layer_norm:
         y = _layer_norm(y, params["g"], params["b"], ly.eps)
     return y
-
-
-def _block_prefill(ly: TransformerEncoderBlock, params, x, shard=None):
-    """Whole-prompt causal forward for one block: x [b, t, d] ->
-    (y [b, t, d], k [b, h, t, dh], v) — ONE batched pass instead of t
-    cached single-token steps, so prefill runs at matmul rate instead
-    of the per-step params-bandwidth floor.  Same math (f32 scores,
-    -1e9 mask) as ``_block_decode_step``.  ``shard`` replicates the
-    feature axis before its reductions (mesh-sharded admissions; the
-    returned K/V rows stay head-sharded for the pool scatter)."""
-    rep = shard.rep if shard is not None else (lambda t: t)
-    b, t, d = x.shape
-    h, dh = ly.n_heads, d // ly.n_heads
-    cast = lambda w: w.astype(x.dtype)
-    qkv = x @ cast(params["Wqkv"]) + cast(params["bqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    split = lambda z: z.reshape(b, t, h, dh).transpose(0, 2, 1, 3)
-    q, k, v = split(q), split(k), split(v)
-    scale = 1.0 / (dh ** 0.5)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    rows = jnp.arange(t)[:, None]
-    cols = jnp.arange(t)[None, :]
-    s = jnp.where((cols <= rows)[None, None], s, -1e9)
-    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    att = jnp.einsum("bhqk,bhkd->bhqd", p, v)
-    att = rep(att.transpose(0, 2, 1, 3).reshape(b, t, d))
-    att = att @ cast(params["Wo"]) + cast(params["bo"])
-    hdn = _layer_norm(rep(x + att), params["ln1_g"], params["ln1_b"],
-                      ly.eps)
-    from deeplearning4j_tpu.nn.activations import get_activation
-    act = get_activation(ly.activation or "gelu")
-    ffn = act(hdn @ cast(params["W1"]) + cast(params["b1"]))
-    ffn = rep(ffn) @ cast(params["W2"]) + cast(params["b2"])
-    y = _layer_norm(rep(hdn + ffn), params["ln2_g"], params["ln2_b"],
-                    ly.eps)
-    return y, k, v
-
-
-def _block_prefill_chunked(ly: TransformerEncoderBlock, params, x,
-                           pk, pv, p0, shard=None):
-    """Chunked (suffix) causal forward for one block: the query rows
-    are the UNCACHED prompt suffix at global positions p0..p0+s-1 and
-    the key set is [cached prefix K/V ; suffix K/V].  x: [b, s, d];
-    ``pk``/``pv``: [b, h, P, dh] gathered prefix rows (valid cols
-    < ``p0`` — the pad tail up to P is masked).  Same f32-score /
-    -1e9-mask / f32-softmax math as ``_block_prefill``; masked columns
-    contribute EXACT zeros to the softmax, so the suffix rows come out
-    byte-identical to the full-prompt prefill's — the prefix-cache hit
-    path's parity contract.  Returns (y, k, v) with k/v the SUFFIX
-    rows only.  ``shard`` replicates feature axes before their
-    reductions (the gathered prefix K/V arrive head-sharded from the
-    mesh-sharded pool and concatenate exactly)."""
-    rep = shard.rep if shard is not None else (lambda t: t)
-    b, s_len, d = x.shape
-    h, dh = ly.n_heads, d // ly.n_heads
-    cast = lambda w: w.astype(x.dtype)
-    qkv = x @ cast(params["Wqkv"]) + cast(params["bqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    split = lambda z: z.reshape(b, s_len, h, dh).transpose(0, 2, 1, 3)
-    q, k, v = split(q), split(k), split(v)
-    P = pk.shape[2]
-    kk = jnp.concatenate([pk, k], axis=2)       # [b, h, P+s, dh]
-    vv = jnp.concatenate([pv, v], axis=2)
-    scale = 1.0 / (dh ** 0.5)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, kk).astype(jnp.float32) * scale
-    cols = jnp.arange(P + s_len)
-    col_g = jnp.where(cols < P, cols, p0 + cols - P)   # global key pos
-    col_ok = jnp.where(cols < P, cols < p0, True)      # prefix pad out
-    rows_g = p0 + jnp.arange(s_len)
-    mask = col_ok[None, :] & (col_g[None, :] <= rows_g[:, None])
-    s = jnp.where(mask[None, None], s, -1e9)
-    p = jax.nn.softmax(s, axis=-1).astype(vv.dtype)
-    att = jnp.einsum("bhqk,bhkd->bhqd", p, vv)
-    att = rep(att.transpose(0, 2, 1, 3).reshape(b, s_len, d))
-    att = att @ cast(params["Wo"]) + cast(params["bo"])
-    hdn = _layer_norm(rep(x + att), params["ln1_g"], params["ln1_b"],
-                      ly.eps)
-    from deeplearning4j_tpu.nn.activations import get_activation
-    act = get_activation(ly.activation or "gelu")
-    ffn = act(hdn @ cast(params["W1"]) + cast(params["b1"]))
-    ffn = rep(ffn) @ cast(params["W2"]) + cast(params["b2"])
-    y = _layer_norm(rep(hdn + ffn), params["ln2_g"], params["ln2_b"],
-                    ly.eps)
-    return y, k, v
 
 
 def _filter_logits_rows(logits, top_k, top_p):
@@ -416,6 +187,38 @@ def _draw_token(logits, key, temperature, top_k, top_p):
     return nxt.astype(jnp.int32), key
 
 
+
+
+def _layer_of(stacked, layer):
+    """Layer ``layer`` (a traced index) of a [layers, ...] array."""
+    return jax.lax.dynamic_index_in_dim(stacked, layer, 0, keepdims=False)
+
+
+def _with_layer(stacked, value, layer):
+    return jax.lax.dynamic_update_index_in_dim(stacked, value, layer, 0)
+
+
+def _scatter_then_read(kv, layer, wblk, woff, k, v, read):
+    """XLA's write of the rows k / v into layer ``layer`` of the pools
+    ``kv`` at (``wblk``, ``woff``), then ``read(k_layer, v_layer)`` of
+    the written layer: where the kernel does not write the pool itself.
+    A kernel-route pool's rows are wider than ``head_dim``, zero past
+    it.  Returns (what ``read`` gave, kv)."""
+    from deeplearning4j_tpu.kernels import pad_head_dim
+    kc, vc = kv
+    put = lambda c, rows: _layer_of(c, layer).at[wblk, :, woff, :].set(
+        pad_head_dim(rows, c.shape[-1]))
+    kl, vl = put(kc, k), put(vc, v)
+    return read(kl, vl), (_with_layer(kc, kl, layer),
+                          _with_layer(vc, vl, layer))
+
+
+def _depth(run_p) -> int:
+    """Layers in one run's stacked parameters (a truncated self-draft
+    hands in fewer than the run was made with)."""
+    return jax.tree_util.tree_leaves(run_p)[0].shape[0]
+
+
 class TransformerGenerator:
     """Greedy / temperature / top-k / nucleus sampling with KV caches
     over a decoder MLN.  The prompt is prefilled in ONE batched causal
@@ -427,61 +230,90 @@ class TransformerGenerator:
     ...                    top_k=40, top_p=0.95)
     """
 
-    def __new__(cls, net, compute_dtype: Optional[str] = None):
-        """A net whose middle layers are block RUNS of two kinds (Mamba
-        and attention: ``nn/conf/layers_hybrid.py``) decodes through
-        ``generation_runs.RunsGenerator``, which keeps this class's
-        surface; the conf-identical rule then holds within a run."""
-        if cls is TransformerGenerator:
-            from deeplearning4j_tpu.models import generation_runs
-            if generation_runs.is_run_stack(net):
-                return super().__new__(generation_runs.RunsGenerator)
-        return super().__new__(cls)
-
-    # what a server sizes its K/V pool by; ``recurrent`` describes the
-    # per-row state a stack of runs keeps besides (None here)
-    recurrent = None
-    kv_layers = property(lambda self: len(self.blocks))
-    kv_heads = property(lambda self: self.blocks[0].n_heads)
-    head_dim = property(lambda self: self.emb.n_out // self.kv_heads)
-    vocab_size = property(
-        lambda self: int(np.shape(self._params()[2]["W"])[-1]))
-
     def __init__(self, net, compute_dtype: Optional[str] = None):
         layers = list(net.layers)
         if not isinstance(layers[0], EmbeddingSequenceLayer):
             raise ValueError("generator expects EmbeddingSequenceLayer "
                              f"first, got {type(layers[0]).__name__}")
+        self.net, self.emb, self.head = net, layers[0], layers[-1]
+        self.blocks = layers[1:-1]
+        # runs of pre-norm blocks are made stacked ([n_blocks, ...])
+        self._made_stacked = bool(self.blocks) and all(
+            isinstance(l, (MambaBlockRun, AttentionBlockRun))
+            for l in self.blocks)
+        if self._made_stacked:
+            if not isinstance(self.head, TiedLMHead):
+                raise ValueError(
+                    "a stack of block runs decodes through a TiedLMHead, "
+                    f"got {type(self.head).__name__}")
+            self.runs = self.blocks
+            self._layers = [r.n_blocks for r in self.runs]
+        else:
+            self.runs = [self._one_post_ln_run()]
+            self._layers = [len(self.blocks)]
+        attn = [r for r in self.runs if not r.RECURRENT]
+        rec = [r for r in self.runs if r.RECURRENT]
+        # one pool and one stacked state serve every run of a kind
+        if len({(r.n_kv_heads, r.head_dim) for r in attn}) > 1:
+            raise ValueError("the attention runs of one stack share one "
+                             "K/V pool: n_kv_heads and head_dim must agree")
+        if len({(r.d_state, r.d_inner, r.d_conv) for r in rec}) > 1:
+            raise ValueError("the recurrent runs of one stack share one "
+                             "state: d_state, d_inner and d_conv must agree")
+        if not attn:
+            raise ValueError("a stack without an attention run has no "
+                             "K/V pool to page (not supported)")
+        self._attn, self._rec = attn[0], (rec[0] if rec else None)
+        #: why a server cannot share, restore, re-verify or shard this
+        #: stack's K/V rows (the run kind's ``REFUSES``; None: it can)
+        self.refuses = next((r.REFUSES for r in rec + attn if r.REFUSES),
+                            None)
+        self.compute_dtype = (jnp.dtype(compute_dtype)
+                              if compute_dtype else jnp.float32)
+        self._fn_cache = {}
+
+    def _one_post_ln_run(self):
+        """The conf of a stack of conf-identical causal post-LN blocks
+        under an (Rnn)OutputLayer: stacked, they are one run."""
         if not all(isinstance(l, TransformerEncoderBlock)
-                   for l in layers[1:-1]):
+                   for l in self.blocks):
             raise ValueError("generator expects a pure "
                              "TransformerEncoderBlock stack")
-        for l in layers[1:-1]:
-            if not l.causal:
-                raise ValueError("generation requires causal=True blocks")
-        import dataclasses
-        ref = dataclasses.asdict(layers[1])
-        if any(dataclasses.asdict(l) != ref for l in layers[2:-1]):
-            # the decode tick stacks the block params on a leading axis
-            # and lax.scans over layers (ONE traced block body instead
-            # of n_layers inlined copies) — that stack needs
-            # conf-identical blocks.  Every in-tree decoder (zoo.Gpt)
-            # is homogeneous.
+        if not all(l.causal for l in self.blocks):
+            raise ValueError("generation requires causal=True blocks")
+        ref = dataclasses.asdict(self.blocks[0])
+        if any(dataclasses.asdict(l) != ref for l in self.blocks[1:]):
+            # a run's parameters are stacked on a leading axis and
+            # lax.scan'ed: that needs conf-identical blocks.  Every
+            # in-tree decoder (zoo.Gpt) is homogeneous.
             raise ValueError("generator requires conf-identical "
                              "TransformerEncoderBlocks (the decode "
                              "tick scans stacked block params)")
-        self.net = net
-        self.emb = layers[0]
-        self.blocks = layers[1:-1]
-        self.head = layers[-1]
         if not isinstance(self.head, OutputLayer):
             # RnnOutputLayer subclasses OutputLayer: any W/b softmax
             # head over the final hidden state decodes
             raise ValueError("generator expects an (Rnn)OutputLayer "
                              f"head, got {type(self.head).__name__}")
-        self.compute_dtype = (jnp.dtype(compute_dtype)
-                              if compute_dtype else jnp.float32)
-        self._fn_cache = {}
+        return self.blocks[0]
+
+    # -- what a server sizes its pool and state by ----------------------
+    kv_layers = property(lambda self: sum(
+        n for r, n in zip(self.runs, self._layers) if not r.RECURRENT))
+    kv_heads = property(lambda self: self._attn.n_kv_heads)
+    head_dim = property(lambda self: self._attn.head_dim)
+    vocab_size = property(lambda self: int(self.head.n_out))
+
+    def fresh_rec(self, b: int):
+        """The recurrent state of ``b`` rows that have seen nothing
+        (None: the stack keeps none)."""
+        r = self._rec
+        if r is None:
+            return None
+        layers = sum(self._layers) - self.kv_layers
+        return {"h": jnp.zeros((layers, b, r.d_state, r.d_inner),
+                               jnp.float32),
+                "conv": jnp.zeros((layers, b, r.d_conv - 1, r.d_inner),
+                                  self.compute_dtype)}
 
     def _params(self):
         self.net._check_init()   # fires any lazy _param_sync_hook
@@ -491,92 +323,150 @@ class TransformerGenerator:
                 [pt[f"layer_{i}"] for i in range(1, n - 1)],
                 pt[f"layer_{n - 1}"])
 
-    @staticmethod
-    def _stack_blocks(blk_ps):
-        """List of per-block param dicts -> one dict with a leading
-        [n_layers] axis on every leaf — the layout ``_step``'s
-        layer-scan consumes.  Inside jit the stack is a compile-time
-        concatenate; the scan body then references ONE block's worth of
-        program, so the decode tick's XLA program size stays O(1) in
-        depth instead of inlining n_layers copies."""
-        return jax.tree_util.tree_map(
-            lambda *ls: jnp.stack(ls), *blk_ps)
+    def _stack_blocks(self, blk_ps):
+        """The middle layers' param dicts -> one dict a RUN, every leaf
+        with a leading [layers] axis: what the scans over a run consume.
+        Runs made stacked are the net's own arrays (a snapshot copies
+        nothing); N post-LN blocks are stacked here (inside jit a
+        compile-time concatenate)."""
+        if self._made_stacked:
+            return tuple(blk_ps)
+        return (jax.tree_util.tree_map(lambda *ls: jnp.stack(ls),
+                                       *blk_ps),)
 
-    def _step(self, emb_p, blk_stack, head_p, kc, vc, tok, pos):
-        """One decode tick.  ``blk_stack`` is ``_stack_blocks`` output;
-        ``kc``/``vc`` are [n_layers, b, h, L, dh]; ``pos`` is a scalar
-        (offline scan) or [b] vector (server slots).  Returns
-        (logits [b, V], kc, vc)."""
-        x = _embed_token(self.emb, emb_p, tok, pos)
-        x = x.astype(self.compute_dtype)
-        ly = self.blocks[0]          # conf-identical (checked in init)
+    def _logits(self, emb_p, head_p, x, shard=None):
+        """float32 logits of the final hidden rows x [..., d]; under a
+        mesh they gather, so a sampler's argmax / sort runs on the full
+        vocabulary row."""
+        if isinstance(self.head, TiedLMHead):
+            logits = self.head.logits(head_p, emb_p["W"], x)
+        else:
+            logits = x.astype(jnp.float32) @ head_p["W"] + head_p["b"]
+        return logits if shard is None else shard.rep(logits)
 
-        def body(h, layer):
-            p, kc_l, vc_l = layer
-            h, kc_l, vc_l = _block_decode_step(ly, p, kc_l, vc_l, h, pos)
-            return h, (kc_l, vc_l)
-
-        x, (kc, vc) = jax.lax.scan(body, x, (blk_stack, kc, vc))
-        logits = (x.astype(jnp.float32) @ head_p["W"] + head_p["b"])
-        return logits, kc, vc
-
-    def _step_paged(self, emb_p, blk_stack, head_p, kc, vc, tok, pos,
-                    table, wblk, woff, shard=None, kernel_writes=False):
-        """Paged-pool decode tick: ``kc``/``vc`` are the global block
-        pools [n_layers, n_blocks, h, block_size, dh], ``table``
-        [b, max_blocks] the per-slot block tables, and the new row
-        lands at (``wblk``, ``woff``) per slot.  Same layer-scan
-        structure as ``_step``; attention routes through
-        ``kernels.paged_decode_attention``.  ``shard`` (TpShardCtx)
-        turns this into the mesh-sharded tick: embeds replicate, block
-        math shards heads/columns along ``tp`` with explicit
-        replication before feature reductions, and the logits gather
-        so the sampler's argmax/sort runs on the full vocab row —
-        byte-identical to the unsharded program by construction.
-
-        ``kernel_writes`` (the server's decode scan) lets the kernel
-        route CARRY the pools whole through the layer scan, written
-        and read by the paged kernel alone: an XLA scatter of single
-        rows makes XLA hold the loop's pool token-major inside a block,
-        and the kernel's row-major operands then cost a slice, a layout
-        copy and a write-back of a layer's pool per layer per tick
-        (79% of the device's time before PR 26).  The reference routes
-        (CPU, tp > 1) keep the per-layer slice + scatter + gather."""
-        from deeplearning4j_tpu.kernels import paged_route
-        x = _embed_token(self.emb, emb_p, tok, pos)
-        x = x.astype(self.compute_dtype)
+    # -- one tick --------------------------------------------------------
+    def _tick(self, emb_p, runs_p, head_p, tok, pos, rec, active, kv,
+              attend_at, shard=None):
+        """Every run's ``step()`` in turn over the rows ``tok`` at
+        ``pos``.  ``kv`` is the attention layers' cache, carried WHOLE
+        through each run's layer scan (xs: the run's stacked parameters
+        and the layer's index) and threaded through
+        ``attend_at(kv, layer)(q, k, v) -> (att, kv)``; ``rec`` the
+        recurrent layers' state, advanced for the ``active`` rows.
+        Returns (logits, kv, rec)."""
+        x = _embed_token(self.emb, emb_p, tok, pos).astype(
+            self.compute_dtype)
         if shard is not None:
             x = shard.rep(x)
-        ly = self.blocks[0]          # conf-identical (checked in init)
+        kv_l = rec_l = 0
+        for run, p in zip(self.runs, runs_p):
+            n = _depth(p)
+            if run.RECURRENT:
+                def body(carry, xs, run=run):
+                    h, rec = carry
+                    return run.step(xs[0], h, rec, xs[1], active), None
+                (x, rec), _ = jax.lax.scan(
+                    body, (x, rec), (p, jnp.arange(rec_l, rec_l + n)))
+                rec_l += n
+            else:
+                def body(carry, xs, run=run):
+                    h, kv = carry
+                    return run.step(xs[0], h, attend_at(kv, xs[1]),
+                                    shard=shard), None
+                (x, kv), _ = jax.lax.scan(
+                    body, (x, kv), (p, jnp.arange(kv_l, kv_l + n)))
+                kv_l += n
+        return self._logits(emb_p, head_p, x, shard), kv, rec
 
-        if kernel_writes and paged_route(shard) == "pallas":
-            def body(carry, layer):
-                h, kc, vc = carry
-                p, l = layer
-                h, kc, vc = _block_decode_step_paged(
-                    ly, p, kc, vc, h, pos, table, wblk, woff,
-                    shard=shard, layer=l)
-                return (h, kc, vc), None
+    def _step(self, emb_p, runs_p, head_p, kc, vc, rec, tok, pos):
+        """One offline decode tick over dense caches ``kc`` / ``vc``
+        [kv_layers, b, kv_heads, L, head_dim]: writes position ``pos``
+        (a scalar), attends over <= pos.  float32 scores, a -1e9 mask,
+        softmax in float32: ``sequence()``'s math, which is what makes
+        cached decode equal the full forward.  Returns (logits [b, V],
+        kc, vc, rec)."""
+        scale = 1.0 / math.sqrt(self.head_dim)
 
-            (x, kc, vc), _ = jax.lax.scan(
-                body, (x, kc, vc), (blk_stack, jnp.arange(kc.shape[0])))
-        else:
-            def body(h, layer):
-                p, kc_l, vc_l = layer
-                h, kc_l, vc_l = _block_decode_step_paged(
-                    ly, p, kc_l, vc_l, h, pos, table, wblk, woff,
-                    shard=shard)
-                return h, (kc_l, vc_l)
+        def attend_at(kv, layer):
+            def attend(q, k, v):
+                b, hq, dh = q.shape
+                kc, vc = kv
+                put = lambda c, row: jax.lax.dynamic_update_slice(
+                    _layer_of(c, layer),
+                    row[:, :, None, :].astype(c.dtype), (0, 0, pos, 0))
+                kl, vl = put(kc, k), put(vc, v)
+                # grouped query heads: hq // kv_heads on each K/V head
+                qg = q.reshape(b, kl.shape[1], -1, dh)
+                s = jnp.einsum("bhgd,bhkd->bhgk", qg, kl).astype(
+                    jnp.float32) * scale
+                s = jnp.where(jnp.arange(kl.shape[2]) <= pos, s, -1e9)
+                w = jax.nn.softmax(s, axis=-1).astype(vl.dtype)
+                att = jnp.einsum("bhgk,bhkd->bhgd", w, vl)
+                return att.reshape(b, hq, dh), (_with_layer(kc, kl, layer),
+                                                _with_layer(vc, vl, layer))
+            return attend
 
-            x, (kc, vc) = jax.lax.scan(body, x, (blk_stack, kc, vc))
-        logits = (x.astype(jnp.float32) @ head_p["W"] + head_p["b"])
-        if shard is not None:
-            logits = shard.rep(logits)
-        return logits, kc, vc
+        active = jnp.ones(tok.shape, bool)
+        logits, (kc, vc), rec = self._tick(
+            emb_p, runs_p, head_p, tok, pos, rec, active, (kc, vc),
+            attend_at)
+        return logits, kc, vc, rec
 
-    def _verify_rows_paged(self, emb_p, blk_stack, head_p, kc, vc,
-                           toks, pos0, epos, table, wblk, woff,
-                           shard=None):
+    def _step_paged(self, emb_p, runs_p, head_p, kc, vc, tok, pos, table,
+                    wblk, woff, shard=None, kernel_writes=False, rec=None,
+                    active=None):
+        """The server's tick: ``kc`` / ``vc`` are the global block pools
+        [kv_layers, n_blocks, kv_heads, block_size, width], ``table``
+        [b, max_blocks] the per-slot block tables, ``pos`` [b]; the new
+        K/V row of a slot lands at (``wblk``, ``woff``) -- the caller
+        masks inactive slots to the scratch block 0 -- and attention
+        reads THROUGH the table (``kernels.paged_decode_attention``; its
+        reference path mirrors the dense cache's math exactly, which is
+        what byte parity with offline decode rests on).  The recurrent
+        layers advance ``rec`` for the ``active`` rows.
+
+        ``kernel_writes`` (the server's decode scan) lets the kernel
+        route have the pools written and read by ``%paged_attention``
+        alone (``kernels.paged_decode_write_attention``): an XLA scatter
+        of single rows makes XLA hold the loop's pool token-major inside
+        a block, and the kernel's row-major operands then cost a slice,
+        a layout copy and a write-back of a layer's pool per layer per
+        tick (79% of the device's time before PR 26).  Otherwise the
+        layer's pool is sliced, scattered into and put back.  A
+        kernel-route pool's rows are ``kernels.paged_pool_width(dh)``
+        wide, zero past ``head_dim``.
+
+        ``shard`` (TpShardCtx) makes this the mesh-sharded tick: embeds
+        replicate, block math shards heads / columns along ``tp`` with
+        explicit replication before feature reductions, the logits
+        gather -- byte-identical to the unsharded program by
+        construction.  Returns (logits, kc, vc, rec)."""
+        from deeplearning4j_tpu.kernels import (
+            paged_decode_attention, paged_decode_write_attention,
+            paged_route)
+        scale = 1.0 / math.sqrt(self.head_dim)
+        kernel = kernel_writes and paged_route(shard) == "pallas"
+
+        def attend_at(kv, layer):
+            def attend(q, k, v):
+                if kernel:
+                    att, kc, vc = paged_decode_write_attention(
+                        q, k, v, *kv, table, pos, wblk, woff, layer,
+                        scale=scale)
+                    return att, (kc, vc)
+                return _scatter_then_read(
+                    kv, layer, wblk, woff, k, v,
+                    lambda kl, vl: paged_decode_attention(
+                        q, kl, vl, table, pos, scale=scale, shard=shard))
+            return attend
+
+        logits, (kc, vc), rec = self._tick(
+            emb_p, runs_p, head_p, tok, pos, rec, active, (kc, vc),
+            attend_at, shard)
+        return logits, kc, vc, rec
+
+    def _verify_rows_paged(self, emb_p, runs_p, head_p, kc, vc, toks,
+                           pos0, epos, table, wblk, woff, shard=None):
         """Speculative verification forward: ONE batched pass over a
         chunk of W tokens per slot — ``toks`` [B, W] (the anchor + the
         draft's proposals, inactive rows masked to 0), ``pos0`` [B]
@@ -586,75 +476,110 @@ class TransformerGenerator:
         ``woff`` [B, W] the per-token write targets through the
         slot's block table (masked rows at the scratch block 0).
 
-        Returns (logits [B, W, V], kc, vc): logits at EVERY chunk
-        position — G_j is the target's distribution after consuming
-        tokens 0..j, which is both the acceptance judge and the held
-        logits the round hands forward.  Flat-row matmuls + the
-        per-row attention contract (``_block_verify_step_paged``)
-        make logits AND cache writes bitwise equal to W sequential
-        ``_step_paged`` ticks."""
+        Returns (logits [B, W, V], kc, vc, rec = None): logits at EVERY
+        chunk position — G_j is the target's distribution after
+        consuming tokens 0..j, which is both the acceptance judge and
+        the held logits the round hands forward.  The tick runs over
+        FLAT [B*W] rows -- every matmul and layer norm of ``step()`` at
+        the 2-D shapes that are row-bitwise-stable on the backends --
+        and attention unrolls per query row, row j at ``pos0 + j``
+        (``kernels.paged_verify_attention``): logits AND cache writes
+        are bitwise equal to W sequential ``_step_paged`` ticks, the
+        invariant speculative greedy parity rests on."""
+        from deeplearning4j_tpu.kernels import paged_verify_attention
         B, W = toks.shape
-        ly = self.blocks[0]
-        flat_tok = toks.reshape(B * W).astype(jnp.int32)
-        y = jnp.take(emb_p["W"], flat_tok, axis=0)
-        if self.emb.add_positional:
-            y = y + jnp.take(emb_p["P"], epos.reshape(B * W), axis=0)
-        if self.emb.layer_norm:
-            y = _layer_norm(y, emb_p["g"], emb_p["b"], self.emb.eps)
-        x = y.astype(self.compute_dtype)
-        if shard is not None:
-            x = shard.rep(x)
+        scale = 1.0 / math.sqrt(self.head_dim)
+        chunk = lambda z: z.reshape((B, W) + z.shape[1:])
 
-        def body(h, layer):
-            p, kc_l, vc_l = layer
-            h, kc_l, vc_l = _block_verify_step_paged(
-                ly, p, kc_l, vc_l, h, table, wblk, woff, pos0,
-                shard=shard)
-            return h, (kc_l, vc_l)
+        def attend_at(kv, layer):
+            def attend(q, k, v):
+                return _scatter_then_read(
+                    kv, layer, wblk, woff, chunk(k), chunk(v),
+                    lambda kl, vl: paged_verify_attention(
+                        chunk(q), kl, vl, table, pos0, scale=scale,
+                        shard=shard))
+            return attend
 
-        x, (kc, vc) = jax.lax.scan(body, x, (blk_stack, kc, vc))
-        logits = (x.astype(jnp.float32) @ head_p["W"] + head_p["b"])
-        if shard is not None:
-            logits = shard.rep(logits)
-        return logits.reshape(B, W, -1), kc, vc
+        logits, (kc, vc), rec = self._tick(
+            emb_p, runs_p, head_p, toks.reshape(B * W),
+            epos.reshape(B * W), None, None, (kc, vc), attend_at, shard)
+        return logits.reshape(B, W, -1), kc, vc, rec
 
-    def _prefill_rows_chunked(self, emb_p, blk_stack, head_p, suffix,
-                              pk, pv, p0, last_ix, shard=None):
-        """Chunked-prefill counterpart of ``_prefill_rows`` for
-        prefix-cache HITS: ``suffix`` [b, s] are the uncached prompt
-        tokens at global positions p0..p0+s-1 (pad tail beyond the
-        real suffix), ``pk``/``pv`` [n_layers, b, h, P, dh] the cached
-        prefix K/V gathered out of the block pool (valid cols < p0).
-        Returns (logits [b, V] at local row ``last_ix`` = t0-p0-1, ks,
-        vs [n_layers, b, h, s, dh]) — the SUFFIX rows only, for the
-        caller to scatter into fresh blocks.  Prefill runs only on the
-        suffix: the prefix's compute is the work the cache saves."""
+    # -- prefill ---------------------------------------------------------
+    def _sequences(self, emb_p, runs_p, head_p, x, t0, last_ix,
+                   prefix=None, shard=None):
+        """Every run's ``sequence()`` in turn over the embedded rows x
+        [b, t, d].  Returns (logits [b, V] at row ``last_ix`` (default:
+        the last), ks, vs [kv_layers, b, kv_heads, t, head_dim], rec)."""
         cd = self.compute_dtype
-        ly = self.blocks[0]
-        pos = p0 + jnp.arange(suffix.shape[1])
-        y = jnp.take(emb_p["W"], suffix.astype(jnp.int32), axis=0)
-        if self.emb.add_positional:
-            # same rows _embed_prompt's [:t] slice reads; take clamps
-            # the pad tail (finite garbage, masked before any read)
-            y = y + jnp.take(emb_p["P"], pos, axis=0)
-        if self.emb.layer_norm:
-            y = _layer_norm(y, emb_p["g"], emb_p["b"], self.emb.eps)
-        x = y.astype(cd)
+        x = x.astype(cd)
         if shard is not None:
             x = shard.rep(x)
+        ks, vs, hs, convs = [], [], [], []
+        kv_l = 0
+        for run, p in zip(self.runs, runs_p):
+            if run.RECURRENT:
+                x, got = jax.lax.scan(
+                    lambda h, p_l, run=run: run.sequence(p_l, h, t0), x, p)
+                hs.append(got["h"])
+                convs.append(got["conv"].astype(cd))
+                continue
+            n = _depth(p)
+            if prefix is None:
+                x, got = jax.lax.scan(
+                    lambda h, p_l, run=run: run.sequence(
+                        p_l, h, t0, shard=shard), x, p)
+            else:
+                pk, pv, p0 = prefix
+                mine = slice(kv_l, kv_l + n)
+                x, got = jax.lax.scan(
+                    lambda h, xs, run=run: run.sequence(
+                        xs[0], h, t0, prefix=(xs[1], xs[2], p0),
+                        shard=shard), x, (p, pk[mine], pv[mine]))
+            ks.append(got["k"].astype(cd))
+            vs.append(got["v"].astype(cd))
+            kv_l += n
+        cat = lambda parts: (jnp.concatenate(parts, axis=0)
+                             if len(parts) > 1 else parts[0])
+        last = (x[:, -1] if last_ix is None else
+                jax.lax.dynamic_slice_in_dim(x, last_ix, 1, axis=1)[:, 0])
+        rec = {"h": cat(hs), "conv": cat(convs)} if hs else None
+        return (self._logits(emb_p, head_p, last, shard), cat(ks), cat(vs),
+                rec)
 
-        def body(hdn, layer):
-            p, pk_l, pv_l = layer
-            hdn, k, v = _block_prefill_chunked(ly, p, hdn, pk_l, pv_l,
-                                               p0, shard=shard)
-            return hdn, (k.astype(cd), v.astype(cd))
+    def _prefill_rows(self, emb_p, runs_p, head_p, prompt, t0=None,
+                      shard=None):
+        """Whole-prompt forward, one batched pass a run.  Returns
+        (logits [b, V], ks, vs [kv_layers, b, kv_heads, t, head_dim],
+        rec): the raw K/V rows for the caller to place (offline decode
+        zero-pads to L; the server scatters into a slot's blocks), and
+        the recurrent state AS AFTER TOKEN ``t0`` -- the pad tail of a
+        bucket does not advance it.  ``t0`` picks the logits position of
+        a prompt PADDED past its real length (causal masking makes
+        position t0-1 independent of the pad tail); default is the last
+        column.  THE prefill numerics both decode paths share:
+        byte-identical greedy parity between them depends on exactly
+        this."""
+        return self._sequences(
+            emb_p, runs_p, head_p, _embed_prompt(self.emb, emb_p, prompt),
+            t0, None if t0 is None else t0 - 1, shard=shard)
 
-        x, (ks, vs) = jax.lax.scan(body, x, (blk_stack, pk, pv))
-        last = jax.lax.dynamic_slice_in_dim(x, last_ix, 1, axis=1)[:, 0]
-        logits = last.astype(jnp.float32) @ head_p["W"] + head_p["b"]
-        if shard is not None:
-            logits = shard.rep(logits)
-        return logits, ks, vs
+    def _prefill_rows_chunked(self, emb_p, runs_p, head_p, suffix, pk, pv,
+                              p0, last_ix, shard=None):
+        """``_prefill_rows`` for prefix-cache HITS: ``suffix`` [b, s]
+        are the uncached prompt tokens at global positions p0..p0+s-1
+        (pad tail beyond the real suffix), ``pk`` / ``pv`` [kv_layers,
+        b, kv_heads, P, head_dim] the cached prefix K/V gathered out of
+        the block pool (valid cols < p0).  Logits at local row
+        ``last_ix`` = t0-p0-1; ks / vs are the SUFFIX rows only, for the
+        caller to scatter into fresh blocks.  Prefill runs only on the
+        suffix: the prefix's compute is the work the cache saves.  The
+        positional take clamps the pad tail (finite garbage, masked
+        before any read)."""
+        x = _embed_token(self.emb, emb_p, suffix,
+                         p0 + jnp.arange(suffix.shape[1]))
+        return self._sequences(emb_p, runs_p, head_p, x, None, last_ix,
+                               prefix=(pk, pv, p0), shard=shard)
 
     def generate(self, prompt_ids, n_new: int, temperature: float = 0.0,
                  seed: int = 0, max_len: Optional[int] = None,
@@ -711,85 +636,33 @@ class TransformerGenerator:
             _GEN_RATE.set(n_new / dt)
         return out
 
-    def _prefill_rows(self, emb_p, blk_stack, head_p, prompt, t0=None,
-                      shard=None):
-        """Batched prompt pass scanned over the stacked block params.
-        Returns (logits [b, V], ks, vs [n_layers, b, h, t, dh]) — the
-        raw per-layer K/V rows, for the caller to place (offline decode
-        zero-pads to L; the generation server scatters into a slot's
-        cache stripe).  ``t0`` picks the logits position for prompts
-        PADDED past their real length (causal masking makes position
-        t0-1 independent of the pad tail); default is the last column.
-        THE prefill numerics both decode paths share — byte-identical
-        greedy parity between them depends on exactly this."""
-        cd = self.compute_dtype
-        ly = self.blocks[0]
-        x = _embed_prompt(self.emb, emb_p, prompt)
-        x = x.astype(cd)
-        if shard is not None:
-            x = shard.rep(x)
 
-        def body(hdn, p):
-            hdn, k, v = _block_prefill(ly, p, hdn, shard=shard)
-            return hdn, (k.astype(cd), v.astype(cd))
-
-        x, (ks, vs) = jax.lax.scan(body, x, blk_stack)
-        if t0 is None:
-            last = x[:, -1]
-        else:
-            last = jax.lax.dynamic_slice_in_dim(x, t0 - 1, 1,
-                                                axis=1)[:, 0]
-        logits = last.astype(jnp.float32) @ head_p["W"] + head_p["b"]
-        if shard is not None:
-            logits = shard.rep(logits)
-        return logits, ks, vs
-
-    def _prefill(self, emb_p, blk_stack, head_p, prompt, L):
-        """``_prefill_rows`` + zero-padded caches out to length L,
-        stacked [n_layers, b, h, L, dh] — ``_step``'s layout."""
-        b = prompt.shape[0]
-        h = self.blocks[0].n_heads
-        dh = self.emb.n_out // h
-        n_layers = len(self.blocks)
-        cd = self.compute_dtype
-        logits, ks, vs = self._prefill_rows(emb_p, blk_stack, head_p,
-                                            prompt)
-        kc = jnp.zeros((n_layers, b, h, L, dh), cd)
-        vc = jnp.zeros((n_layers, b, h, L, dh), cd)
-        kc = jax.lax.dynamic_update_slice(kc, ks, (0, 0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, vs, (0, 0, 0, 0, 0))
-        return logits, kc, vc
-
-    def _generate_scan(self, emb_p, blk_ps, head_p, ids, rng_key,
-                       t0, n_new, L, temperature, top_k=None,
-                       top_p=None):
+    def _generate_scan(self, emb_p, blk_ps, head_p, ids, rng_key, t0,
+                       n_new, L, temperature, top_k=None, top_p=None):
         if self.compute_dtype != jnp.float32:
             # cast the full parameter set ONCE inside the program: the
             # decode scan re-reads every parameter each tick, and
             # streaming f32-stored weights costs 2x the bytes of the
-            # bf16 math actually performed (measured 840 -> 969
-            # steps/s on zoo.Gpt; the tick also carries per-op
-            # overheads the byte halving cannot remove)
+            # bf16 math actually performed
             emb_p, blk_ps, head_p = _cast_floating(
                 (emb_p, blk_ps, head_p), self.compute_dtype)
-        blk_stack = self._stack_blocks(blk_ps)
-        prompt = ids[:, :t0]
-        logits0, kc, vc = self._prefill(emb_p, blk_stack, head_p,
-                                        prompt, L)
+        runs_p = self._stack_blocks(blk_ps)
+        logits0, ks, vs, rec = self._prefill_rows(
+            emb_p, runs_p, head_p, ids[:, :t0])
+        pad = ((0, 0), (0, 0), (0, 0), (0, L - t0), (0, 0))
+        kc, vc = jnp.pad(ks, pad), jnp.pad(vs, pad)
 
         def body(carry, pos):
             # sample the token AT pos from the previous logits, write
             # it, embed it, advance the caches
-            ids, kc, vc, key, logits = carry
-            nxt, key = _draw_token(logits, key, temperature, top_k,
-                                   top_p)
-            ids = jax.lax.dynamic_update_slice(ids, nxt[:, None],
-                                               (0, pos))
-            logits, kc, vc = self._step(emb_p, blk_stack, head_p,
-                                        kc, vc, nxt, pos)
-            return (ids, kc, vc, key, logits), None
+            ids, kc, vc, rec, key, logits = carry
+            nxt, key = _draw_token(logits, key, temperature, top_k, top_p)
+            ids = jax.lax.dynamic_update_slice(ids, nxt[:, None], (0, pos))
+            logits, kc, vc, rec = self._step(emb_p, runs_p, head_p, kc, vc,
+                                             rec, nxt, pos)
+            return (ids, kc, vc, rec, key, logits), None
 
-        (ids, _, _, _, _), _ = jax.lax.scan(
-            body, (ids, kc, vc, rng_key, logits0),
+        (ids, *_), _ = jax.lax.scan(
+            body, (ids, kc, vc, rec, rng_key, logits0),
             t0 + jnp.arange(n_new))
         return ids

@@ -140,6 +140,12 @@ class AttentionBlockRun(_PreNormRun):
     n_kv_heads: int = 1
     head_dim: Optional[int] = None   # default d / n_heads
 
+    #: why a server cannot share, restore, re-verify or shard this
+    #: kind's K/V rows (None would mean it can)
+    REFUSES = ("AttentionBlockRun layers: the run has no "
+               "sequence(prefix=) over cached K/V rows, no W-row verify "
+               "step() and no shard points yet (ROADMAP M1)")
+
     def _check_widths(self):
         if self.head_dim is None:
             if self.n_in % self.n_heads:
@@ -169,10 +175,12 @@ class AttentionBlockRun(_PreNormRun):
                 (n @ p["Wk"].astype(x.dtype)).reshape(lead + (hkv, dh)),
                 (n @ p["Wv"].astype(x.dtype)).reshape(lead + (hkv, dh)))
 
-    def sequence(self, p, x, t0=None):
-        """x [b, t, d] -> (y, {"k", "v"} [b, t, n_kv_heads, head_dim]).
-        Causal, so ``t0`` (a padded prompt's real length) changes
-        nothing a real position reads."""
+    def sequence(self, p, x, t0=None, shard=None):
+        """x [b, t, d] -> (y, {"k", "v"} [b, n_kv_heads, t, head_dim],
+        the rows as a pool holds them).  Causal, so ``t0`` (a padded
+        prompt's real length) changes nothing a real position reads.
+        No ``prefix=`` form and no shard points (``shard`` names a
+        device, never a split): ``REFUSES``."""
         b, t, _ = x.shape
         hq, hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
         q, k, v = self._qkv(p, x)
@@ -184,9 +192,10 @@ class AttentionBlockRun(_PreNormRun):
         w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         att = jnp.einsum("bhgqk,bkhd->bqhgd", w, v).reshape(b, t, hq * dh)
         x = x + att @ p["Wo"].astype(x.dtype)
-        return self._ffn(p, x), {"k": k, "v": v}
+        return self._ffn(p, x), {"k": k.transpose(0, 2, 1, 3),
+                                 "v": v.transpose(0, 2, 1, 3)}
 
-    def step(self, p, x, attend):
+    def step(self, p, x, attend, shard=None):
         """x [b, d], one new token per row.  ``attend(q [b, n_heads,
         head_dim], k, v [b, n_kv_heads, head_dim]) -> (att like q,
         cache)`` writes the row and reads the context."""
@@ -210,6 +219,11 @@ class MambaBlockRun(_PreNormRun):
     dt_rank: Optional[int] = None    # default ceil(d / 16)
 
     RECURRENT = True
+    #: the same question, for the state this kind keeps besides
+    REFUSES = ("recurrent (state-space) layers: shared or restored K/V "
+               "blocks cannot restore a slot's recurrent state (snapshots "
+               "of it at block boundaries are later work), and the state "
+               "is not sharded")
 
     def _check_widths(self):
         if self.dt_rank is None:

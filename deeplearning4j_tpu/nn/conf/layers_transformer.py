@@ -106,7 +106,15 @@ class TransformerEncoderBlock(BaseLayerConf):
     kernel on TPU (O(t) memory, causal/mask-aware) with an XLA einsum
     fallback; a [b, t] sequence mask becomes the kernel's additive
     key-position bias.  With ``compute_dtype=bfloat16`` every matmul is
-    full-rate MXU; layer norms and softmax stay f32."""
+    full-rate MXU; layer norms and softmax stay f32.
+
+    For decoding the block is written ONCE more, as ``sequence()`` (its
+    causal forward over whole sequences: the prefill) and ``step()``
+    (its forward for new rows against a cache), the cache access handed
+    in -- the seam ``models.generation.TransformerGenerator`` walks, as
+    it walks the pre-norm runs of ``layers_hybrid``.  ``apply()`` shares
+    nothing with them: flash, dropout and the padding mask are its
+    own."""
 
     n_heads: int = 8
     d_ff: Optional[int] = None       # default 4*d
@@ -118,6 +126,10 @@ class TransformerEncoderBlock(BaseLayerConf):
 
     WANTED_KINDS = ("rnn",)
     USES_MASK = True
+    RECURRENT = False                # a decode keeps K/V rows only
+    #: why a server cannot share, restore, re-verify or shard this
+    #: kind's K/V rows (None: it can)
+    REFUSES = None
 
     def infer_shapes(self, input_shape):
         t, f = input_shape
@@ -192,3 +204,81 @@ class TransformerEncoderBlock(BaseLayerConf):
         y = _layer_norm(hdn + ffn, params["ln2_g"], params["ln2_b"],
                         self.eps)
         return y, state
+
+    # -- the decoding forms ------------------------------------------------
+    n_kv_heads = property(lambda self: self.n_heads)
+    head_dim = property(lambda self: self.n_in // self.n_heads)
+
+    def _qkv(self, p, x):
+        """x [..., d] -> q, k, v [..., n_heads, head_dim]."""
+        qkv = x @ p["Wqkv"].astype(x.dtype) + p["bqkv"].astype(x.dtype)
+        lead = x.shape[:-1] + (self.n_heads, self.head_dim)
+        return tuple(z.reshape(lead) for z in jnp.split(qkv, 3, axis=-1))
+
+    def _tail(self, p, x, att, shard):
+        """``Wo`` + residual + ``ln1`` + feed-forward + ``ln2`` over
+        attention's output ``att`` (shaped like x).  ``shard`` (a
+        ``parallel.mesh.TpShardCtx``, or None = identity) is the
+        mesh-sharded parity contract: weights arrive with their OUTPUT
+        columns sharded along ``tp`` (heads ride along when qkv splits)
+        and ``shard.rep`` gathers the feature axis back to full
+        replication at EXACTLY the points where the math reduces over
+        it -- before ``@ Wo``, both layer norms and ``@ W2`` -- so no
+        device sums a partial axis."""
+        rep = shard.rep if shard is not None else (lambda t: t)
+        cast = lambda w: w.astype(x.dtype)
+        att = rep(att) @ cast(p["Wo"]) + cast(p["bo"])
+        hdn = _layer_norm(rep(x + att), p["ln1_g"], p["ln1_b"], self.eps)
+        act = get_activation(self.activation or "gelu")
+        ffn = act(hdn @ cast(p["W1"]) + cast(p["b1"]))
+        ffn = rep(ffn) @ cast(p["W2"]) + cast(p["b2"])
+        return _layer_norm(rep(hdn + ffn), p["ln2_g"], p["ln2_b"], self.eps)
+
+    def step(self, p, x, attend, shard=None):
+        """x [rows, d], one new token a row: rows are slots in a decode
+        tick and ``B * W`` flat rows in a speculative verify (2-D
+        matmuls are row-bitwise-stable where a [B, W, d] contraction
+        need not be).  ``attend(q, k, v [rows, n_heads, head_dim]) ->
+        (att, cache)`` writes the rows' K/V and reads their context: a
+        dense cache offline, the paged pool in the server.  Returns
+        (y [rows, d], cache)."""
+        att, cache = attend(*self._qkv(p, x))
+        return self._tail(p, x, att.reshape(x.shape), shard), cache
+
+    def sequence(self, p, x, t0=None, prefix=None, shard=None):
+        """Whole-sequence causal forward, ONE batched pass: x [b, t, d]
+        -> (y, {"k", "v"} [b, n_heads, t, head_dim], the rows as a pool
+        holds them).  float32 scores, a -1e9 mask, softmax in float32:
+        the math of ``step()``'s dense cache, so a prefill and t cached
+        steps agree byte for byte.  Causal, so ``t0`` (a padded prompt's
+        real length) changes nothing a real position reads.
+
+        With ``prefix = (pk, pv [b, n_heads, P, head_dim], p0)`` the
+        rows of x are the UNCACHED suffix at positions p0.. and the keys
+        are [cached prefix ; suffix] (prefix columns >= p0 are padding
+        and masked).  Masked columns contribute EXACT zeros to the
+        softmax, so the suffix rows equal the whole prompt's: the
+        prefix-cache hit path's parity contract.  k / v are then the
+        suffix's rows only."""
+        b, t, d = x.shape
+        q, k, v = (z.transpose(0, 2, 1, 3) for z in self._qkv(p, x))
+        rows = jnp.arange(t)[:, None]
+        if prefix is None:
+            kk, vv = k, v
+            mask = jnp.arange(t)[None, :] <= rows
+        else:
+            pk, pv, p0 = prefix
+            n = pk.shape[2]
+            kk = jnp.concatenate([pk, k], axis=2)       # [b, h, P+t, dh]
+            vv = jnp.concatenate([pv, v], axis=2)
+            cols = jnp.arange(n + t)
+            col_g = jnp.where(cols < n, cols, p0 + cols - n)  # global pos
+            col_ok = jnp.where(cols < n, cols < p0, True)     # prefix pad
+            mask = col_ok[None, :] & (col_g[None, :] <= p0 + rows)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, kk).astype(jnp.float32) \
+            * (1.0 / (self.head_dim ** 0.5))
+        s = jnp.where(mask[None, None], s, -1e9)
+        w = jax.nn.softmax(s, axis=-1).astype(vv.dtype)
+        att = jnp.einsum("bhqk,bhkd->bhqd", w, vv)
+        att = att.transpose(0, 2, 1, 3).reshape(b, t, d)
+        return self._tail(p, x, att, shard), {"k": k, "v": v}

@@ -42,7 +42,8 @@ Design:
   position / remaining-budget / EOS-id / block-table / sampling params
   live in device-side state, sampling masks inactive slots, and cache
   writes land at (block, offset) targets routed through each slot's
-  table (``_block_decode_step_paged``);
+  table (``TransformerGenerator._step_paged``: every run's ``step()``
+  with the paged pool as its ``attend``);
 * the scheduler fuses up to ``tick_batch`` ticks into ONE device-side
   ``lax.scan`` (``_decode_scan``): sampled tokens stage in a [B, K]
   device buffer and the host polls ONCE per scan instead of once per
@@ -54,7 +55,8 @@ Design:
   preserving the poisoned-slot invariant below);
 * between ticks the host scheduler admits queued requests into free
   slots — ON A MISS prefill runs the existing batched causal forward
-  (``_prefill_rows`` scanned over the stacked block params) with the
+  (``_prefill_rows``: every run's ``sequence()`` scanned over its
+  stacked params) with the
   prompt padded to a power-of-two bucket rounded to the block size
   (bounds prefill recompiles at log2(L) variants; padded rows are
   never attended before being overwritten by decode writes); ON A
@@ -115,8 +117,9 @@ cheap draft model runs K tokens ahead per slot through its own block
 table (``dtable`` — ordinary pool blocks holding the first
 ``draft_layers`` layers of the pool leaves, claimed at admission in
 the same block economy), and the target model verifies the whole
-K+1-token chunk in ONE batched pass (``_verify_rows_paged`` +
-``kernels.paged_verify_attention``) — the agreeing prefix commits, the
+K+1-token chunk in ONE batched pass (``_verify_rows_paged``: the
+runs' ``step()`` over flat rows + ``kernels.paged_verify_attention``)
+— the agreeing prefix commits, the
 first disagreement falls back to the target's own argmax, so greedy
 output stays BYTE-IDENTICAL to non-speculative decode at every
 acceptance pattern (the verification runs flat-row matmuls and
@@ -196,6 +199,7 @@ from deeplearning4j_tpu.kernels import (pad_head_dim, paged_pool_width,
                                         paged_route, paged_walk_blocks,
                                         paged_walk_extent)
 from deeplearning4j_tpu.models.generation import (TransformerGenerator,
+                                                  _cast_floating,
                                                   _filter_logits_rows,
                                                   _filtered_logprobs_rows)
 from deeplearning4j_tpu.parallel import speculative as _speculative
@@ -752,19 +756,17 @@ class GenerationServer:
                  retry_backoff_s: float = 0.05):
         self._gen = TransformerGenerator(net, compute_dtype=compute_dtype)
         gen = self._gen
-        # a net with recurrent (state-space) layers keeps a fixed-size
-        # state per slot that K/V blocks do not hold: whatever would
-        # have to restore it from blocks refuses, by name
-        self._rec = gen.recurrent
-        if self._rec is not None:
-            n_dev = len(list(devices)) if devices is not None else 1
-            for on, what in (
-                    (prefix_cache, "prefix_cache=True"),
-                    (speculative is not None, "speculative decode"),
-                    (host_tier_blocks, "host_tier_blocks > 0"),
-                    ((tp or n_dev) > 1, "tp > 1")):
-                if on:
-                    raise ValueError(self._refusal(what))
+        # a stack may hold a kind of run whose rows K/V blocks cannot
+        # bring back (a recurrent layer's per-slot state) or that has no
+        # chunked sequence() / verify step() / shard points: whatever
+        # needs one of those refuses, by name
+        n_dev = len(list(devices)) if devices is not None else 1
+        for on, what in ((prefix_cache, "prefix_cache=True"),
+                         (speculative is not None, "speculative decode"),
+                         (host_tier_blocks, "host_tier_blocks > 0"),
+                         ((tp or n_dev) > 1, "tp > 1")):
+            if on:
+                self._refuse(what)
         self.n_slots = int(n_slots)
         if self.n_slots < 1:
             raise ValueError("n_slots must be >= 1")
@@ -939,8 +941,7 @@ class GenerationServer:
         if self._spec is not None:
             self._spec_ctl = _speculative.AcceptanceController(
                 self._spec.k_max,
-                draft_cost=(self._spec.draft.n_layers
-                            / len(gen.blocks)))
+                draft_cost=self._spec.draft.n_layers / gen.kv_layers)
         self._stop_event = threading.Event()   # ends the watchdog
         # retire prior DEAD servers' series before adding ours: the
         # last-known 0 stays scrapeable until the next construction,
@@ -960,13 +961,33 @@ class GenerationServer:
                                               daemon=True)
             self._watchdog.start()
 
+    def _refuse(self, what: str) -> None:
+        """Raise, by name, where the stack's run kinds cannot do
+        ``what``."""
+        if self._gen.refuses:
+            raise ValueError(f"{what} is not supported for a net with "
+                             f"{self._gen.refuses}")
+
+    # the recurrent runs' state rides in the carried ``state`` as two
+    # [layers, slots, ...] leaves -- d_inner on the lanes; donated, reset
+    # and salvaged with the pool -- and a stack that keeps none has no
+    # such leaf: this pair is where the generator's ``rec`` meets them
+    _REC_KEYS = ("rec_h", "rec_conv")
+
     @staticmethod
-    def _refusal(what: str) -> str:
-        return (f"{what} is not supported for a net with recurrent "
-                "(state-space) layers: shared or restored K/V blocks "
-                "cannot restore a slot's recurrent state (snapshots of "
-                "it at block boundaries are later work), and the state "
-                "is not sharded")
+    def _rec_of(state):
+        """``state``'s recurrent leaves as the generator takes them
+        (None: the stack keeps no such state)."""
+        if "rec_h" not in state:
+            return None
+        return {"h": state["rec_h"], "conv": state["rec_conv"]}
+
+    @staticmethod
+    def _with_rec(state, rec):
+        """``state`` with the generator's ``rec`` (None: as it is)."""
+        if rec is None:
+            return state
+        return {**state, "rec_h": rec["h"], "rec_conv": rec["conv"]}
 
     def _fresh_pool(self):
         """(Re)allocate the KV block pool and per-slot device state —
@@ -1035,12 +1056,8 @@ class GenerationServer:
         if self._shard is not None:
             state = {k: self._shard.put_batch(v)
                      for k, v in state.items()}
-        if self._rec is not None:
-            # the recurrent layers' per-slot state, [layers, slots, ..],
-            # d_inner on the lanes: carried, donated and reset with the
-            # pool (zeros: a slot that has seen nothing)
-            rec = gen.fresh_rec(B)
-            state["rec_h"], state["rec_conv"] = rec["h"], rec["conv"]
+        # zeros: a slot that has seen nothing
+        state = self._with_rec(state, gen.fresh_rec(B))
         _REC_BYTES.set(sum(state[k].nbytes for k in self._REC_KEYS
                            if k in state))
         # commit atomically: this also runs on the watchdog's recovery
@@ -1071,9 +1088,6 @@ class GenerationServer:
         _POOL_FREE.set(self.kv_blocks)
         _POOL_EVICTABLE.set(0)
 
-    #: the state leaves that are [layers, slots, ...], not [slots, ...]
-    _REC_KEYS = ("rec_h", "rec_conv")
-
     # -- public API ----------------------------------------------------
     def refresh_params(self):
         """Snapshot the net's params for serving: block params stacked
@@ -1081,20 +1095,13 @@ class GenerationServer:
         bf16) every floating leaf cast ONCE — the decode tick re-reads
         every parameter each tick, and streaming f32-stored weights
         would cost 2x the bytes of the math performed.  Call again
-        after the underlying net's weights change.  A stack of block
-        runs is stacked as the net holds it: its snapshot IS the tree
-        (a same-dtype cast copies nothing), one copy of the weights."""
+        after the underlying net's weights change.  Runs made stacked
+        are snapshot as the net holds them: the snapshot IS the tree (a
+        same-dtype cast copies nothing), one copy of the weights."""
         gen = self._gen
         emb_p, blk_ps, head_p = gen._params()
-        blk_stack = gen._stack_blocks(blk_ps)
-        if gen.compute_dtype != jnp.float32:
-            cd = gen.compute_dtype
-            cast = lambda t: jax.tree_util.tree_map(
-                lambda a: (a.astype(cd)
-                           if jnp.issubdtype(a.dtype, jnp.floating)
-                           else a), t)
-            emb_p, blk_stack, head_p = (cast(emb_p), cast(blk_stack),
-                                        cast(head_p))
+        emb_p, blk_stack, head_p = _cast_floating(
+            (emb_p, gen._stack_blocks(blk_ps), head_p), gen.compute_dtype)
         if self._shard is not None:
             emb_p, blk_stack, head_p = self._place_params(
                 emb_p, blk_stack, head_p)
@@ -1112,45 +1119,32 @@ class GenerationServer:
                 self._draft_params = self._place_params(
                     *self._draft_params)
 
-    #: output-axis shard map for the stacked block params (ISSUE 17):
-    #: every named axis is an OUTPUT axis — qkv/mlp columns — so no
-    #: contraction is ever split (the TpShardCtx parity contract);
-    #: everything absent (layer norms) replicates.
+    #: output-axis shard maps of a serving snapshot (ISSUE 17), by
+    #: leaf name: every named axis of a block's weights is an OUTPUT
+    #: axis — qkv/mlp columns — so no contraction is ever split (the
+    #: TpShardCtx parity contract); the embedding / positional tables
+    #: shard their vocab / position ROWS (gathered by token id — pure
+    #: data movement), the head its vocab columns; everything absent
+    #: (layer norms, a run kind without shard points) replicates.
+    _EMB_SHARD_AXES = {"W": ("tp", None), "P": ("tp", None)}
     _BLK_SHARD_AXES = {
         "Wqkv": (None, None, "tp"), "bqkv": (None, "tp"),
         "Wo": (None, None, "tp"), "bo": (None, "tp"),
         "W1": (None, None, "tp"), "b1": (None, "tp"),
         "W2": (None, None, "tp"), "b2": (None, "tp"),
     }
+    _HEAD_SHARD_AXES = {"W": (None, "tp"), "b": ("tp",)}
 
     def _place_params(self, emb_p, blk_stack, head_p):
-        """Spread one serving snapshot over the replica's mesh: block
-        weights by :attr:`_BLK_SHARD_AXES`, the embedding/positional
-        tables by their vocab/position ROWS (gathered by token id —
-        pure data movement), the head by its vocab columns.  ``put``
-        falls any axis the tp extent does not divide back to
-        replication, so odd vocab sizes etc. cost memory, never
-        parity."""
-        shard = self._shard
-        if self._rec is not None:     # one device: replicated is placed
-            return jax.tree_util.tree_map(shard.put,
-                                          (emb_p, blk_stack, head_p))
-        emb_p = dict(emb_p)
-        for k, axes in (("W", ("tp", None)), ("P", ("tp", None))):
-            if k in emb_p:
-                emb_p[k] = shard.put(emb_p[k], *axes)
-        for k in ("g", "b"):
-            if k in emb_p:
-                emb_p[k] = shard.put(emb_p[k])
-        blk_stack = {
-            k: shard.put(v, *self._BLK_SHARD_AXES.get(k, ()))
-            for k, v in blk_stack.items()}
-        head_p = dict(head_p)
-        if "W" in head_p:
-            head_p["W"] = shard.put(head_p["W"], None, "tp")
-        if "b" in head_p:
-            head_p["b"] = shard.put(head_p["b"], "tp")
-        return emb_p, blk_stack, head_p
+        """Spread one serving snapshot over the replica's mesh by the
+        maps above.  ``put`` falls any axis the tp extent does not
+        divide back to replication, so odd vocab sizes etc. cost
+        memory, never parity."""
+        put = lambda axes, p: {k: self._shard.put(v, *axes.get(k, ()))
+                               for k, v in p.items()}
+        return (put(self._EMB_SHARD_AXES, emb_p),
+                tuple(put(self._BLK_SHARD_AXES, p) for p in blk_stack),
+                put(self._HEAD_SHARD_AXES, head_p))
 
     def healthy(self) -> bool:
         """True while the scheduler thread is alive and admission is
@@ -1293,8 +1287,7 @@ class GenerationServer:
         donating dispatch on accelerator backends, so it retries
         (bounded by ``max_wait_s``) until a committed pool snapshot
         reads clean."""
-        if self._rec is not None:
-            raise ValueError(self._refusal("export_prefix"))
+        self._refuse("export_prefix")
         prompt = np.asarray(prompt_ids, np.int32)
         if prompt.ndim != 1 or prompt.size == 0:
             return []
@@ -1337,8 +1330,7 @@ class GenerationServer:
         every later same-prefix admission then maps them copy-free.
         Entries whose chain hash is already device-resident (verified)
         are skipped.  Returns how many blocks landed."""
-        if self._rec is not None:
-            raise ValueError(self._refusal("import_blocks"))
+        self._refuse("import_blocks")
         n = n_bytes = 0
         tier = None
         for hsh, tok, k, v in payload:
@@ -1760,8 +1752,7 @@ class GenerationServer:
         the decode replica's :meth:`import_blocks` — whose admission
         of the same prompt then prefills only the last partial
         block."""
-        if self._rec is not None:
-            raise ValueError(self._refusal("prefill_async"))
+        self._refuse("prefill_async")
         if not self.prefix_cache:
             raise ValueError("prefill_async needs prefix_cache=True "
                              "(a prefill-only request's sole product "
@@ -1962,7 +1953,6 @@ class GenerationServer:
         pick = self._sampler(sampled)
         bs = self.block_size
         shard = self._shard
-        recurrent = self._rec is not None
 
         # the jitted callable's __name__ names the program in a
         # profile (module ``jit_decode_scan``), the scope one tick's
@@ -1984,20 +1974,17 @@ class GenerationServer:
                     tbl, (pos // bs)[:, None], axis=1)[:, 0]
                 wblk = jnp.where(active, bidx, 0)
                 woff = jnp.where(active, pos % bs, 0)
-                # a stack with recurrent layers also takes and gives
-                # their state; inactive slots keep theirs bit for bit (a
-                # retired slot's may not drift to inf)
-                carried = ({"rec": {"h": state["rec_h"],
-                                    "conv": state["rec_conv"]},
-                            "active": active} if recurrent else {})
-                new_logits, kc, vc, *rec = gen._step_paged(
+                # recurrent layers' state goes in and comes out too;
+                # inactive slots keep theirs bit for bit (a retired
+                # slot's may not drift to inf)
+                new_logits, kc, vc, rec = gen._step_paged(
                     emb_p, blk_stack, head_p, kc, vc, tok, pos, tbl,
                     wblk, woff, shard=shard, kernel_writes=True,
-                    **carried)
+                    rec=self._rec_of(state), active=active)
                 hit_eos = active & (tok == state["eos"])
                 remaining = jnp.where(active, state["remaining"] - 1, 0)
                 remaining = jnp.where(hit_eos, 0, remaining)
-                state = {
+                state = self._with_rec({
                     "pos": jnp.where(active, state["pos"] + 1,
                                      state["pos"]),
                     "remaining": remaining,
@@ -2019,10 +2006,7 @@ class GenerationServer:
                     # (residuals only arise on sampled slots)
                     "rawlg": ((state["rawlg"] & ~active)
                               if sampled else state["rawlg"]),
-                }
-                if recurrent:
-                    state["rec_h"], state["rec_conv"] = (rec[0]["h"],
-                                                         rec[0]["conv"])
+                }, rec)
                 emitted = emitted + active.astype(jnp.int32)
                 return (kc, vc, state, emitted), tok
 
@@ -2041,172 +2025,29 @@ class GenerationServer:
                                              donate_argnums=(3, 4, 5))
         return fn
 
-    def _spec_fn(self, R: int):
-        """R speculative rounds fused into ONE dispatch (cached per R;
-        the speculative analogue of ``_decode_scan``).  Each round:
-        anchor from the held target logits, K draft proposals through
-        the slot's draft table (the first ``draft.n_layers`` pool
-        layers), ONE batched W = K+1-token target verification through
-        the slot's block table, then :func:`speculative.accept_greedy`
-        — the committed tokens stage into a [B, R*W] device buffer at
-        each slot's running cursor, so the host unpacks exactly the
-        PR 5 way (``toks_h[slot, :emitted]``).
+    def _spec_fn2(self, R: int, K: int, sampled: bool):
+        """R speculative rounds fused into ONE dispatch (cached per
+        (R, K, sampled); the speculative analogue of ``_decode_scan``).
+        Each round: anchor from the held target logits, K draft
+        proposals through the slot's draft table (the first
+        ``draft.n_layers`` pool layers), ONE batched W = K+1-token
+        target verification through the slot's block table, then the
+        acceptance rule — the committed tokens stage into a [B, R*W]
+        device buffer at each slot's running cursor, so the host
+        unpacks exactly the PR 5 way (``toks_h[slot, :emitted]``).
 
         Masking: a round's writes past a slot's remaining budget land
         in the scratch block 0 with embed positions clamped to 0 (the
         PR 2 OOB-positional NaN class), and rejected-suffix rows roll
         back by ``pos`` simply not advancing over them — the blocks
         were claimed at admission, so the next round overwrites in
-        place.  Returns ``(kc, vc, state, toks [B, R*W], emitted [B],
-        n_alive, proposed, accepted)`` — the last two feed the
-        ``generation_server_spec_*`` counters."""
-        key = ("spec", int(R))
-        fn = self._scan_cache.get(key)
-        if fn is not None:
-            return fn
-        gen = self._gen
-        spec = self._spec
-        dgen = spec.draft.gen
-        d = spec.draft.n_layers
-        K = spec.k
-        W = K + 1
-        bs = self.block_size
-        B = self.n_slots
-        shard = self._shard
+        place.
 
-        def spec_fn(emb_p, blk_stack, head_p, demb_p, dblk, dhead_p,
-                    kc, vc, state):
-            # the draft's layer slice happens IN-TRACE: a self-draft
-            # passes the target's stack verbatim (zero extra device
-            # memory) and an external draft's own d-layer stack
-            # slices to itself
-            dblk = jax.tree_util.tree_map(lambda a: a[:d], dblk)
-            jidx = jnp.arange(W)[None, :]
-
-            def round_body(carry, _):
-                kc, vc, state, staged, emitted, prop, acc = carry
-                active = state["remaining"] > 0
-                pos, rem = state["pos"], state["remaining"]
-                tbl, dtbl = state["table"], state["dtable"]
-                anchor = jnp.where(
-                    active, jnp.argmax(state["logits"], axis=-1),
-                    0).astype(jnp.int32)
-
-                # -- draft: K cheap proposals through the draft table.
-                # The scan runs W = K+1 consume steps, not K: step j
-                # consumes chunk token v_j at pos+j (writing its draft
-                # KV) and proposes v_{j+1}.  The LAST step's proposal
-                # is discarded, but its WRITE matters — on a full
-                # accept the round advances pos over v_K, and a draft
-                # row never consumed would leave a hole in the draft's
-                # context that degrades every later round's proposals
-                # (measured: full-depth self-draft acceptance fell to
-                # 2/3 without it; 1.0 with it).
-                kcd, vcd = kc[:d], vc[:d]
-
-                def dstep(c, j):
-                    kcd, vcd, tok = c
-                    ok = active & (j < rem)
-                    p = jnp.where(ok, pos + j, 0)
-                    bidx = jnp.take_along_axis(
-                        dtbl, (p // bs)[:, None], axis=1)[:, 0]
-                    wblk = jnp.where(ok, bidx, 0)
-                    woff = jnp.where(ok, p % bs, 0)
-                    lg, kcd, vcd = dgen._step_paged(
-                        demb_p, dblk, dhead_p, kcd, vcd, tok, p,
-                        dtbl, wblk, woff, shard=shard)
-                    nxt = jnp.where(ok, jnp.argmax(lg, axis=-1),
-                                    0).astype(jnp.int32)
-                    return (kcd, vcd, nxt), tok
-
-                (kcd, vcd, _), consumed = jax.lax.scan(
-                    dstep, (kcd, vcd, anchor), jnp.arange(W))
-                kc = kc.at[:d].set(kcd)
-                vc = vc.at[:d].set(vcd)
-                v = consumed.T                            # [B, W]
-
-                # -- verify: one batched W-token target pass
-                okv = active[:, None] & (jidx < rem[:, None])
-                p = pos[:, None] + jidx
-                epos = jnp.where(okv, p, 0)
-                vtok = jnp.where(okv, v, 0)
-                bidx = jnp.take_along_axis(
-                    tbl, jnp.where(okv, p // bs, 0), axis=1)
-                wblk = jnp.where(okv, bidx, 0)
-                woff = jnp.where(okv, p % bs, 0)
-                pos0 = jnp.where(active, pos, 0)
-                G, kc, vc = gen._verify_rows_paged(
-                    emb_p, blk_stack, head_p, kc, vc, vtok, pos0,
-                    epos, tbl, wblk, woff, shard=shard)
-                g = jnp.argmax(G, axis=-1).astype(jnp.int32)
-                c, rem_after = _speculative.accept_greedy(
-                    v, g, active, rem, state["eos"])
-                sel = jnp.maximum(c - 1, 0)
-                new_logits = G[jnp.arange(B), sel]
-                state = {
-                    "pos": jnp.where(active, pos + c, pos),
-                    "remaining": jnp.where(active, rem_after, rem),
-                    "eos": state["eos"],
-                    "logits": jnp.where(active[:, None], new_logits,
-                                        state["logits"]),
-                    "key": state["key"],
-                    "temp": state["temp"],
-                    "tk": state["tk"],
-                    "tp": state["tp"],
-                    "table": tbl,
-                    "dtable": dtbl,
-                    # greedy-only program: no residual can be live in
-                    # this dispatch (the sampled-capable variant is
-                    # _spec_fn2) — pure passthrough
-                    "rawlg": state["rawlg"],
-                }
-                # -- stage the commits at each slot's cursor (the
-                # [B, K]-buffer idiom from PR 5, cursor-scattered;
-                # uncommitted columns dump into the extra column)
-                rows = jnp.arange(B)[:, None]
-                keep = active[:, None] & (jidx < c[:, None])
-                cols = jnp.where(keep, emitted[:, None] + jidx, R * W)
-                staged = staged.at[rows, cols].set(v)
-                emitted = emitted + c
-                # proposals that COULD commit: at most remaining-1
-                # beyond the anchor (the draft's tail past a slot's
-                # budget is masked garbage, not a real proposal), and
-                # when a committed EOS ended the stream (rem_after 0
-                # with budget left) everything behind the cut was
-                # flushed, not rejected — so a perfect draft scores
-                # acceptance exactly 1.0 through budget tails AND
-                # EOS-terminated requests
-                prop_i = jnp.clip(jnp.minimum(K, rem - 1), 0, K)
-                prop_i = jnp.where((rem_after == 0) & (c < rem),
-                                   jnp.maximum(c - 1, 0), prop_i)
-                prop = prop + jnp.sum(jnp.where(
-                    active, prop_i, 0).astype(jnp.int32))
-                acc = acc + jnp.sum(jnp.maximum(c - 1, 0))
-                return (kc, vc, state, staged, emitted, prop, acc), None
-
-            staged0 = jnp.zeros((B, R * W + 1), jnp.int32)
-            emitted0 = jnp.zeros((B,), jnp.int32)
-            (kc, vc, state, staged, emitted, prop, acc), _ = \
-                jax.lax.scan(round_body,
-                             (kc, vc, state, staged0, emitted0,
-                              jnp.int32(0), jnp.int32(0)),
-                             None, length=R)
-            n_alive = jnp.sum((state["remaining"] > 0)
-                              .astype(jnp.int32))
-            return (kc, vc, state, staged[:, :R * W], emitted,
-                    n_alive, prop, acc)
-
-        fn = self._scan_cache[key] = jax.jit(spec_fn,
-                                             donate_argnums=(6, 7, 8))
-        return fn
-
-    def _spec_fn2(self, R: int, K: int, sampled: bool):
-        """The kcap-aware speculative program (ISSUE 20): R rounds at
-        dispatch depth ``K`` (the pool max of the per-slot adaptive
-        depths) with a per-slot ``kcap`` [B] operand masking each
-        slot's proposals down to ITS depth, and — with
-        ``sampled=True`` — Leviathan rejection resampling for
-        temperature>0 rows riding the same flat-row verify:
+        ``K`` is the dispatch depth (the pool max of the per-slot
+        depths, ISSUE 20) and a per-slot ``kcap`` [B] operand masks
+        each slot's proposals down to ITS depth.  With
+        ``sampled=True`` temperature>0 rows ride the same flat-row
+        verify through Leviathan rejection resampling:
 
         * the anchor of a sampled row is drawn from the slot's held
           distribution (its own temperature/top-k/top-p shaping, or
@@ -2232,9 +2073,10 @@ class GenerationServer:
         sequence depends only on its seed and its own acceptance
         history, invariant to R batching and pool composition.
 
-        Returns the legacy tuple with ``proposed`` / ``accepted`` as
-        [B] PER-SLOT vectors (the host attributes them to tenants and
-        feeds the acceptance controller)."""
+        Returns ``(kc, vc, state, toks [B, R*W], emitted [B], n_alive,
+        proposed [B], accepted [B])`` — the last two PER SLOT: the host
+        attributes them to tenants, feeds the acceptance controller and
+        sums them into the ``generation_server_spec_*`` counters."""
         key = ("spec", int(R), int(K), bool(sampled))
         fn = self._scan_cache.get(key)
         if fn is not None:
@@ -2285,10 +2127,16 @@ class GenerationServer:
                     anchor = g_anchor
                 anchor = jnp.where(active, anchor, 0).astype(jnp.int32)
 
-                # -- draft: K proposals through the draft table; same
-                # W = K+1 consume-step discipline as _spec_fn (the
-                # last step's proposal is discarded but its WRITE
-                # keeps the draft context hole-free)
+                # -- draft: K proposals through the draft table.  The
+                # scan runs W = K+1 consume steps, not K: step j
+                # consumes chunk token v_j at pos+j (writing its draft
+                # KV) and proposes v_{j+1}.  The LAST step's proposal
+                # is discarded, but its WRITE matters — on a full
+                # accept the round advances pos over v_K, and a draft
+                # row never consumed would leave a hole in the draft's
+                # context that degrades every later round's proposals
+                # (measured: full-depth self-draft acceptance fell to
+                # 2/3 without it; 1.0 with it).
                 kcd, vcd = kc[:d], vc[:d]
 
                 def dstep(c, j):
@@ -2299,7 +2147,7 @@ class GenerationServer:
                         dtbl, (p // bs)[:, None], axis=1)[:, 0]
                     wblk = jnp.where(ok, bidx, 0)
                     woff = jnp.where(ok, p % bs, 0)
-                    lg, kcd, vcd = dgen._step_paged(
+                    lg, kcd, vcd, _ = dgen._step_paged(
                         demb_p, dblk, dhead_p, kcd, vcd, tok, p,
                         dtbl, wblk, woff, shard=shard)
                     if sampled:
@@ -2331,7 +2179,7 @@ class GenerationServer:
                 wblk = jnp.where(okv, bidx, 0)
                 woff = jnp.where(okv, p % bs, 0)
                 pos0 = jnp.where(active, pos, 0)
-                G, kc, vc = gen._verify_rows_paged(
+                G, kc, vc, _ = gen._verify_rows_paged(
                     emb_p, blk_stack, head_p, kc, vc, vtok, pos0,
                     epos, tbl, wblk, woff, shard=shard)
                 g = jnp.argmax(G, axis=-1).astype(jnp.int32)
@@ -2398,14 +2246,23 @@ class GenerationServer:
                     "dtable": dtbl,
                     "rawlg": new_rawlg,
                 }
+                # -- stage the commits at each slot's cursor (the
+                # [B, K]-buffer idiom from PR 5, cursor-scattered;
+                # uncommitted columns dump into the extra column)
                 rows = jnp.arange(B)[:, None]
                 keep = active[:, None] & (jidx < c[:, None])
                 cols = jnp.where(keep, emitted[:, None] + jidx, R * W)
                 staged = staged.at[rows, cols].set(v)
                 emitted = emitted + c
-                # per-slot tallies — EOS flush adjustment as in
-                # _spec_fn, but kept [B] so the host can attribute
-                # acceptance to tenants and feed the controller
+                # per-slot tallies of proposals that COULD commit
+                # (n_eval: at most remaining-1 beyond the anchor — the
+                # draft's tail past a slot's budget is masked garbage,
+                # not a real proposal — and at most kcap); when a
+                # committed EOS ended the stream (rem_after 0 with
+                # budget left) everything behind the cut was flushed,
+                # not rejected — so a perfect draft scores acceptance
+                # exactly 1.0 through budget tails AND EOS-terminated
+                # requests
                 prop_i = jnp.where((rem_after == 0) & (c < rem),
                                    jnp.maximum(c - 1, 0), n_eval)
                 prop = prop + jnp.where(active, prop_i, 0)
@@ -2470,15 +2327,14 @@ class GenerationServer:
     def _arm_slot(self, state, logits, slot, t0, n_new, eos_id, key,
                   temp, tk, tp, table_row, dtable_row, rec=None):
         """Slot device-state update shared by both admit programs.
-        ``rec`` (a net with recurrent layers): the prefill's recurrent
+        ``rec`` (a stack with recurrent layers): the prefill's recurrent
         state of the one admitted row, as after its last real token."""
-        armed = {k: state[k] for k in self._REC_KEYS if k in state}
+        armed = self._rec_of(state)
         if rec is not None:
-            for k, rows in (("rec_h", rec["h"]), ("rec_conv", rec["conv"])):
-                armed[k] = jax.lax.dynamic_update_slice(
-                    state[k], rows.astype(state[k].dtype), (0, slot, 0, 0))
-        return {
-            **armed,
+            armed = {k: jax.lax.dynamic_update_slice(
+                all_rows, rec[k].astype(all_rows.dtype), (0, slot, 0, 0))
+                for k, all_rows in armed.items()}
+        return self._with_rec({
             "pos": state["pos"].at[slot].set(t0),
             "remaining": state["remaining"].at[slot].set(n_new),
             "eos": state["eos"].at[slot].set(eos_id),
@@ -2494,7 +2350,7 @@ class GenerationServer:
             "dtable": jax.lax.dynamic_update_slice(
                 state["dtable"], dtable_row[None], (slot, 0)),
             "rawlg": state["rawlg"].at[slot].set(False),
-        }
+        }, armed)
 
     def _admit_miss_fn(self, tb: int, use_draft: bool = True):
         """Prefix-MISS admission program for prefill bucket ``tb`` (a
@@ -2518,7 +2374,7 @@ class GenerationServer:
             # t0 picks the last REAL position's logits out of the
             # padded bucket (and, of a recurrent layer, the state as
             # after that position)
-            logits, ks, vs, *rec = gen._prefill_rows(
+            logits, ks, vs, rec = gen._prefill_rows(
                 emb_p, blk_stack, head_p, prompt, t0, shard=shard)
             kc = self._scatter_rows(kc, ks, phys)
             vc = self._scatter_rows(vc, vs, phys)
@@ -2531,13 +2387,13 @@ class GenerationServer:
                 demb_p, dblk, dhead_p, dphys = draft_ops
                 dblk = jax.tree_util.tree_map(
                     lambda a: a[:spec.draft.n_layers], dblk)
-                _, dks, dvs = spec.draft.gen._prefill_rows(
+                _, dks, dvs, _ = spec.draft.gen._prefill_rows(
                     demb_p, dblk, dhead_p, prompt, t0, shard=shard)
                 kc = self._scatter_rows(kc, dks, dphys)
                 vc = self._scatter_rows(vc, dvs, dphys)
             state = self._arm_slot(state, logits, slot, t0, n_new,
                                    eos_id, key, temp, tk, tp, table_row,
-                                   dtable_row, *rec)
+                                   dtable_row, rec)
             return kc, vc, state
 
         fn = self._admit_cache[key] = jax.jit(admit_miss,
@@ -2598,7 +2454,7 @@ class GenerationServer:
                 draft_ops = extra_ops
             pk = self._gather_rows(kc, prefix_phys)
             pv = self._gather_rows(vc, prefix_phys)
-            logits, ks, vs = gen._prefill_rows_chunked(
+            logits, ks, vs, rec = gen._prefill_rows_chunked(
                 emb_p, blk_stack, head_p, suffix, pk, pv, p0, last_ix,
                 shard=shard)
             kc = self._scatter_rows(kc, ks, phys)
@@ -2617,21 +2473,21 @@ class GenerationServer:
                     dpk = self._gather_rows(kc[:dl], dprefix_phys)
                     dpv = self._gather_rows(vc[:dl], dprefix_phys)
                     dp0 = dmatched * self.block_size
-                    _, dks, dvs = spec.draft.gen._prefill_rows_chunked(
+                    _, dks, dvs, _ = spec.draft.gen._prefill_rows_chunked(
                         demb_p, dblk, dhead_p, dsuffix, dpk, dpv,
                         jnp.int32(dp0), t0 - dp0 - 1, shard=shard)
                 else:
                     demb_p, dblk, dhead_p, dprompt, dphys = draft_ops
                     dblk = jax.tree_util.tree_map(
                         lambda a: a[:dl], dblk)
-                    _, dks, dvs = spec.draft.gen._prefill_rows(
+                    _, dks, dvs, _ = spec.draft.gen._prefill_rows(
                         demb_p, dblk, dhead_p, dprompt, t0,
                         shard=shard)
                 kc = self._scatter_rows(kc, dks, dphys)
                 vc = self._scatter_rows(vc, dvs, dphys)
             state = self._arm_slot(state, logits, slot, t0, n_new,
                                    eos_id, key, temp, tk, tp, table_row,
-                                   dtable_row)
+                                   dtable_row, rec)
             return kc, vc, state
 
         fn = self._admit_cache[key] = jax.jit(admit_hit,
@@ -3337,36 +3193,26 @@ class GenerationServer:
                 # the fallback — ``rawlg`` rows sample it through the
                 # plain scan's pick_sampled)
                 use_spec = self._spec is not None and not spec_off
-                legacy_spec = (use_spec and not sampled
-                               and not self._spec.adaptive
-                               and draft_cap is None)
                 kcap_arr = None
                 if use_spec:
-                    if legacy_spec:
-                        # the PR 11 program, byte-for-byte: fixed-K
-                        # all-greedy pools keep its exact compile
-                        K_disp = self._spec.k
-                    else:
-                        # per-slot draft depth: the acceptance
-                        # controller's pick (adaptive) or the fixed k,
-                        # both clamped by the degrade ladder's cap;
-                        # the dispatch compiles at the pool max and a
-                        # [B] kcap operand masks each slot down to its
-                        # own depth (depths change per tick without
-                        # recompiling)
-                        kcap_arr = np.zeros((self.n_slots,), np.int32)
-                        ctl = self._spec_ctl
-                        for slot, r in live_items:
-                            if self._spec.adaptive:
-                                k_i = ctl.k_for((r.tenant, r.pkey),
-                                                cap=draft_cap)
-                            elif draft_cap is not None:
-                                k_i = max(1, min(self._spec.k,
-                                                 draft_cap))
-                            else:
-                                k_i = self._spec.k
-                            kcap_arr[slot] = k_i
-                        K_disp = int(max(1, kcap_arr.max()))
+                    # per-slot draft depth: the acceptance
+                    # controller's pick (adaptive) or the fixed k,
+                    # both clamped by the degrade ladder's cap; the
+                    # dispatch compiles at the pool max and a [B] kcap
+                    # operand masks each slot down to its own depth
+                    # (depths change per tick without recompiling)
+                    kcap_arr = np.zeros((self.n_slots,), np.int32)
+                    ctl = self._spec_ctl
+                    for slot, r in live_items:
+                        if self._spec.adaptive:
+                            k_i = ctl.k_for((r.tenant, r.pkey),
+                                            cap=draft_cap)
+                        elif draft_cap is not None:
+                            k_i = max(1, min(self._spec.k, draft_cap))
+                        else:
+                            k_i = self._spec.k
+                        kcap_arr[slot] = k_i
+                    K_disp = int(max(1, kcap_arr.max()))
                     # adaptive round count, the scan-length rule's
                     # analogue: a single round while admission is
                     # pending (a join waits at most one W-wide round
@@ -3415,13 +3261,7 @@ class GenerationServer:
                     with prof.measure("verify" if use_spec
                                       else "decode_tick",
                                       devices=self._device_labels):
-                        if use_spec and legacy_spec:
-                            demb_p, dblk, dhead_p = self._draft_params
-                            (kc, vc, state, toks, emitted, n_alive,
-                             prop, acc) = self._spec_fn(R)(
-                                emb_p, blk_stack, head_p, demb_p, dblk,
-                                dhead_p, kc_in, vc_in, state_in)
-                        elif use_spec:
+                        if use_spec:
                             demb_p, dblk, dhead_p = self._draft_params
                             (kc, vc, state, toks, emitted, n_alive,
                              prop, acc) = self._spec_fn2(
@@ -3444,10 +3284,8 @@ class GenerationServer:
                         rem_h = np.asarray(state["remaining"])
                         alive_h = int(n_alive)
                     prop_h = acc_h = None
-                    if use_spec and legacy_spec:
-                        n_prop, n_acc = int(prop), int(acc)
-                    elif use_spec:
-                        # the kcap program tallies PER SLOT, so the
+                    if use_spec:
+                        # the program tallies PER SLOT, so the
                         # host can attribute acceptance to tenants and
                         # feed the controller
                         prop_h = np.asarray(prop)

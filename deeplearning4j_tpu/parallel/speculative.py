@@ -57,21 +57,21 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.models.generation import TransformerGenerator
+from deeplearning4j_tpu.models.generation import (TransformerGenerator,
+                                                  _cast_floating)
 
 
 class DraftModel:
     """The draft side of a speculative server: ``gen`` supplies the
-    layer math (its block conf drives ``_step_paged`` /
-    ``_prefill_rows``), ``n_layers`` is the draft depth — the slice of
-    the pool leaves its KV occupies — and :meth:`params` derives the
-    draft's (emb, stacked blocks, head) from the server's refreshed
-    target params (a self-draft slices them; an external draft
-    snapshots its own net)."""
+    layer math (``_step_paged`` / ``_prefill_rows`` over however many
+    layers its stacked params hold), ``n_layers`` is the draft depth —
+    the slice of the pool leaves its KV occupies — and :meth:`params`
+    derives the draft's (emb, stacked runs, head) from the server's
+    refreshed target params (a self-draft slices them; an external
+    draft snapshots its own net)."""
 
     def __init__(self, gen: TransformerGenerator, n_layers: int,
                  params_fn):
@@ -94,7 +94,7 @@ class DraftModel:
         target's heads and passes trivially, but an external draft
         with an incompatible head count must fail at construction, not
         as a GSPMD error mid-admission."""
-        h = self.gen.blocks[0].n_heads
+        h = self.gen.kv_heads
         if tp > 1 and h % tp:
             raise ValueError(
                 f"draft n_heads={h} must divide by tp={tp} (draft KV "
@@ -109,7 +109,7 @@ def make_self_draft(gen: TransformerGenerator,
     target step per proposal and needs no extra weights; its params
     are SLICES of the server's cast target params, so a
     ``refresh_params`` refreshes both for free."""
-    n = len(gen.blocks)
+    n = gen.kv_layers
     d = max(1, n // 2) if draft_layers is None else int(draft_layers)
     if not 1 <= d <= n:
         raise ValueError(
@@ -135,37 +135,29 @@ def make_draft(gen: TransformerGenerator, draft_net) -> DraftModel:
     occupies the first ``n_layers`` pool layers)."""
     dgen = TransformerGenerator(
         draft_net, compute_dtype=np.dtype(gen.compute_dtype).name)
-    d = len(dgen.blocks)
-    if d > len(gen.blocks):
+    d = dgen.kv_layers
+    if d > gen.kv_layers:
         raise ValueError(
-            f"draft depth {d} exceeds the target's {len(gen.blocks)} "
+            f"draft depth {d} exceeds the target's {gen.kv_layers} "
             "(draft KV lives in the first layers of the target's pool)")
-    if dgen.blocks[0].n_heads != gen.blocks[0].n_heads:
+    if dgen.kv_heads != gen.kv_heads:
         raise ValueError(
-            f"draft n_heads {dgen.blocks[0].n_heads} != target "
-            f"{gen.blocks[0].n_heads} (pool K/V layout is per-head)")
+            f"draft n_heads {dgen.kv_heads} != target "
+            f"{gen.kv_heads} (pool K/V layout is per-head)")
     if dgen.emb.n_out != gen.emb.n_out:
         raise ValueError(
             f"draft d_model {dgen.emb.n_out} != target {gen.emb.n_out} "
             "(pool K/V rows are [h, dh])")
-    v_t = int(np.shape(gen._params()[2]["W"])[-1])
-    v_d = int(np.shape(dgen._params()[2]["W"])[-1])
+    v_t, v_d = gen.vocab_size, dgen.vocab_size
     if v_d != v_t:
         raise ValueError(f"draft vocab {v_d} != target vocab {v_t} "
                          "(proposals must index target logits)")
 
     def params_fn(_target_params):
         emb_p, blk_ps, head_p = dgen._params()
-        blk_stack = dgen._stack_blocks(blk_ps)
-        if dgen.compute_dtype != jnp.float32:
-            cd = dgen.compute_dtype
-            cast = lambda t: jax.tree_util.tree_map(
-                lambda a: (a.astype(cd)
-                           if jnp.issubdtype(a.dtype, jnp.floating)
-                           else a), t)
-            emb_p, blk_stack, head_p = (cast(emb_p), cast(blk_stack),
-                                        cast(head_p))
-        return emb_p, blk_stack, head_p
+        return _cast_floating(
+            (emb_p, dgen._stack_blocks(blk_ps), head_p),
+            dgen.compute_dtype)
 
     return DraftModel(dgen, d, params_fn)
 

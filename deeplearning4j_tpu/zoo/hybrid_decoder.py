@@ -16,10 +16,12 @@ parameters' own dtype (``"bfloat16"`` for a served-only model: a
 float32 ``init()`` of a 3B-parameter net is 12 GB).
 
 Inference only: the head has no loss.  ``TransformerGenerator`` decodes
-it offline and ``GenerationServer`` serves it -- with per-slot
-recurrent state beside the paged K/V pool, and without the features
-that would have to restore that state from K/V blocks (prefix reuse,
-speculation, the host tier, prefill hand-off, ``tp > 1``), which raise.
+it offline (walking each run's ``sequence()`` and ``step()``) and
+``GenerationServer`` serves it -- with per-slot recurrent state beside
+the paged K/V pool, and without what its run kinds cannot do (their
+``REFUSES``: prefix reuse, speculation, the host tier, prefill
+hand-off, ``tp > 1``), which raises at construction -- for a stack of
+attention runs alone too.
 """
 from __future__ import annotations
 

@@ -50,8 +50,10 @@ def test_cached_logits_match_full_forward():
     vc = jnp.zeros((len(gen.blocks), 1, 4, 8, 8))
     logits = None
     for pos in range(prompt.shape[1]):
-        logits, kc, vc = gen._step(emb_p, blk_stack, head_p, kc, vc,
-                                   jnp.asarray(prompt[:, pos]), pos)
+        logits, kc, vc, rec = gen._step(emb_p, blk_stack, head_p, kc, vc,
+                                        None, jnp.asarray(prompt[:, pos]),
+                                        pos)
+        assert rec is None
     import jax
     full_probs = np.asarray(net.output(prompt))[:, -1]
     step_probs = np.asarray(jax.nn.softmax(logits, axis=-1))

@@ -639,3 +639,74 @@ def test_generate_rejects_out_of_range_top_k(net):
         gen.generate(prompt, n_new=2, temperature=1.0, top_k=51)
     out = gen.generate(prompt, n_new=2, temperature=1.0, top_k=50)
     assert out.shape == (1, 5)
+
+
+# -- the seam between the server and the one generator -----------------
+def _tiny_hybrid(**kw):
+    from deeplearning4j_tpu.zoo.hybrid_decoder import HybridDecoder
+    cfg = dict(vocab_size=50, d_model=32, n_layers=4, d_ff=64, n_heads=4,
+               n_kv_heads=2, attn_period=2, attn_offset=1, d_state=8,
+               d_conv=4, expand=2, dt_rank=2, seq_len=8,
+               compute_dtype=None, seed=3)
+    cfg.update(kw)
+    return HybridDecoder(**cfg).init_graph()
+
+
+@pytest.mark.parametrize("kind", ["post_ln", "runs"])
+def test_one_generator_one_signature(net, kind):
+    """Whatever the stack, ``TransformerGenerator(net)`` is that class
+    and answers with the runs' signatures: four values, ``rec`` None
+    exactly when the stack keeps no recurrent state -- and a server
+    over such a stack carries no array for it."""
+    import jax.numpy as jnp
+    net = net if kind == "post_ln" else _tiny_hybrid()
+    gen = TransformerGenerator(net)
+    assert type(gen) is TransformerGenerator
+    emb_p, blk_ps, head_p = gen._params()
+    runs_p = gen._stack_blocks(blk_ps)
+    assert isinstance(runs_p, tuple) and len(runs_p) == len(gen.runs)
+    prompt = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
+    logits, ks, vs, rec = gen._prefill_rows(emb_p, runs_p, head_p, prompt)
+    assert ks.shape == (gen.kv_layers, 1, gen.kv_heads, 4, gen.head_dim)
+    pad = ((0, 0), (0, 0), (0, 0), (0, 4), (0, 0))
+    out = gen._step(emb_p, runs_p, head_p, jnp.pad(ks, pad),
+                    jnp.pad(vs, pad), rec, logits.argmax(-1), 4)
+    assert len(out) == 4 and out[0].shape == (1, gen.vocab_size)
+    recurrent = kind == "runs"
+    assert (rec is not None) == recurrent == (out[3] is not None)
+    assert (gen.fresh_rec(2) is not None) == recurrent
+    with GenerationServer(net, n_slots=2, max_len=16, block_size=4,
+                          prefix_cache=False) as srv:
+        assert len(srv._params) == 3
+        assert ("rec_h" in srv._state) == ("rec_conv" in srv._state) \
+            == recurrent
+
+
+def test_attention_only_run_stack_refused_by_name_then_served():
+    """A stack of runs with NO recurrent run (the shape of a pre-norm
+    grouped-query decoder) has no chunked ``sequence()``: the default
+    ``prefix_cache=True`` is refused at construction by what the run
+    kind cannot do, not served until the first prefix hit dies inside
+    a trace.  Without the cache the same prompt twice is served and
+    equals offline ``generate()``."""
+    net = _tiny_hybrid(attn_period=1, attn_offset=0)
+    gen = TransformerGenerator(net)
+    assert gen.fresh_rec(1) is None and "AttentionBlockRun" in gen.refuses
+    kw = dict(n_slots=2, max_len=32, block_size=4)
+    for on, what in [({}, "prefix_cache=True"),
+                     ({"prefix_cache": False, "speculative": {"k": 2}},
+                      "speculative decode")]:
+        with pytest.raises(ValueError, match=f"{what} is not supported for "
+                                             "a net with AttentionBlockRun"):
+            GenerationServer(net, **kw, **on)
+    prompt = np.arange(1, 10, dtype=np.int32)     # two full blocks
+    ref = gen.generate(prompt[None], n_new=6)[0]
+    with GenerationServer(net, prefix_cache=False, **kw) as srv:
+        assert not any(k.startswith("rec_") for k in srv._state)
+        for _ in range(2):
+            np.testing.assert_array_equal(
+                srv.submit(prompt, n_new=6, timeout=300), ref)
+        with pytest.raises(ValueError, match="export_prefix is not "
+                                             "supported for a net with "
+                                             "AttentionBlockRun"):
+            srv.export_prefix(prompt)

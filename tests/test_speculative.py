@@ -112,9 +112,9 @@ def test_verify_rows_bitwise_equals_sequential_steps(net, offline):
         t0, tb = len(p), 8
         padded = np.zeros((1, tb), np.int32)
         padded[0, :t0] = p
-        lg, ks, vs = gen._prefill_rows(emb_p, blk_stack, head_p,
-                                       jnp.asarray(padded),
-                                       jnp.int32(t0))
+        lg, ks, vs, _ = gen._prefill_rows(emb_p, blk_stack, head_p,
+                                          jnp.asarray(padded),
+                                          jnp.int32(t0))
         bk = ks[:, 0].reshape(nl, h, tb // bs, bs, dh) \
             .transpose(0, 2, 1, 3, 4)
         bv = vs[:, 0].reshape(nl, h, tb // bs, bs, dh) \
@@ -134,8 +134,8 @@ def test_verify_rows_bitwise_equals_sequential_steps(net, offline):
         toks.append(tok)
         wblk = jnp.take_along_axis(table, (posA // bs)[:, None],
                                    axis=1)[:, 0]
-        lg, kcA, vcA = step(emb_p, blk_stack, head_p, kcA, vcA, tok,
-                            posA, table, wblk, posA % bs)
+        lg, kcA, vcA, _ = step(emb_p, blk_stack, head_p, kcA, vcA, tok,
+                               posA, table, wblk, posA % bs)
         logitsA.append(lg)
         posA = posA + 1
     toks = jnp.stack(toks, 1)
@@ -143,9 +143,10 @@ def test_verify_rows_bitwise_equals_sequential_steps(net, offline):
     # path B: ONE batched verification pass over the same tokens
     p = pos0[:, None] + jnp.arange(W)[None, :]
     wblk = jnp.take_along_axis(table, p // bs, axis=1)
-    logitsB, kcB, vcB = jax.jit(gen._verify_rows_paged)(
+    logitsB, kcB, vcB, rec = jax.jit(gen._verify_rows_paged)(
         emb_p, blk_stack, head_p, kc, vc, toks, pos0, p, table,
         wblk, p % bs)
+    assert rec is None
     np.testing.assert_array_equal(np.asarray(logitsA),
                                   np.asarray(logitsB))
     np.testing.assert_array_equal(np.asarray(kcA), np.asarray(kcB))
