@@ -235,6 +235,27 @@ _HOST_SYNCS = telemetry.counter(
     "generation_server_host_syncs_total",
     "device->host polls by the scheduler (one per decode scan — the "
     "dispatch-overhead denominator; syncs/token ~ 1/k steady-state)")
+# what a dispatch costs the host beyond the call itself: every program
+# the scheduler dispatches takes its host operands as ONE packed array
+# and hands back what the host needs as ONE packed array, both moved by
+# GenerationServer._to_device / _from_device and counted there
+_HOST_TRANSFERS = telemetry.counter(
+    "generation_server_host_transfers_total",
+    "arrays the scheduler's thread moved between host and device, by "
+    "the kind of dispatch they belong to and the direction (one d2h a "
+    "decode scan: its packed result; one h2d an admission: its packed "
+    "operands, a host-tier restore's K/V payloads beside them)",
+    labelnames=("site", "dir"))
+_MOVED = {(site, way): _HOST_TRANSFERS.labels(site=site, dir=way)
+          for site in ("scan", "admit", "kill") for way in ("h2d", "d2h")}
+_DISPATCHES = telemetry.counter(
+    "generation_server_dispatches_total",
+    "programs the scheduler dispatched, by kind (scan: a decode scan "
+    "or a speculative round; admit: a miss or hit admission; kill: "
+    "the budget-zeroing of cancelled / expired slots)",
+    labelnames=("program",))
+_DISPATCHED = {p: _DISPATCHES.labels(program=p)
+               for p in ("scan", "admit", "kill")}
 # useful share of the decode pool: tokens / slot-ticks is the share of
 # slot-ticks in which a slot emitted (a slot whose request ended
 # mid-scan rides the scan out idle).  A speculative round can commit
@@ -455,10 +476,10 @@ _PHASE = telemetry.histogram(
     labelnames=("phase",))
 
 #: prefill device-time sampling rate (ISSUE 13): the admit dispatch
-#: is async and ready() adds a block_until_ready IN the scheduler
-#: loop, so only 1-in-N admissions pays that bubble (the decode tick
-#: samples every dispatch — that site host-syncs anyway, so its
-#: sample is free)
+#: is async and the scheduler never waits for it, so a sampled
+#: admission's clock runs from its dispatch to the NEXT scan's poll —
+#: the first host sync that shows the device past it (the decode tick
+#: samples every dispatch: that site host-syncs anyway)
 _PROFILE_PREFILL_EVERY = 4
 
 
@@ -549,6 +570,51 @@ def _kill_slots(state, mask):
     instead of decoding out its budget.  Jitted with ``state`` donated
     (``GenerationServer._kill``)."""
     return dict(state, remaining=jnp.where(mask, 0, state["remaining"]))
+
+
+#: the scalars at the head of an admission's packed operands
+_ADMIT_HEAD = 8
+
+
+def _pack_admission(req, slot: int, *rows) -> np.ndarray:
+    """One admission's host operands as ONE int32 vector: the head's
+    scalars (``t0``, slot, ``n_new``, ``eos_id``, ``top_k``, the seed's
+    low 32 bits, ``temperature`` and ``top_p`` as their float32 bits),
+    then ``rows`` end to end — the padded prompt, block ids, table
+    rows — whose lengths the admit program knows from its compile key
+    (:func:`_unpack_admission` is the other end)."""
+    head = np.empty((_ADMIT_HEAD,), np.int32)
+    head[:5] = (req.t0, slot, req.n_new, req.eos_id, req.top_k)
+    head[5] = np.int64(req.seed).astype(np.int32)
+    head[6:] = np.array((req.temperature, req.top_p),
+                        np.float32).view(np.int32)
+    return np.concatenate((head,) + rows)
+
+
+def _unpack_admission(ops, *sizes):
+    """In a trace: the head of :func:`_pack_admission`'s vector as
+    ``_arm_slot`` takes it — ``(slot, t0, n_new, eos_id, key,
+    temperature, top_k, top_p)`` — and its rows, cut at static offsets.
+    The PRNG key is derived HERE from the seed word, to the bits
+    ``jax.random.PRNGKey(seed)`` gives on the host in 32-bit mode
+    (there the seed is cut to its low 32 bits first)."""
+    t0, slot, n_new, eos_id, tk, seed = (ops[i] for i in range(6))
+    temp, tp = jax.lax.bitcast_convert_type(ops[6:_ADMIT_HEAD],
+                                            jnp.float32)
+    rows, at = [], _ADMIT_HEAD
+    for n in sizes:
+        rows.append(ops[at:at + n])
+        at += n
+    return (slot, t0, n_new, eos_id, jax.random.PRNGKey(seed), temp, tk,
+            tp), rows
+
+
+def _pack_polled(toks, *columns):
+    """In a trace: all the host reads of a decode dispatch as ONE int32
+    array — the staged tokens [B, n], then each per-slot ``columns``
+    entry [B] as one more column."""
+    return jnp.concatenate([toks] + [c[:, None] for c in columns],
+                           axis=1)
 
 
 def _refuse_cache_loaded_mesh_programs(devices) -> None:
@@ -1458,7 +1524,11 @@ class GenerationServer:
             tp = float(self.top_p)       # server-wide default
         tk_eff = self._vocab if tk is None else tk
         tp_eff = 1.0 if tp is None else tp
-        return temp, tk_eff, tp_eff, int(samp.get("seed", seed))
+        seed = int(samp.get("seed", seed))
+        if not -2 ** 63 <= seed < 2 ** 63:
+            raise ValueError(f"sampling seed={seed} out of range (a "
+                             "signed 64-bit integer)")
+        return temp, tk_eff, tp_eff, seed
 
     # -- block allocator + prefix cache (host truth, under _lock) ------
     def _chain_hashes(self, prompt: np.ndarray):
@@ -1938,13 +2008,13 @@ class GenerationServer:
         and an out-of-bounds positional-table take fills NaN — which
         a clamped write would smear into a live block and poison it.
 
-        Returns ``(kc, vc, state, tokens [B, K], emitted [B],
-        n_alive)`` — tokens stage device-side and the host polls ONCE
-        per scan instead of once per token; ``emitted`` counts each
-        slot's live ticks so the host can unpack exactly the tokens
-        that were really generated, and ``n_alive`` is the device-
-        truth occupancy at scan end (feeds the slots-busy gauge
-        without another reduction host-side)."""
+        Returns ``(kc, vc, state, polled)``: ``polled`` is ONE int32
+        array [B, K + 2], all the host reads of a scan — the staged
+        tokens [B, K] (the host polls once per scan, not once per
+        token), ``emitted`` (each slot's live ticks, so the host
+        unpacks exactly the tokens that were really generated) and a
+        copy of ``state["remaining"]`` (who is done; the device-truth
+        occupancy at scan end is its count of non-zeros)."""
         key = (int(K), bool(sampled))
         fn = self._scan_cache.get(key)
         if fn is not None:
@@ -2013,9 +2083,8 @@ class GenerationServer:
             emitted0 = jnp.zeros(state["remaining"].shape, jnp.int32)
             (kc, vc, state, emitted), toks = jax.lax.scan(
                 step, (kc, vc, state, emitted0), None, length=K)
-            n_alive = jnp.sum((state["remaining"] > 0)
-                              .astype(jnp.int32))
-            return kc, vc, state, toks.T, emitted, n_alive
+            return kc, vc, state, _pack_polled(
+                toks.T, emitted, state["remaining"])
 
         # donate caches + state: the scan updates them in place instead
         # of copying both full [n_layers, B, h, L, dh] buffers per
@@ -2073,10 +2142,11 @@ class GenerationServer:
         sequence depends only on its seed and its own acceptance
         history, invariant to R batching and pool composition.
 
-        Returns ``(kc, vc, state, toks [B, R*W], emitted [B], n_alive,
-        proposed [B], accepted [B])`` — the last two PER SLOT: the host
-        attributes them to tenants, feeds the acceptance controller and
-        sums them into the ``generation_server_spec_*`` counters."""
+        Returns ``(kc, vc, state, polled)``, ``polled`` [B, R*W + 4]
+        as ``_decode_scan`` packs it with two more columns, ``proposed``
+        and ``accepted`` PER SLOT: the host attributes them to tenants,
+        feeds the acceptance controller and sums them into the
+        ``generation_server_spec_*`` counters."""
         key = ("spec", int(R), int(K), bool(sampled))
         fn = self._scan_cache.get(key)
         if fn is not None:
@@ -2278,10 +2348,9 @@ class GenerationServer:
                              (kc, vc, state, staged0, emitted0,
                               zeros_b, zeros_b),
                              None, length=R)
-            n_alive = jnp.sum((state["remaining"] > 0)
-                              .astype(jnp.int32))
-            return (kc, vc, state, staged[:, :R * W], emitted,
-                    n_alive, prop, acc)
+            return kc, vc, state, _pack_polled(
+                staged[:, :R * W], emitted, state["remaining"], prop,
+                acc)
 
         fn = self._scan_cache[key] = jax.jit(spec_fn,
                                              donate_argnums=(6, 7, 8))
@@ -2367,10 +2436,28 @@ class GenerationServer:
         gen = self._gen
         spec = self._spec if use_draft else None
         shard = self._shard
+        n_sc, mb = tb // self.block_size, self.max_blocks
 
-        def admit_miss(emb_p, blk_stack, head_p, kc, vc, state, prompt,
-                       t0, slot, n_new, eos_id, key, temp, tk, tp,
-                       phys, table_row, dtable_row, *draft_ops):
+        def admit_miss(emb_p, blk_stack, head_p, kc, vc, state, ops,
+                       *more):
+            if ops.ndim == 1:
+                # everything the host gives an admission, packed
+                # (``_admit`` is the other end of this order); then
+                # the draft's params
+                head, (prompt, phys, table_row, dtable_row, dphys) = \
+                    _unpack_admission(ops, tb, n_sc, mb, mb,
+                                      n_sc if spec is not None else 0)
+                prompt, draft_params = prompt[None], more
+            else:
+                # the operands one by one — [1, tb] prompt, t0, slot,
+                # n_new, eos_id, key, temp, tk, tp, phys, the two table
+                # rows — as tests/benchmark_suite, frozen with the
+                # benchmark, drives this program by hand (no scheduler
+                # path: PERF.md section 7)
+                (t0, slot, *head), (phys, table_row, dtable_row) = \
+                    more[:8], more[8:]
+                prompt, head = ops, (slot, t0, *head)
+            t0 = head[1]
             # t0 picks the last REAL position's logits out of the
             # padded bucket (and, of a recurrent layer, the state as
             # after that position)
@@ -2384,15 +2471,14 @@ class GenerationServer:
                 # can propose (its logits are discarded — rounds
                 # re-feed from the anchor).  In-trace layer slice: a
                 # self-draft's operand is the target stack verbatim.
-                demb_p, dblk, dhead_p, dphys = draft_ops
+                demb_p, dblk, dhead_p = draft_params
                 dblk = jax.tree_util.tree_map(
                     lambda a: a[:spec.draft.n_layers], dblk)
                 _, dks, dvs, _ = spec.draft.gen._prefill_rows(
                     demb_p, dblk, dhead_p, prompt, t0, shard=shard)
                 kc = self._scatter_rows(kc, dks, dphys)
                 vc = self._scatter_rows(vc, dvs, dphys)
-            state = self._arm_slot(state, logits, slot, t0, n_new,
-                                   eos_id, key, temp, tk, tp, table_row,
+            state = self._arm_slot(state, logits, *head, table_row,
                                    dtable_row, rec)
             return kc, vc, state
 
@@ -2435,58 +2521,64 @@ class GenerationServer:
         gen = self._gen
         spec = self._spec if use_draft else None
         shard = self._shard
+        bs, mb = self.block_size, self.max_blocks
+        p0 = matched * bs
+        # the draft's rows (speculative only): its suffix past
+        # ``dmatched`` cached blocks, or the whole prompt at ``dtb``
+        dm = dmatched if spec is not None else 0
+        dlen = (dsb if dm else dtb) if spec is not None else 0
 
-        def admit_hit(emb_p, blk_stack, head_p, kc, vc, state, suffix,
-                      p0, last_ix, t0, slot, n_new, eos_id, key, temp,
-                      tk, tp, prefix_phys, phys, table_row, dtable_row,
+        def admit_hit(emb_p, blk_stack, head_p, kc, vc, state, ops,
                       *extra_ops):
+            # ``ops``: everything the host gives an admission, packed
+            # (``_admit`` is the other end of this order); a host-tier
+            # restore's K/V payloads are data and ride beside it
+            (head, (suffix, prefix_phys, phys, table_row, dtable_row,
+                    fill_ids, dtokens, dprefix_phys, dphys)) = \
+                _unpack_admission(ops, sb, matched, sb // bs, mb, mb,
+                                  nfill, dlen, dm, dlen // bs)
+            suffix, t0 = suffix[None], head[1]
             if nfill:
                 # host-tier restore: land the spilled bytes in their
                 # claimed pool blocks BEFORE the prefix gather below
                 # reads them (one fused scatter per cache side)
-                fill_ids, fill_k, fill_v = extra_ops[:3]
-                draft_ops = extra_ops[3:]
+                fill_k, fill_v = extra_ops[:2]
+                draft_params = extra_ops[2:]
                 kc = kc.at[:, fill_ids].set(
                     pad_head_dim(fill_k, kc.shape[-1]))
                 vc = vc.at[:, fill_ids].set(
                     pad_head_dim(fill_v, vc.shape[-1]))
             else:
-                draft_ops = extra_ops
+                draft_params = extra_ops
             pk = self._gather_rows(kc, prefix_phys)
             pv = self._gather_rows(vc, prefix_phys)
             logits, ks, vs, rec = gen._prefill_rows_chunked(
-                emb_p, blk_stack, head_p, suffix, pk, pv, p0, last_ix,
-                shard=shard)
+                emb_p, blk_stack, head_p, suffix, pk, pv,
+                jnp.int32(p0), t0 - p0 - 1, shard=shard)
             kc = self._scatter_rows(kc, ks, phys)
             vc = self._scatter_rows(vc, vs, phys)
             if spec is not None:
                 dl = spec.draft.n_layers
-                if dmatched:
+                demb_p, dblk, dhead_p = draft_params
+                dblk = jax.tree_util.tree_map(lambda a: a[:dl], dblk)
+                if dm:
                     # draft-cache HIT: gather the draft prefix out of
                     # the pool's first d layers, chunk-prefill only
                     # the draft suffix (logits discarded — rounds
                     # re-feed from the anchor)
-                    (demb_p, dblk, dhead_p, dsuffix, dprefix_phys,
-                     dphys) = draft_ops
-                    dblk = jax.tree_util.tree_map(
-                        lambda a: a[:dl], dblk)
                     dpk = self._gather_rows(kc[:dl], dprefix_phys)
                     dpv = self._gather_rows(vc[:dl], dprefix_phys)
-                    dp0 = dmatched * self.block_size
+                    dp0 = dm * bs
                     _, dks, dvs, _ = spec.draft.gen._prefill_rows_chunked(
-                        demb_p, dblk, dhead_p, dsuffix, dpk, dpv,
+                        demb_p, dblk, dhead_p, dtokens[None], dpk, dpv,
                         jnp.int32(dp0), t0 - dp0 - 1, shard=shard)
                 else:
-                    demb_p, dblk, dhead_p, dprompt, dphys = draft_ops
-                    dblk = jax.tree_util.tree_map(
-                        lambda a: a[:dl], dblk)
                     _, dks, dvs, _ = spec.draft.gen._prefill_rows(
-                        demb_p, dblk, dhead_p, dprompt, t0,
+                        demb_p, dblk, dhead_p, dtokens[None], t0,
                         shard=shard)
                 kc = self._scatter_rows(kc, dks, dphys)
                 vc = self._scatter_rows(vc, dvs, dphys)
-            state = self._arm_slot(state, logits, slot, t0, n_new,
-                                   eos_id, key, temp, tk, tp, table_row,
+            state = self._arm_slot(state, logits, *head, table_row,
                                    dtable_row, rec)
             return kc, vc, state
 
@@ -2495,6 +2587,22 @@ class GenerationServer:
         return fn
 
     # -- scheduler -----------------------------------------------------
+    # The two places the scheduler's thread moves data between host and
+    # device, each an EXPLICIT transfer (under ``jax.transfer_guard``
+    # nothing else on that thread may move any) counted by the site it
+    # serves — next to where that site counts its dispatch, so a scrape
+    # between the two is rare.
+    @staticmethod
+    def _to_device(site: str, host_array: np.ndarray):
+        on_device = jax.device_put(host_array)
+        _MOVED[site, "h2d"].inc()
+        return on_device
+
+    @staticmethod
+    def _from_device(site: str, device_array) -> np.ndarray:
+        _MOVED[site, "d2h"].inc()
+        return jax.device_get(device_array)
+
     def _admit(self, req: _Pending, slot: int, plan: _AdmitPlan,
                my_epoch: int) -> bool:
         """Prefill dispatch + commit; returns False when a watchdog
@@ -2507,25 +2615,16 @@ class GenerationServer:
         # prefill-only admissions skip the draft entirely (no dtable
         # blocks were claimed — plan.dphys is empty)
         use_draft = self._spec is not None and not req.prefill_only
-        table_row = np.zeros((self.max_blocks,), np.int32)
-        table_row[:len(plan.phys)] = plan.phys
-        dtable_row = np.zeros((self.max_blocks,), np.int32)
-        dtable_row[:len(plan.dphys)] = plan.dphys
-        emb_p, blk_stack, head_p = self._params
 
-        def draft_ops(dtb):
-            """Draft-prefill operands (speculative only): the draft's
-            params, its full-prompt pad to the ``dtb`` bucket, and its
-            scatter targets."""
-            dpad = np.zeros((1, dtb), np.int32)
-            dpad[0, :req.t0] = req.prompt
-            n_dc = dtb // bs
-            dscatter = np.zeros((n_dc,), np.int32)
-            dhead = plan.dphys[:n_dc]
-            dscatter[:len(dhead)] = dhead
-            demb_p, dblk, dhead_p = self._draft_params
-            return (demb_p, dblk, dhead_p, jnp.asarray(dpad),
-                    jnp.asarray(dscatter))
+        def padded(tokens, n):
+            row = np.zeros((n,), np.int32)
+            row[:len(tokens)] = tokens
+            return row
+
+        def bucket(n):
+            """Prefill length for ``n`` tokens: the next power of two,
+            in whole blocks."""
+            return -(-_bucket(n, self.max_len) // bs) * bs
 
         # snapshot the pool atomically: a concurrent watchdog recovery
         # swaps all three together, and a torn read would scatter this
@@ -2533,108 +2632,66 @@ class GenerationServer:
         with self._lock:
             kc, vc, state = self._kc, self._vc, self._state
         _sanitize.check_not_donated("serve/admit", kc, vc, state)
-        # device-phase sample (ISSUE 13): the prefill dispatch is
-        # async — ready(out) pays the block_until_ready only on the
-        # 1-in-N sampled calls (explicit every=, NOT the profiler's
-        # default of 1), so unsampled admissions stay fully async
-        with telemetry.get_profiler().measure(
-                "prefill", every=_PROFILE_PREFILL_EVERY,
-                devices=self._device_labels) as prof_m:
-            if matched:
-                # prefix HIT: gather the cached blocks, prefill only
-                # the suffix — scatter targets start at the first
-                # fresh block
-                suffix = req.prompt[p0:]
-                sb = -(-_bucket(len(suffix), self.max_len) // bs) * bs
-                padded = np.zeros((1, sb), np.int32)
-                padded[0, :len(suffix)] = suffix
-                _PREFILL_REAL.inc(len(suffix))
-                _PREFILL_PAD.inc(sb - len(suffix))
-                n_sc = sb // bs
-                fresh = plan.phys[matched:matched + n_sc]
-                scatter_phys = np.zeros((n_sc,), np.int32)
-                scatter_phys[:len(fresh)] = fresh
-                dmatched = plan.dmatched if use_draft else 0
-                if dmatched:
-                    # draft-cache hit (ISSUE 20): chunk-prefill only
-                    # the draft suffix past its cached blocks
-                    dtb = 0
-                    dsuffix = req.prompt[dmatched * bs:]
-                    dsb = -(-_bucket(len(dsuffix),
-                                     self.max_len) // bs) * bs
-                    dpadded = np.zeros((1, dsb), np.int32)
-                    dpadded[0, :len(dsuffix)] = dsuffix
-                    n_dc = dsb // bs
-                    dfresh = plan.dphys[dmatched:dmatched + n_dc]
-                    dscatter = np.zeros((n_dc,), np.int32)
-                    dscatter[:len(dfresh)] = dfresh
-                    demb_p, dblk, dhead_p = self._draft_params
-                    extra = (demb_p, dblk, dhead_p,
-                             jnp.asarray(dpadded),
-                             jnp.asarray(plan.dphys[:dmatched],
-                                         jnp.int32),
-                             jnp.asarray(dscatter))
-                else:
-                    dsb = 0
-                    dtb = (-(-_bucket(req.t0, self.max_len) // bs) * bs
-                           if use_draft else 0)
-                    extra = draft_ops(dtb) if use_draft else ()
-                nfill = len(plan.fills)
-                if nfill:
-                    # host-tier restore operands: ONE stacked H2D per
-                    # cache side for the whole admission, however many
-                    # spilled blocks it restores
-                    fill_ids = np.asarray(
+        # the packed operands' rows, in the order the admit programs
+        # cut them (scatter targets past the slot's allocation stay 0,
+        # the scratch block); then the payloads and the draft's params
+        table_rows = (padded(plan.phys, self.max_blocks),
+                      padded(plan.dphys, self.max_blocks))
+        draft_params = self._draft_params if use_draft else ()
+        if matched:
+            # prefix HIT: gather the cached blocks, prefill only the
+            # suffix — scatter targets start at the first fresh block
+            suffix = req.prompt[p0:]
+            sb = bucket(len(suffix))
+            n_real, n_pad = len(suffix), sb - len(suffix)
+            dmatched = plan.dmatched if use_draft else 0
+            # the draft (ISSUE 20) chunk-prefills only the suffix past
+            # ITS cached blocks, or on a draft-cache miss the whole
+            # prompt at its own bucket
+            dtokens = req.prompt[dmatched * bs:] if use_draft else ()
+            dlen = bucket(len(dtokens)) if use_draft else 0
+            dtb, dsb = (0, dlen) if dmatched else (dlen, 0)
+            nfill = len(plan.fills)
+            fn = self._admit_hit_fn(sb, matched, dtb, nfill, use_draft,
+                                    dmatched, dsb)
+            rows = (padded(suffix, sb),
+                    np.asarray(plan.phys[:matched], np.int32),
+                    padded(plan.phys[matched:matched + sb // bs],
+                           sb // bs),
+                    *table_rows,
+                    # host-tier restore: the claimed blocks' ids here,
+                    # their bytes as ONE stacked payload per cache side
+                    np.asarray(
                         plan.phys[plan.reg_from:plan.reg_from + nfill],
-                        np.int32)
-                    fill_ops = (jnp.asarray(fill_ids),
-                                jnp.asarray(np.stack(
-                                    [f[0] for f in plan.fills], axis=1)),
-                                jnp.asarray(np.stack(
-                                    [f[1] for f in plan.fills], axis=1)))
-                else:
-                    fill_ops = ()
-                out = self._admit_hit_fn(sb, matched, dtb, nfill,
-                                         use_draft, dmatched, dsb)(
-                    emb_p, blk_stack, head_p, kc, vc, state,
-                    jnp.asarray(padded), np.int32(p0),
-                    np.int32(req.t0 - p0 - 1), np.int32(req.t0),
-                    np.int32(slot), np.int32(req.n_new),
-                    np.int32(req.eos_id), jax.random.PRNGKey(req.seed),
-                    np.float32(req.temperature), np.int32(req.top_k),
-                    np.float32(req.top_p),
-                    jnp.asarray(plan.phys[:matched], jnp.int32),
-                    jnp.asarray(scatter_phys), jnp.asarray(table_row),
-                    jnp.asarray(dtable_row), *fill_ops, *extra)
-            else:
-                tb = -(-_bucket(req.t0, self.max_len) // bs) * bs
-                padded = np.zeros((1, tb), np.int32)
-                padded[0, :req.t0] = req.prompt
-                _PREFILL_REAL.inc(req.t0)
-                _PREFILL_PAD.inc(tb - req.t0)
-                n_sc = tb // bs
-                scatter_phys = np.zeros((n_sc,), np.int32)
-                head = plan.phys[:n_sc]
-                scatter_phys[:len(head)] = head
-                if use_draft:
-                    demb_p, dblk, dhead_p, dpad, dscatter = \
-                        draft_ops(tb)
-                    # miss path: draft shares the target's padded
-                    # prompt
-                    extra = (demb_p, dblk, dhead_p, dscatter)
-                else:
-                    extra = ()
-                out = self._admit_miss_fn(tb, use_draft)(
-                    emb_p, blk_stack, head_p, kc, vc, state,
-                    jnp.asarray(padded), np.int32(req.t0),
-                    np.int32(slot), np.int32(req.n_new),
-                    np.int32(req.eos_id), jax.random.PRNGKey(req.seed),
-                    np.float32(req.temperature), np.int32(req.top_k),
-                    np.float32(req.top_p), jnp.asarray(scatter_phys),
-                    jnp.asarray(table_row), jnp.asarray(dtable_row),
-                    *extra)
-            prof_m.ready(out)
+                        np.int32),
+                    padded(dtokens, dlen),
+                    np.asarray(plan.dphys[:dmatched], np.int32),
+                    padded(plan.dphys[dmatched:dmatched + dlen // bs],
+                           dlen // bs))
+            payloads = tuple(
+                self._to_device("admit", np.stack(
+                    [f[side] for f in plan.fills], axis=1))
+                for side in ((0, 1) if nfill else ()))
+        else:
+            tb = bucket(req.t0)
+            n_real, n_pad = req.t0, tb - req.t0
+            fn = self._admit_miss_fn(tb, use_draft)
+            # miss path: the draft shares the target's padded prompt
+            rows = (padded(req.prompt, tb),
+                    padded(plan.phys[:tb // bs], tb // bs),
+                    *table_rows,
+                    padded(plan.dphys[:tb // bs],
+                           tb // bs if use_draft else 0))
+            payloads = ()
+        _PREFILL_REAL.inc(n_real)
+        _PREFILL_PAD.inc(n_pad)
+        ops = self._to_device("admit", _pack_admission(req, slot, *rows))
+        _DISPATCHED["admit"].inc()
+        # ledger-mark BEFORE the donating dispatch (a host-side weakref
+        # record, not a buffer read): no name outlives its donation
         _sanitize.mark_donated("serve/admit", kc, vc, state)
+        out = fn(*self._params, kc, vc, state, ops, *payloads,
+                 *draft_params)
         with self._lock:
             if self._epoch != my_epoch:
                 return False
@@ -3007,7 +3064,10 @@ class GenerationServer:
 
     def _run_loop(self, my_epoch: int, tracer, phases: _SchedPhases):
         prof = telemetry.get_profiler()
+        owner = (id(self), my_epoch)
         stop = False
+        prefill_t0 = None   # a sampled admission's dispatch, until the
+                            # next poll shows the device past it
         while True:
             with self._lock:
                 if self._epoch != my_epoch:
@@ -3120,6 +3180,9 @@ class GenerationServer:
                     self._mark_tick(my_epoch,
                                     (my_epoch, time.monotonic(), 1))
                     admitting = slot     # a raising prefill implicates
+                    if prof.sampled("prefill", _PROFILE_PREFILL_EVERY) \
+                            and prefill_t0 is None:
+                        prefill_t0 = time.perf_counter()
                     committed = self._admit(req, slot, plan, my_epoch)
                     admitting = None     # only ITS slot in recovery
                     self._mark_tick(my_epoch, None)
@@ -3160,7 +3223,6 @@ class GenerationServer:
                 _SLOTS_BUSY.set(n_active)
                 if not n_active:
                     continue
-                emb_p, blk_stack, head_p = self._params
                 # adaptive scan length: single ticks while ANY request
                 # is waiting for admission (a join never waits behind a
                 # long scan — TTFT does not regress), else the largest
@@ -3231,8 +3293,7 @@ class GenerationServer:
                          else min(self.tick_batch, _pow2_floor(k_drain)))
                 # serve/tick: dispatch -> the one host poll
                 phases.switch(None)
-                with tracer.span("serve/tick",
-                                 owner=(id(self), my_epoch),
+                with tracer.span("serve/tick", owner=owner,
                                  active=n_active, queued=n_pending,
                                  k=k, spec=int(use_spec)):
                     self._mark_tick(my_epoch,
@@ -3253,43 +3314,60 @@ class GenerationServer:
                                                   self._state)
                     _sanitize.check_not_donated("serve/tick", kc_in,
                                                 vc_in, state_in)
-                    n_prop = n_acc = 0
+                    if use_spec:
+                        fn = self._spec_fn2(R, K_disp, sampled)
+                        operands = (*self._draft_params, kc_in, vc_in,
+                                    state_in,
+                                    self._to_device("scan", kcap_arr))
+                        n_tok = R * (K_disp + 1)
+                    else:
+                        fn = self._decode_scan(k, sampled)
+                        operands = (kc_in, vc_in, state_in)
+                        n_tok = k
                     # device-phase sample (ISSUE 13): dispatch ->
                     # host-sync is the device time of this tick; the
-                    # site already syncs (the np.asarray poll), so the
-                    # continuous profile costs one perf_counter pair
+                    # site already syncs (the poll), so the continuous
+                    # profile costs one perf_counter pair
                     with prof.measure("verify" if use_spec
                                       else "decode_tick",
                                       devices=self._device_labels):
-                        if use_spec:
-                            demb_p, dblk, dhead_p = self._draft_params
-                            (kc, vc, state, toks, emitted, n_alive,
-                             prop, acc) = self._spec_fn2(
-                                R, K_disp, sampled)(
-                                emb_p, blk_stack, head_p, demb_p, dblk,
-                                dhead_p, kc_in, vc_in, state_in,
-                                jnp.asarray(kcap_arr))
-                        else:
-                            kc, vc, state, toks, emitted, n_alive = \
-                                self._decode_scan(k, sampled)(
-                                    emb_p, blk_stack, head_p, kc_in,
-                                    vc_in, state_in)
+                        # serve/launch: the dispatch call, and the
+                        # packed result's copy to the host started
+                        # behind it — before the host asks
+                        with tracer.span("serve/launch", owner=owner):
+                            kc, vc, state, polled = fn(
+                                *self._params, *operands)
+                            polled.copy_to_host_async()
                         _sanitize.mark_donated("serve/tick", kc_in,
                                                vc_in, state_in)
-                        # THE host sync: one poll per dispatch — tokens
-                        # staged [B, K] device-side, per-slot live-tick
-                        # counts, budgets left (all off one dispatch)
-                        toks_h = np.asarray(toks)
-                        emit_h = np.asarray(emitted)
-                        rem_h = np.asarray(state["remaining"])
-                        alive_h = int(n_alive)
+                        _DISPATCHED["scan"].inc()
+                        # serve/poll: THE host sync, one read per
+                        # dispatch of either kind — tokens staged
+                        # device-side, per-slot live-tick counts,
+                        # budgets left (a speculative round: its
+                        # per-slot tallies too, so the host can
+                        # attribute acceptance to tenants and feed the
+                        # controller)
+                        with tracer.span("serve/poll", owner=owner):
+                            polled_h = self._from_device("scan", polled)
+                    if prefill_t0 is not None:
+                        # the device is past every admission dispatched
+                        # before this scan: the sampled one's clock ends
+                        for dev in self._device_labels or (None,):
+                            prof.observe(
+                                "prefill",
+                                time.perf_counter() - prefill_t0,
+                                device=dev)
+                        prefill_t0 = None
+                    toks_h = polled_h[:, :n_tok]
+                    emit_h, rem_h = (polled_h[:, n_tok],
+                                     polled_h[:, n_tok + 1])
+                    alive_h = int(np.count_nonzero(rem_h))
                     prop_h = acc_h = None
+                    n_prop = n_acc = 0
                     if use_spec:
-                        # the program tallies PER SLOT, so the
-                        # host can attribute acceptance to tenants and
-                        # feed the controller
-                        prop_h = np.asarray(prop)
-                        acc_h = np.asarray(acc)
+                        prop_h, acc_h = (polled_h[:, n_tok + 2],
+                                         polled_h[:, n_tok + 3])
                         n_prop = int(prop_h.sum())
                         n_acc = int(acc_h.sum())
                     _HOST_SYNCS.inc()
@@ -3435,6 +3513,7 @@ class GenerationServer:
                     # nobody is left hanging on an unset event.
                     mask = np.zeros((self.n_slots,), bool)
                     mask[kill] = True
+                    mask = self._to_device("kill", mask)
                     with self._lock:
                         if self._epoch != my_epoch:
                             return
@@ -3444,7 +3523,8 @@ class GenerationServer:
                         # host-side weakref record, not a buffer read)
                         # so no name outlives its donation
                         _sanitize.mark_donated("serve/kill", st)
-                        self._state = self._kill(st, jnp.asarray(mask))
+                        _DISPATCHED["kill"].inc()
+                        self._state = self._kill(st, mask)
                 # post-tick refresh so an idle pool scrapes as 0 busy
                 # (the loop blocks on the queue next, with no tick to
                 # update the gauges)

@@ -13,11 +13,14 @@ the ONE fleet scrape gains
 
 * **measurement** — :meth:`DeviceProfiler.measure` times the dispatch
   + host sync of a block.  Sites that already sync (the decode tick's
-  ``np.asarray`` poll) pay nothing extra; async sites (prefill,
-  optimizer step) hand their output to :meth:`_Measure.ready`, which
+  poll) pay nothing extra; an async site (the optimizer step) hands
+  its output to :meth:`_Measure.ready`, which
   ``jax.block_until_ready``-s it ONLY when this call is sampled —
   1-in-``every`` dispatches pays the sync, the rest stay fully async
-  (the sampling that makes "continuous" affordable);
+  (the sampling that makes "continuous" affordable); a site whose
+  thread may not wait at all (the serving scheduler's prefill) asks
+  :meth:`DeviceProfiler.sampled` and closes its sample with
+  :meth:`DeviceProfiler.observe` at its next sync;
 * **fold** — samples land in the per-``(device, phase)`` histogram;
   :meth:`top_ops` ranks phases by cumulative device seconds (count,
   total, p50/p99) — the top-K op summary a fleet dashboard shows;
@@ -162,13 +165,8 @@ class DeviceProfiler:
         EACH listed device's series (per-device phase attribution
         across the slice); None keeps the single default-device
         label."""
-        phase = str(phase)
-        every = self.sample_every if every is None else max(1, int(every))
-        with self._lock:
-            n = self._calls.get(phase, 0) + 1
-            self._calls[phase] = n
         capturing = self._xprof_participate()
-        sampled = capturing or (n % every == 0)
+        sampled = self.sampled(phase, every, force=capturing)
         m = _Measure(sampled)
         t0 = time.perf_counter() if sampled else 0.0
         try:
@@ -178,10 +176,25 @@ class DeviceProfiler:
                 dt = time.perf_counter() - t0
                 for dev in (devices if devices else (None,)):
                     self.observe(phase, dt, device=dev)
-            else:
-                self._skipped.labels(phase=phase).inc()
             if capturing:
                 self._xprof_end()
+
+    def sampled(self, phase: str, every: Optional[int] = None,
+                force: bool = False) -> bool:
+        """Count one dispatch of ``phase``; True for the 1 in ``every``
+        (or ``force``) to time.  :meth:`measure` asks it; so does a
+        site that must not wait for its own dispatch and hands the
+        sample to :meth:`observe` where a later host sync shows the
+        device past it (the serving scheduler's prefill)."""
+        phase = str(phase)
+        every = self.sample_every if every is None else max(1, int(every))
+        with self._lock:
+            n = self._calls.get(phase, 0) + 1
+            self._calls[phase] = n
+        if force or n % every == 0:
+            return True
+        self._skipped.labels(phase=phase).inc()
+        return False
 
     def observe(self, phase: str, seconds: float,
                 device: Optional[str] = None) -> None:

@@ -503,6 +503,13 @@ def main() -> int:
     if retired.value - retired_before != 4:
         problems.append(f"generation_server_retired_total grew "
                         f"{retired.value - retired_before} != 4")
+    # the scheduler's scoped spans: a tick holds its dispatch call and
+    # its one read (ISSUE 32), so a device gap is named by either
+    span_names = {ev["name"] for ev in tracer.events()}
+    for span in ("serve/admit", "serve/tick", "serve/launch",
+                 "serve/poll", "serve/retire"):
+        if span not in span_names:
+            problems.append(f"no {span} span recorded by the scheduler")
 
     # -- paged KV: two requests sharing one system prompt must score a
     # real prefix-cache hit (the second prefills only its suffix) ----
@@ -1045,6 +1052,11 @@ def main() -> int:
         "generation_server_slot_ticks_total",
         'generation_server_sched_host_seconds_total{phase="admit"}',
         'generation_server_sched_host_seconds_total{phase="retire"}',
+        # one packed array each way a dispatch (ISSUE 32)
+        'generation_server_host_transfers_total{site="scan",dir="d2h"}',
+        'generation_server_host_transfers_total{site="admit",dir="h2d"}',
+        'generation_server_dispatches_total{program="scan"}',
+        'generation_server_dispatches_total{program="admit"}',
         # continuous device-phase profile (ISSUE 13): the serve/spec
         # runs above sampled all three serve phases on this process
         'fleet_device_phase_seconds_bucket{device="cpu:0",'

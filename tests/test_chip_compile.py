@@ -27,6 +27,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from deeplearning4j_tpu import kernels
+from deeplearning4j_tpu.parallel.generation_server import _ADMIT_HEAD
 
 flash_mod = importlib.import_module(
     "deeplearning4j_tpu.kernels.flash_attention")
@@ -315,18 +316,15 @@ def named_programs(one_chip):
             pool = _server_operands(srv, one_chip)
             out["decode_scan"] = _trace_names(
                 srv._decode_scan(2, False).lower(*pool).compile())
-            # operands as GenerationServer._admit hands them over: a
+            # operands as GenerationServer._admit packs them: a
             # 33-token prompt padded to the 64 bucket, then the same
             # prompt again with its two full blocks cached
-            key = _on_chip(jax.random.PRNGKey(0), one_chip)
-            slot = (S(()), S(()), S(()), key, S((), jnp.float32), S(()),
-                    S((), jnp.float32))     # slot n_new eos key temp tk tp
-            rows = (S((srv.max_blocks,)), S((srv.max_blocks,)))
-            out["admit_miss"] = _trace_names(srv._admit_miss_fn(64).lower(
-                *pool, S((1, 64)), S(()), *slot, S((4,)), *rows).compile())
+            rows = 2 * srv.max_blocks       # the two table rows
+            miss = S((_ADMIT_HEAD + 64 + 4 + rows,))
+            out["admit_miss"] = _trace_names(
+                srv._admit_miss_fn(64).lower(*pool, miss).compile())
             out["admit_hit"] = _trace_names(srv._admit_hit_fn(16, 2).lower(
-                *pool, S((1, 16)), S(()), S(()), S(()), *slot, S((2,)),
-                S((1,)), *rows).compile())
+                *pool, S((_ADMIT_HEAD + 16 + 2 + 1 + rows,))).compile())
         finally:
             srv.shutdown(drain=False, timeout=30.0)
 
@@ -345,9 +343,7 @@ def named_programs(one_chip):
             out["hybrid_decode_scan"] = _trace_names(
                 srv._decode_scan(2, False).lower(*pool).compile())
             out["hybrid_admit_miss"] = _trace_names(
-                srv._admit_miss_fn(64).lower(
-                    *pool, S((1, 64)), S(()), *slot, S((4,)),
-                    *rows).compile())
+                srv._admit_miss_fn(64).lower(*pool, miss).compile())
         finally:
             srv.shutdown(drain=False, timeout=30.0)
     finally:
@@ -508,15 +504,13 @@ def benchmark_geometry_programs(one_chip):
             ops = _server_operands(srv, one_chip, n_layers=24,
                                    n_blocks=2049)
             out["pool"] = ops[3].shape
-            out["decode_scan"] = _trace_names(
-                srv._decode_scan(8, False).lower(*ops).compile())[1]
-            key = _on_chip(jax.random.PRNGKey(0), one_chip)
-            slot = (S(()), S(()), S(()), key, S((), jnp.float32), S(()),
-                    S((), jnp.float32))     # slot n_new eos key temp tk tp
-            rows = (S((srv.max_blocks,)), S((srv.max_blocks,)))
-            out["admit_miss"] = _trace_names(srv._admit_miss_fn(128).lower(
-                *ops, S((1, 128)), S(()), *slot, S((8,)), *rows)
-                .compile())[1]
+            scan = srv._decode_scan(8, False).lower(*ops)
+            out["decode_scan"] = _trace_names(scan.compile())[1]
+            out["decode_scan_out"] = scan.out_info
+            admit = srv._admit_miss_fn(128).lower(
+                *ops, S((_ADMIT_HEAD + 128 + 8 + 2 * srv.max_blocks,)))
+            out["admit_miss"] = _trace_names(admit.compile())[1]
+            out["operands"] = ops
         finally:
             srv.shutdown(drain=False, timeout=30.0)
     finally:
@@ -588,3 +582,28 @@ def test_admission_at_the_benchmark_geometry_scatters_in_place(
                             benchmark_geometry_programs["pool"])
     assert sorted(op for op, _ in found) == ["fusion", "fusion",
                                              "scatter", "scatter"], found
+
+
+def test_each_program_meets_the_host_through_one_array(
+        benchmark_geometry_programs):
+    """ISSUE 32, at cell 2's shapes for the described chip: beside the
+    pools and the state ``jit_decode_scan`` (K = 8) returns ONE array,
+    int32 [64, K + 2] — the staged tokens, ``emitted``, ``remaining``:
+    all the scheduler reads of a scan — and ``jit_admit_miss`` takes
+    ONE operand beyond parameters, pools and state, the int32 vector
+    ``_pack_admission`` builds; the compiled entry has no parameter
+    more."""
+    kc, vc, state, polled = benchmark_geometry_programs["decode_scan_out"]
+    assert (polled.shape, polled.dtype) == ((64, 8 + 2), jnp.int32)
+    assert isinstance(state, dict) and kc.shape == vc.shape
+    n_device = len(jax.tree_util.tree_leaves(
+        benchmark_geometry_programs["operands"]))
+    entry = [ln for ln in benchmark_geometry_programs["admit_miss"]
+             if re.search(r" parameter\(\d+\)", ln)]
+    # the entry computation's parameters come first in the text, numbered
+    # from 0: one beyond the device-resident operands, the packed vector
+    numbers = [int(re.search(r" parameter\((\d+)\)", ln).group(1))
+               for ln in entry]
+    assert max(numbers) == n_device, (max(numbers), n_device)
+    packed = [ln for ln in entry if f" parameter({n_device})" in ln]
+    assert any(re.search(r"= s32\[\d+\]", ln) for ln in packed), packed

@@ -5,6 +5,7 @@ admission, mixed n_new), queue behind a full slot pool, or retire
 early on EOS."""
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -315,6 +316,90 @@ def test_host_syncs_amortized_by_scan(net):
     assert out.shape == (19,)
     assert syncs.value - s0 == 2                 # two 8-tick scans
     assert ticks.value - t0 == 16
+
+
+def test_one_transfer_each_way_a_dispatch(net, offline, guarded_scheduler):
+    """ISSUE 32 over scans of 1-4 ticks and four admissions — two
+    misses, a prefix hit, a sampled request, a cancel's kill — with the
+    scheduler's thread under ``jax.transfer_guard``: what it hands a
+    program is ONE packed array through ``_to_device``, what it reads
+    back ONE packed array through ``_from_device``; the served tokens
+    are unchanged."""
+    shared = np.asarray([7, 8, 9, 10, 11, 12, 13, 14, 15], np.int32)
+    other = np.asarray([3, 1, 4, 1, 5], np.int32)
+    hits = telemetry.get_registry().counter("prefix_cache_hits_total")
+    with GenerationServer(net, n_slots=2, max_len=32, block_size=4,
+                          tick_batch=4, tick_timeout_s=None) as srv:
+        before, h0 = guarded_scheduler.counts(), hits.value
+        first = srv.submit(shared, n_new=6, timeout=300)
+        again = srv.submit_async(shared, n_new=5)       # a prefix hit
+        sampled = srv.submit_async(other, n_new=7, sampling={
+            "temperature": 1.0, "top_k": 5, "seed": 11})
+        outs = [again.result(timeout=300), sampled.result(timeout=300)]
+        with FaultInjector([f"serve_tick_stall@{i}:0.05"
+                            for i in range(40)]):
+            doomed = srv.submit_async(other, n_new=20)
+            while doomed.emitted == 0:
+                time.sleep(0.005)
+            assert doomed.cancel()
+            with pytest.raises(CancelledError):
+                doomed.result(timeout=300)
+            # the kill's dispatch follows the retire
+            srv.submit(other, n_new=1, timeout=300)
+        d = guarded_scheduler.held(before)
+    assert hits.value - h0 == 3     # ``shared`` once, ``other`` twice
+    assert d['dispatches_total{program="kill"}'] == 1
+    assert d['host_transfers_total{site="scan",dir="h2d"}'] == 0
+    np.testing.assert_array_equal(
+        first, offline.generate(shared[None], n_new=6)[0])
+    np.testing.assert_array_equal(
+        outs[0], offline.generate(shared[None], n_new=5)[0])
+    assert outs[1].shape == (12,)
+
+
+# a sampled request's tokens on the tiny net (prompt [1, 2, 3], 8 new,
+# temperature 1, top_k 5) as PR 31's tree serves them on this
+# installation, by seed: the key made on the host then, in the program now
+PARENT_SAMPLED = {
+    0: [44, 28, 34, 43, 44, 44, 10, 37],
+    11: [15, 5, 23, 20, 28, 20, 37, 16],
+    2 ** 31 - 1: [44, 10, 37, 25, 14, 28, 10, 25],
+    2 ** 32 - 1: [28, 44, 26, 15, 5, 43, 44, 34],
+    2 ** 63 - 1: [28, 44, 26, 15, 5, 43, 44, 34],
+}
+
+
+def test_key_is_derived_in_the_program_to_the_host_s_bits(net):
+    """The admit programs make the slot's PRNG key from the packed seed
+    word: bit for bit ``jax.random.PRNGKey(seed)`` at the edges of what
+    ``submit`` accepts (any signed 64-bit integer), temperature and
+    top_p cross as their bits, and a sampled request's tokens are the
+    parent's."""
+    from deeplearning4j_tpu.parallel import generation_server as gs
+    unpack = jax.jit(lambda ops: gs._unpack_admission(ops, 3)[0])
+    for seed in (0, 1, 2 ** 31 - 1, 2 ** 32 - 1, 2 ** 63 - 1, -1,
+                 -2 ** 63):
+        req = gs._Pending(np.asarray([1, 2, 3], np.int32), 8, -1, seed,
+                          temperature=0.7, top_k=5, top_p=0.9)
+        ops = gs._pack_admission(req, 1, req.prompt)
+        assert ops.dtype == np.int32 and ops.shape == (gs._ADMIT_HEAD + 3,)
+        slot, t0, n_new, eos, key, temp, tk, tp = unpack(ops)
+        np.testing.assert_array_equal(
+            np.asarray(key), np.asarray(jax.random.PRNGKey(seed)))
+        assert (int(t0), int(slot), int(n_new), int(eos), int(tk)) == (
+            3, 1, 8, -1, 5)
+        assert (np.float32(temp), np.float32(tp)) == (
+            np.float32(0.7), np.float32(0.9))
+    ps = np.asarray([1, 2, 3], np.int32)
+    with GenerationServer(net, n_slots=2, max_len=32, tick_batch=8,
+                          tick_timeout_s=None) as srv:
+        for seed, tokens in PARENT_SAMPLED.items():
+            out = srv.submit(ps, n_new=8, timeout=300, sampling={
+                "temperature": 1.0, "top_k": 5, "seed": seed})
+            assert out[3:].tolist() == tokens, seed
+        for seed in (2 ** 63, -2 ** 63 - 1):
+            with pytest.raises(ValueError, match="seed"):
+                srv.submit(ps, n_new=1, sampling={"seed": seed})
 
 
 @pytest.mark.parametrize("route", ["reference", "pallas"])

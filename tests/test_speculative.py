@@ -335,6 +335,33 @@ def test_spec_prefix_cache_hit_parity(net, offline):
             == srv.stats()["spec_proposed"]
 
 
+def test_spec_round_moves_one_array_each_way(net, offline,
+                                             guarded_scheduler):
+    """ISSUE 32 on the speculative path, the scheduler's thread under
+    ``jax.transfer_guard``: a round's result (tokens, ``emitted``,
+    ``remaining``, the per-slot proposed and accepted tallies) is ONE
+    read at the one poll site, its per-slot depths ONE array in; a
+    miss's and a draft-cache hit's operands — the draft's rows among
+    them — ONE vector each.  Tokens and acceptance as without the
+    guard."""
+    p = np.arange(1, 14, dtype=np.int32)     # 3 full blocks @ bs=4
+    ref = offline.generate(p[None], n_new=6)[0]
+    with GenerationServer(net, n_slots=2, max_len=32, block_size=4,
+                          tick_timeout_s=None,
+                          speculative={"k": 2, "draft_layers": 2}) \
+            as srv:
+        before = guarded_scheduler.counts()
+        for _ in range(2):                   # a miss, then the hit
+            np.testing.assert_array_equal(
+                srv.submit(p, n_new=6, timeout=300), ref)
+        d = guarded_scheduler.held(before)
+        st = srv.stats()
+    assert d["admitted_total"] == 2
+    assert d['host_transfers_total{site="scan",dir="h2d"}'] \
+        == d['dispatches_total{program="scan"}']
+    assert st["spec_accepted"] == st["spec_proposed"] > 0
+
+
 def test_spec_on_the_kernel_route_reads_a_lane_wide_pool(net, offline,
                                                         monkeypatch):
     """The speculative programs on the kernel route (forced; Pallas
