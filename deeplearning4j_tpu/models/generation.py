@@ -28,15 +28,25 @@ Two stacks decode here:
   -> (Rnn)OutputLayer`` (e.g. ``zoo.Gpt``): the N conf-identical post-LN
   blocks are ONE run, their parameters stacked by ``_stack_blocks``;
 * ``EmbeddingSequenceLayer -> runs of pre-norm Mamba / attention blocks
-  -> TiedLMHead`` (``nn/conf/layers_hybrid.py``; e.g.
-  ``zoo.HybridDecoder``), stacked as the net holds them, so a snapshot
-  of them is the tree itself.  Beside the K/V cache of its attention
-  layers its decode carries a fixed-size RECURRENT state per row:
+  -> TiedLMHead | LMHead`` (``nn/conf/layers_hybrid.py``; e.g.
+  ``zoo.HybridDecoder``, ``zoo.SparseWindowDecoder``), stacked as the
+  net holds them, so a snapshot of them is the tree itself.  Beside the
+  K/V cache of its FULL-attention layers its decode carries per-row
+  state of a fixed size, ``rec``, a dict of whichever of these the
+  stack's runs keep (``None`` where none does):
 
-      rec = {"h":    [rec_layers, b, d_state, d_inner] float32,
-             "conv": [rec_layers, b, d_conv - 1, d_inner] compute dtype}
+      "h":      [rec_layers, b, d_state, d_inner] float32   (Mamba)
+      "conv":   [rec_layers, b, d_conv - 1, d_inner] compute dtype
+      "win_k":  [win_layers, b, kv_heads, window, qk_dim]   (a WINDOW
+      "win_v":  [win_layers, b, kv_heads, window, v_dim]     run's ring:
+                row j holds the newest position p with p % window == j)
+      "routed": int32 [held + 1], the routed feed-forwards' tally: rows
+                each held expert got, then every token-expert pair made
 
-  ``rec`` is ``None`` wherever a stack keeps no such state.
+  One pool a KIND of attention run, a kind being the run's
+  ``(n_kv_heads, qk_dim, v_dim, window)``: the full-attention runs of a
+  stack share one kind, its window runs another (their layers' rows
+  need not be as many or as wide as the full kind's).
 
 IMPORTED graphs (SameDiff IR) are NOT decodable here yet: they fine-tune
 through ``fused_attention`` but have no cached-step form — a known gap
@@ -57,6 +67,7 @@ import numpy as np
 from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.nn.conf.layers_core import OutputLayer
 from deeplearning4j_tpu.nn.conf.layers_hybrid import (AttentionBlockRun,
+                                                      LMHead,
                                                       MambaBlockRun,
                                                       TiedLMHead)
 from deeplearning4j_tpu.nn.conf.layers_transformer import (
@@ -213,6 +224,30 @@ def _scatter_then_read(kv, layer, wblk, woff, k, v, read):
                           _with_layer(vc, vl, layer))
 
 
+def _kind(run) -> tuple:
+    """(n_kv_heads, qk_dim, v_dim, window) of an attention run: what
+    two runs must share to share a pool."""
+    dh = run.head_dim
+    return (run.n_kv_heads, getattr(run, "qk_dim", None) or dh,
+            getattr(run, "v_dim", None) or dh, getattr(run, "window", None))
+
+
+def _windowed(run) -> bool:
+    return not run.RECURRENT and _kind(run)[3] is not None
+
+
+def _routed(run) -> bool:
+    return getattr(run, "n_experts", None) is not None
+
+
+def _scan_split(run, p):
+    """(the leaves of a run's stacked parameters a scan over its layers
+    slices, the leaves it closes over whole): a routed run's expert
+    matrices stay where they lie."""
+    whole = run.whole_leaves(p) if hasattr(run, "whole_leaves") else {}
+    return {k: v for k, v in p.items() if k not in whole}, whole
+
+
 def _depth(run_p) -> int:
     """Layers in one run's stacked parameters (a truncated self-draft
     hands in fewer than the run was made with)."""
@@ -244,8 +279,8 @@ class TransformerGenerator:
         if self._made_stacked:
             if not isinstance(self.head, TiedLMHead):
                 raise ValueError(
-                    "a stack of block runs decodes through a TiedLMHead, "
-                    f"got {type(self.head).__name__}")
+                    "a stack of block runs decodes through a TiedLMHead "
+                    f"or an LMHead, got {type(self.head).__name__}")
             self.runs = self.blocks
             self._layers = [r.n_blocks for r in self.runs]
         else:
@@ -253,17 +288,29 @@ class TransformerGenerator:
             self._layers = [len(self.blocks)]
         attn = [r for r in self.runs if not r.RECURRENT]
         rec = [r for r in self.runs if r.RECURRENT]
+        full = [r for r in attn if not _windowed(r)]
+        win = [r for r in attn if _windowed(r)]
         # one pool and one stacked state serve every run of a kind
-        if len({(r.n_kv_heads, r.head_dim) for r in attn}) > 1:
-            raise ValueError("the attention runs of one stack share one "
-                             "K/V pool: n_kv_heads and head_dim must agree")
+        for what, runs in (("full-attention", full), ("window", win)):
+            if len({_kind(r) for r in runs}) > 1:
+                raise ValueError(
+                    f"the {what} runs of one stack share one K/V pool: "
+                    "n_kv_heads, qk_dim, v_dim and window must agree (a "
+                    "stack has one pool a kind -- full, window -- and "
+                    "each kind's runs are of one shape)")
         if len({(r.d_state, r.d_inner, r.d_conv) for r in rec}) > 1:
             raise ValueError("the recurrent runs of one stack share one "
                              "state: d_state, d_inner and d_conv must agree")
-        if not attn:
-            raise ValueError("a stack without an attention run has no "
+        if len({r.held_experts[1] for r in attn if _routed(r)}) > 1:
+            raise ValueError("the routed runs of one stack share one "
+                             "tally: they must hold as many experts each")
+        if not full:
+            raise ValueError("a stack without a full-attention run has no "
                              "K/V pool to page (not supported)")
-        self._attn, self._rec = attn[0], (rec[0] if rec else None)
+        self._attn, self._rec = full[0], (rec[0] if rec else None)
+        self._win = win[0] if win else None
+        self._held = next((r.held_experts[1] for r in attn if _routed(r)),
+                          None)
         #: why a server cannot share, restore, re-verify or shard this
         #: stack's K/V rows (the run kind's ``REFUSES``; None: it can)
         self.refuses = next((r.REFUSES for r in rec + attn if r.REFUSES),
@@ -296,24 +343,57 @@ class TransformerGenerator:
                              f"head, got {type(self.head).__name__}")
         return self.blocks[0]
 
-    # -- what a server sizes its pool and state by ----------------------
+    # -- what a server sizes its pools and state by --------------------
+    # the FULL kind's (the paged, allocated pool) ...
     kv_layers = property(lambda self: sum(
-        n for r, n in zip(self.runs, self._layers) if not r.RECURRENT))
+        n for r, n in zip(self.runs, self._layers)
+        if not r.RECURRENT and not _windowed(r)))
     kv_heads = property(lambda self: self._attn.n_kv_heads)
-    head_dim = property(lambda self: self._attn.head_dim)
+    qk_dim = property(lambda self: _kind(self._attn)[1])
+    v_dim = property(lambda self: _kind(self._attn)[2])
+    head_dim = qk_dim
+    # ... the WINDOW kind's (None: the stack has no window run) ...
+    window_kind = property(
+        lambda self: None if self._win is None else _kind(self._win))
+    win_layers = property(lambda self: sum(
+        n for r, n in zip(self.runs, self._layers) if _windowed(r)))
+    # ... and the routed runs' layers and the experts each holds
+    routed_layers = property(lambda self: sum(
+        n for r, n in zip(self.runs, self._layers) if _routed(r)))
+    held_experts = property(lambda self: self._held)
     vocab_size = property(lambda self: int(self.head.n_out))
 
-    def fresh_rec(self, b: int):
-        """The recurrent state of ``b`` rows that have seen nothing
-        (None: the stack keeps none)."""
-        r = self._rec
-        if r is None:
-            return None
-        layers = sum(self._layers) - self.kv_layers
-        return {"h": jnp.zeros((layers, b, r.d_state, r.d_inner),
-                               jnp.float32),
-                "conv": jnp.zeros((layers, b, r.d_conv - 1, r.d_inner),
-                                  self.compute_dtype)}
+    def window_blocks(self, block_size: int) -> int:
+        """Blocks of ``block_size`` positions a slot's window takes."""
+        return -(-self._win.window // block_size)
+
+    def fresh_rec(self, b: int, block_size: Optional[int] = None,
+                  shard=None):
+        """The per-row state of ``b`` rows that have seen nothing (None:
+        the stack keeps none).  With ``block_size`` the window rings are
+        a server's: paged pools [win_layers, b * window_blocks + 1,
+        kv_heads, block_size, pool width], block 0 the scratch sink,
+        row b's ring in blocks ``1 + b * window_blocks ..``, for life."""
+        from deeplearning4j_tpu.kernels import paged_pool_width
+        out, r = {}, self._rec
+        if r is not None:
+            layers = (sum(self._layers) - self.kv_layers
+                      - self.win_layers)
+            out["h"] = jnp.zeros((layers, b, r.d_state, r.d_inner),
+                                 jnp.float32)
+            out["conv"] = jnp.zeros((layers, b, r.d_conv - 1, r.d_inner),
+                                    self.compute_dtype)
+        if self._win is not None:
+            hkv, dk, dv, window = _kind(self._win)
+            for name, dim in (("win_k", dk), ("win_v", dv)):
+                shape = ((b, hkv, window, dim) if block_size is None else
+                         (b * self.window_blocks(block_size) + 1, hkv,
+                          block_size, paged_pool_width(dim, shard)))
+                out[name] = jnp.zeros((self.win_layers,) + shape,
+                                      self.compute_dtype)
+        if self._held is not None:
+            out["routed"] = jnp.zeros((self._held + 1,), jnp.int32)
+        return out or None
 
     def _params(self):
         self.net._check_init()   # fires any lazy _param_sync_hook
@@ -339,77 +419,117 @@ class TransformerGenerator:
         mesh they gather, so a sampler's argmax / sort runs on the full
         vocabulary row."""
         if isinstance(self.head, TiedLMHead):
-            logits = self.head.logits(head_p, emb_p["W"], x)
+            table = head_p if isinstance(self.head, LMHead) else emb_p
+            logits = self.head.logits(head_p, table["W"], x)
         else:
             logits = x.astype(jnp.float32) @ head_p["W"] + head_p["b"]
         return logits if shard is None else shard.rep(logits)
 
     # -- one tick --------------------------------------------------------
     def _tick(self, emb_p, runs_p, head_p, tok, pos, rec, active, kv,
-              attend_at, shard=None):
+              attend_at, shard=None, attend_win=None):
         """Every run's ``step()`` in turn over the rows ``tok`` at
-        ``pos``.  ``kv`` is the attention layers' cache, carried WHOLE
-        through each run's layer scan (xs: the run's stacked parameters
-        and the layer's index) and threaded through
-        ``attend_at(kv, layer)(q, k, v) -> (att, kv)``; ``rec`` the
-        recurrent layers' state, advanced for the ``active`` rows.
+        ``pos``.  ``kv`` is the full-attention layers' cache, carried
+        WHOLE through each run's layer scan (xs: the run's stacked
+        parameters and the layer's index) and threaded through
+        ``attend_at(kv, layer)(q, k, v) -> (att, kv)``; ``rec`` the rest
+        of the per-row state: the recurrent layers', advanced for the
+        ``active`` rows; the window runs' rings, threaded likewise
+        through ``attend_win((win_k, win_v), layer)``; the routed runs'
+        tally, which the ``active`` rows add to.
         Returns (logits, kv, rec)."""
         x = _embed_token(self.emb, emb_p, tok, pos).astype(
             self.compute_dtype)
         if shard is not None:
             x = shard.rep(x)
-        kv_l = rec_l = 0
+        rec = dict(rec or {})
+        at = {"kv": 0, "win": 0, "rec": 0}
+        caches = {"kv": kv, "win": (rec.get("win_k"), rec.get("win_v"))}
         for run, p in zip(self.runs, runs_p):
             n = _depth(p)
             if run.RECURRENT:
                 def body(carry, xs, run=run):
-                    h, rec = carry
-                    return run.step(xs[0], h, rec, xs[1], active), None
-                (x, rec), _ = jax.lax.scan(
-                    body, (x, rec), (p, jnp.arange(rec_l, rec_l + n)))
-                rec_l += n
-            else:
-                def body(carry, xs, run=run):
-                    h, kv = carry
-                    return run.step(xs[0], h, attend_at(kv, xs[1]),
-                                    shard=shard), None
-                (x, kv), _ = jax.lax.scan(
-                    body, (x, kv), (p, jnp.arange(kv_l, kv_l + n)))
-                kv_l += n
-        return self._logits(emb_p, head_p, x, shard), kv, rec
+                    h, state = carry
+                    return run.step(xs[0], h, state, xs[1], active), None
+                (x, state), _ = jax.lax.scan(
+                    body, (x, {k: rec[k] for k in ("h", "conv")}),
+                    (p, jnp.arange(at["rec"], at["rec"] + n)))
+                rec.update(state)
+                at["rec"] += n
+                continue
+            which = "win" if _windowed(run) else "kv"
+            attend = attend_win if which == "win" else attend_at
+            # a run of layers_hybrid rotates at pos and routes the live
+            # rows; a post-LN block knows neither
+            extra = ({"pos": pos, "live": active}
+                     if isinstance(run, AttentionBlockRun) else {})
+            tally = (rec["routed"],) if _routed(run) else ()
+            sliced, whole = _scan_split(run, p)
+
+            def body(carry, xs, run=run, attend=attend, extra=extra,
+                     whole=whole, first=at[which]):
+                h, cache, *tally = carry
+                if whole:
+                    extra = {**extra, "layer": xs[1] - first}
+                h, cache, *new = run.step(
+                    {**xs[0], **whole}, h, attend(cache, xs[1]),
+                    shard=shard, **extra)
+                return (h, cache, *(t + d for t, d in zip(tally, new))), None
+            (x, caches[which], *tally), _ = jax.lax.scan(
+                body, (x, caches[which], *tally),
+                (sliced, jnp.arange(at[which], at[which] + n)))
+            if tally:
+                rec["routed"] = tally[0]
+            at[which] += n
+        if self._win is not None:
+            rec["win_k"], rec["win_v"] = caches["win"]
+        return (self._logits(emb_p, head_p, x, shard), caches["kv"],
+                rec or None)
 
     def _step(self, emb_p, runs_p, head_p, kc, vc, rec, tok, pos):
         """One offline decode tick over dense caches ``kc`` / ``vc``
-        [kv_layers, b, kv_heads, L, head_dim]: writes position ``pos``
-        (a scalar), attends over <= pos.  float32 scores, a -1e9 mask,
-        softmax in float32: ``sequence()``'s math, which is what makes
-        cached decode equal the full forward.  Returns (logits [b, V],
-        kc, vc, rec)."""
-        scale = 1.0 / math.sqrt(self.head_dim)
+        [kv_layers, b, kv_heads, L, qk_dim | v_dim]: writes position
+        ``pos`` (a scalar), attends over <= pos.  float32 scores, a
+        -1e9 mask, softmax in float32: ``sequence()``'s math, which is
+        what makes cached decode equal the full forward.  A window run
+        writes its ring (``rec["win_k"]`` / ``["win_v"]``) at ``pos %
+        window`` and attends over the rows written so far.  Returns
+        (logits [b, V], kc, vc, rec)."""
+        from deeplearning4j_tpu.kernels import softmax_with_sink
 
-        def attend_at(kv, layer):
-            def attend(q, k, v):
-                b, hq, dh = q.shape
-                kc, vc = kv
-                put = lambda c, row: jax.lax.dynamic_update_slice(
-                    _layer_of(c, layer),
-                    row[:, :, None, :].astype(c.dtype), (0, 0, pos, 0))
-                kl, vl = put(kc, k), put(vc, v)
-                # grouped query heads: hq // kv_heads on each K/V head
-                qg = q.reshape(b, kl.shape[1], -1, dh)
-                s = jnp.einsum("bhgd,bhkd->bhgk", qg, kl).astype(
-                    jnp.float32) * scale
-                s = jnp.where(jnp.arange(kl.shape[2]) <= pos, s, -1e9)
-                w = jax.nn.softmax(s, axis=-1).astype(vl.dtype)
-                att = jnp.einsum("bhgk,bhkd->bhgd", w, vl)
-                return att.reshape(b, hq, dh), (_with_layer(kc, kl, layer),
-                                                _with_layer(vc, vl, layer))
-            return attend
+        def dense(run, at, n_seen):
+            """``attend_at`` over a pair of dense caches, the row put at
+            column ``at``, columns < ``n_seen`` read."""
+            scale = 1.0 / math.sqrt(_kind(run)[1])
 
+            def attend_at(kv, layer):
+                def attend(q, k, v, sink=None):
+                    b, hq, dh = q.shape
+                    kc, vc = kv
+                    put = lambda c, row: jax.lax.dynamic_update_slice(
+                        _layer_of(c, layer),
+                        row[:, :, None, :].astype(c.dtype), (0, 0, at, 0))
+                    kl, vl = put(kc, k), put(vc, v)
+                    # grouped query heads: hq // kv_heads on each K/V head
+                    qg = q.reshape(b, kl.shape[1], -1, dh)
+                    s = jnp.einsum("bhgd,bhkd->bhgk", qg, kl).astype(
+                        jnp.float32) * scale
+                    s = jnp.where(jnp.arange(kl.shape[2]) < n_seen, s, -1e9)
+                    w = softmax_with_sink(s, sink).astype(vl.dtype)
+                    att = jnp.einsum("bhgk,bhkd->bhgd", w, vl)
+                    return att.reshape(b, hq, -1), (
+                        _with_layer(kc, kl, layer),
+                        _with_layer(vc, vl, layer))
+                return attend
+            return attend_at
+
+        win = self._win
         active = jnp.ones(tok.shape, bool)
         logits, (kc, vc), rec = self._tick(
             emb_p, runs_p, head_p, tok, pos, rec, active, (kc, vc),
-            attend_at)
+            dense(self._attn, pos, pos + 1),
+            attend_win=None if win is None else dense(
+                win, pos % win.window, pos + 1))
         return logits, kc, vc, rec
 
     def _step_paged(self, emb_p, runs_p, head_p, kc, vc, tok, pos, table,
@@ -440,29 +560,53 @@ class TransformerGenerator:
         replicate, block math shards heads / columns along ``tp`` with
         explicit replication before feature reductions, the logits
         gather -- byte-identical to the unsharded program by
-        construction.  Returns (logits, kc, vc, rec)."""
+        construction.
+
+        A WINDOW run's ring is paged too (``rec["win_k"]`` /
+        ``["win_v"]``, ``fresh_rec(b, block_size)``'s layout): slot b's
+        table is its own blocks, for life; the row lands at ring
+        position ``pos % window`` and the read covers the ``min(pos + 1,
+        window)`` rows written -- keys are cached rotated and softmax
+        does not mind the order, so it is the SAME call as a full
+        layer's, through a table one window long.
+        Returns (logits, kc, vc, rec)."""
         from deeplearning4j_tpu.kernels import (
             paged_decode_attention, paged_decode_write_attention,
             paged_route)
-        scale = 1.0 / math.sqrt(self.head_dim)
         kernel = kernel_writes and paged_route(shard) == "pallas"
 
-        def attend_at(kv, layer):
-            def attend(q, k, v):
-                if kernel:
-                    att, kc, vc = paged_decode_write_attention(
-                        q, k, v, *kv, table, pos, wblk, woff, layer,
-                        scale=scale)
-                    return att, (kc, vc)
-                return _scatter_then_read(
-                    kv, layer, wblk, woff, k, v,
-                    lambda kl, vl: paged_decode_attention(
-                        q, kl, vl, table, pos, scale=scale, shard=shard))
-            return attend
+        def paged(run, table, pos, wblk, woff):
+            scale = 1.0 / math.sqrt(_kind(run)[1])
 
+            def attend_at(kv, layer):
+                def attend(q, k, v, sink=None):
+                    if kernel:
+                        att, kc, vc = paged_decode_write_attention(
+                            q, k, v, *kv, table, pos, wblk, woff, layer,
+                            scale=scale, sink=sink)
+                        return att, (kc, vc)
+                    return _scatter_then_read(
+                        kv, layer, wblk, woff, k, v,
+                        lambda kl, vl: paged_decode_attention(
+                            q, kl, vl, table, pos, scale=scale,
+                            shard=shard, sink=sink))
+                return attend
+            return attend_at
+
+        attend_win = None
+        if self._win is not None:
+            window, bs = self._win.window, rec["win_k"].shape[3]
+            wb = self.window_blocks(bs)
+            mine = 1 + jnp.arange(tok.shape[0], dtype=jnp.int32) * wb
+            ring = pos % window
+            attend_win = paged(
+                self._win, mine[:, None] + jnp.arange(wb, dtype=jnp.int32),
+                jnp.minimum(pos, window - 1),
+                jnp.where(wblk != 0, mine + ring // bs, 0),
+                jnp.where(wblk != 0, ring % bs, 0))
         logits, (kc, vc), rec = self._tick(
             emb_p, runs_p, head_p, tok, pos, rec, active, (kc, vc),
-            attend_at, shard)
+            paged(self._attn, table, pos, wblk, woff), shard, attend_win)
         return logits, kc, vc, rec
 
     def _verify_rows_paged(self, emb_p, runs_p, head_p, kc, vc, toks,
@@ -488,7 +632,7 @@ class TransformerGenerator:
         invariant speculative greedy parity rests on."""
         from deeplearning4j_tpu.kernels import paged_verify_attention
         B, W = toks.shape
-        scale = 1.0 / math.sqrt(self.head_dim)
+        scale = 1.0 / math.sqrt(self.qk_dim)
         chunk = lambda z: z.reshape((B, W) + z.shape[1:])
 
         def attend_at(kv, layer):
@@ -510,12 +654,15 @@ class TransformerGenerator:
                    prefix=None, shard=None):
         """Every run's ``sequence()`` in turn over the embedded rows x
         [b, t, d].  Returns (logits [b, V] at row ``last_ix`` (default:
-        the last), ks, vs [kv_layers, b, kv_heads, t, head_dim], rec)."""
+        the last), ks, vs [kv_layers, b, kv_heads, t, qk_dim | v_dim] of
+        the full-attention layers, rec: the rest AS AFTER TOKEN ``t0``
+        -- the recurrent state, the window runs' rings, the routed
+        runs' tally over the real positions)."""
         cd = self.compute_dtype
         x = x.astype(cd)
         if shard is not None:
             x = shard.rep(x)
-        ks, vs, hs, convs = [], [], [], []
+        ks, vs, hs, convs, wks, wvs, tally = [], [], [], [], [], [], []
         kv_l = 0
         for run, p in zip(self.runs, runs_p):
             if run.RECURRENT:
@@ -525,10 +672,14 @@ class TransformerGenerator:
                 convs.append(got["conv"].astype(cd))
                 continue
             n = _depth(p)
+            sliced, whole = _scan_split(run, p)
+            # a routed run's experts are read where they lie
+            at_layer = lambda l, whole=whole: {"layer": l} if whole else {}
             if prefix is None:
                 x, got = jax.lax.scan(
-                    lambda h, p_l, run=run: run.sequence(
-                        p_l, h, t0, shard=shard), x, p)
+                    lambda h, xs, run=run, whole=whole: run.sequence(
+                        {**xs[0], **whole}, h, t0, shard=shard,
+                        **at_layer(xs[1])), x, (sliced, jnp.arange(n)))
             else:
                 pk, pv, p0 = prefix
                 mine = slice(kv_l, kv_l + n)
@@ -536,6 +687,12 @@ class TransformerGenerator:
                     lambda h, xs, run=run: run.sequence(
                         xs[0], h, t0, prefix=(xs[1], xs[2], p0),
                         shard=shard), x, (p, pk[mine], pv[mine]))
+            if "routed" in got:
+                tally.append(jnp.sum(got["routed"], axis=0))
+            if _windowed(run):          # its ring as after token t0
+                wks.append(got["k"].astype(cd))
+                wvs.append(got["v"].astype(cd))
+                continue
             ks.append(got["k"].astype(cd))
             vs.append(got["v"].astype(cd))
             kv_l += n
@@ -543,9 +700,13 @@ class TransformerGenerator:
                              if len(parts) > 1 else parts[0])
         last = (x[:, -1] if last_ix is None else
                 jax.lax.dynamic_slice_in_dim(x, last_ix, 1, axis=1)[:, 0])
-        rec = {"h": cat(hs), "conv": cat(convs)} if hs else None
+        rec = {"h": cat(hs), "conv": cat(convs)} if hs else {}
+        if wks:
+            rec.update(win_k=cat(wks), win_v=cat(wvs))
+        if tally:
+            rec["routed"] = sum(tally)
         return (self._logits(emb_p, head_p, last, shard), cat(ks), cat(vs),
-                rec)
+                rec or None)
 
     def _prefill_rows(self, emb_p, runs_p, head_p, prompt, t0=None,
                       shard=None):

@@ -1,7 +1,7 @@
 """Pre-norm decoder blocks for hybrid state-space / attention stacks.
 
 Two blocks, each ``x = x + Mixer(RMSNorm(x))`` then ``x = x +
-SwiGLU(RMSNorm(x))``, no biases and no positional term:
+FFN(RMSNorm(x))``, no biases:
 
 * ``MambaBlockRun`` -- the mixer is a Mamba-1 selective state-space
   layer (input projection, causal depthwise convolution, input-dependent
@@ -9,7 +9,20 @@ SwiGLU(RMSNorm(x))``, no biases and no positional term:
   state per channel, gated output projection; RMSNorm on dt, B and C).
 * ``AttentionBlockRun`` -- the mixer is causal softmax attention with
   grouped query heads (``n_heads`` query heads on ``n_kv_heads`` K/V
-  heads).
+  heads).  By its conf it may also have keys ``qk_dim`` wide beside
+  values ``v_dim`` wide, a rotary term on the first ``rotary_dim``
+  lanes of q and k (rotate-half pairing, keys cached rotated), a scale
+  on the values, a sliding ``window`` (a query reads the ``window``
+  newest positions, itself included) and a learned ``sink`` logit per
+  query head that enters the softmax's denominator and nothing else.
+
+The FFN is a dense SwiGLU, or -- ``n_experts`` set -- a ROUTED one: a
+sigmoid router over all ``n_experts`` in float32, the ``top_k`` of
+``score + bias`` selected and weighted by their scores normalised, and
+the experts this chip HOLDS (``held`` = (first, count): what expert
+parallelism tells a layer) computed by ``kernels.expert_ffn``.  What
+the experts held elsewhere would add is not here to add: the layer
+returns its own part of the sum, which is all there is on one chip.
 
 A layer conf here is a RUN of ``n_blocks`` identical blocks: its
 parameters carry a leading ``[n_blocks]`` axis as ``init()`` makes
@@ -36,7 +49,8 @@ scheduler call the same two functions:
 
 ``TiedLMHead`` is the final RMSNorm and the product with the embedding
 table transposed: it owns the norm's gain and READS the table of the
-layer ``tied_to`` names, so no second table exists.
+layer ``tied_to`` names, so no second table exists.  ``LMHead`` is the
+same with a ``[vocab, d]`` matrix of its own (untied).
 """
 from __future__ import annotations
 
@@ -77,6 +91,12 @@ class _PreNormRun(BaseLayerConf):
     n_in: Optional[int] = None
     n_out: Optional[int] = None
     eps: float = 1e-6
+    # a routed feed-forward: the router's outputs, the experts a token
+    # takes, and (first, count) of the experts held here (None: all);
+    # d_ff is then an expert's width
+    n_experts: Optional[int] = None
+    top_k: int = 1
+    held: Optional[tuple] = None
 
     WANTED_KINDS = ("rnn",)
     RECURRENT = False                # keeps per-row state besides K/V
@@ -108,17 +128,91 @@ class _PreNormRun(BaseLayerConf):
                             self.weight_init, dtype,
                             self.weight_distribution)
 
+    @property
+    def held_experts(self) -> tuple:
+        """(first, count) of the experts this chip holds."""
+        if self.held is None:
+            return (0, self.n_experts)
+        first, count = (int(v) for v in self.held)
+        if not (0 <= first and 1 <= count
+                and first + count <= self.n_experts):
+            raise ValueError(f"held={self.held} lies outside the "
+                             f"{self.n_experts} experts")
+        return (first, count)
+
     def _ffn_params(self, keys, dtype):
         d, ff, n = self.n_in, self.d_ff, self.n_blocks
+        if self.n_experts is None:
+            return {"norm2": jnp.ones((n, d), dtype),
+                    "W_gate": self._matrix(keys[0], (d, ff), dtype),
+                    "W_up": self._matrix(keys[1], (d, ff), dtype),
+                    "W_down": self._matrix(keys[2], (ff, d), dtype)}
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.n_experts} "
+                             "experts")
+        held = self.held_experts[1]
+        kr, kg, ku, kd = jax.random.split(keys[0], 4)
+        experts = lambda key, shape: init_weights(
+            key, (n, held) + shape, shape[0], shape[-1], self.weight_init,
+            dtype, self.weight_distribution)
         return {"norm2": jnp.ones((n, d), dtype),
-                "W_gate": self._matrix(keys[0], (d, ff), dtype),
-                "W_up": self._matrix(keys[1], (d, ff), dtype),
-                "W_down": self._matrix(keys[2], (ff, d), dtype)}
+                "W_router": self._matrix(kr, (d, self.n_experts), dtype),
+                "e_bias": jnp.zeros((n, self.n_experts), dtype),
+                "W_gate": experts(kg, (d, ff)),
+                "W_up": experts(ku, (d, ff)),
+                "W_down": experts(kd, (ff, d))}
 
-    def _ffn(self, p, x):
+    #: leaves of a routed run that a scan over its layers leaves WHOLE
+    #: (``whole_leaves``): the kernel reads a layer's experts out of the
+    #: stacked array by the layer's index
+    WHOLE = ("W_gate", "W_up", "W_down")
+
+    def whole_leaves(self, p) -> dict:
+        """The leaves of a run's stacked parameters ``p`` that a scan
+        over its layers should close over, not slice -- a routed run's
+        expert matrices: handed to ``sequence()`` / ``step()`` whole,
+        with ``layer=`` the layer's index in the run."""
+        if self.n_experts is None:
+            return {}
+        return {k: p[k] for k in self.WHOLE}
+
+    def _ffn(self, p, x, live=None, layer=None):
+        """(x + FFN(RMSNorm(x)), tally).  Dense: ``tally`` is None.
+        Routed: rows where ``live`` is false take no expert, and
+        ``tally`` is int32 [count + 1] -- the rows each held expert got,
+        then all the token-expert pairs the router made (held here or
+        elsewhere).  With ``layer`` the expert matrices of ``p`` are the
+        whole run's, this layer's at that index."""
         n = rms_norm(x, p["norm2"], self.eps)
-        w = lambda k: p[k].astype(x.dtype)
-        return x + (_silu(n @ w("W_gate")) * (n @ w("W_up"))) @ w("W_down")
+        if self.n_experts is None:
+            w = lambda k: p[k].astype(x.dtype)
+            return x + (_silu(n @ w("W_gate")) * (n @ w("W_up"))) \
+                @ w("W_down"), None
+        from deeplearning4j_tpu.kernels import expert_ffn
+        first, count = self.held_experts
+        rows = n.reshape(-1, n.shape[-1])
+        with jax.named_scope("expert_route"):
+            # float32 whatever the compute dtype: a top-k among many
+            # near-equal scores flips on rounding
+            f32 = jnp.float32
+            r = jax.nn.sigmoid(jnp.matmul(
+                rows.astype(f32), p["W_router"].astype(f32),
+                precision=jax.lax.Precision.HIGHEST))
+            _, idx = jax.lax.top_k(r + p["e_bias"].astype(f32), self.top_k)
+            picked = jnp.take_along_axis(r, idx, axis=-1)
+            weight = picked / jnp.sum(picked, axis=-1, keepdims=True)
+            alive = (jnp.ones(rows.shape[:1], bool) if live is None
+                     else live.reshape(-1))
+            here = (idx >= first) & (idx < first + count) & alive[:, None]
+            local = jnp.where(here, idx - first, count).astype(jnp.int32)
+            per_expert = jnp.sum(
+                local[..., None] == jnp.arange(count), axis=(0, 1))
+            tally = jnp.concatenate(
+                [per_expert, self.top_k * jnp.sum(alive)[None]]).astype(
+                    jnp.int32)
+        out = expert_ffn(rows, local, jnp.where(here, weight, 0.0),
+                         p["W_gate"], p["W_up"], p["W_down"], layer)
+        return x + out.reshape(x.shape), tally
 
     def apply(self, params, state, x, *, training: bool, rng=None,
               compute_dtype=None, mask=None):
@@ -129,22 +223,53 @@ class _PreNormRun(BaseLayerConf):
         return y, state
 
 
+#: a sequence longer than this is attended a block of queries at a
+#: time: its [b, heads, t, t] float32 scores are not built whole
+_QUERY_BLOCK = 256
+
+
+def rotate_half(x, pos, rotary_dim: int, theta: float):
+    """The rotary term on the first ``rotary_dim`` lanes of x [...,
+    heads, dim] at positions ``pos`` [...]: lane i pairs with lane
+    i + rotary_dim / 2 (rotate-half), angle ``pos * theta ** (-i /
+    (rotary_dim / 2))``; in float32, back in x's dtype."""
+    half = rotary_dim // 2
+    f32 = jnp.float32
+    inv = jnp.exp(jnp.arange(half, dtype=f32) * (-math.log(theta) / half))
+    ang = jnp.asarray(pos, f32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half].astype(f32), x[..., half:rotary_dim].astype(f32)
+    return jnp.concatenate(
+        [(x1 * cos - x2 * sin).astype(x.dtype),
+         (x2 * cos + x1 * sin).astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+
 @register_layer
 @dataclasses.dataclass
 class AttentionBlockRun(_PreNormRun):
-    """``n_blocks`` x [grouped-query causal attention + SwiGLU].  No
-    rotary or other positional term: in a hybrid stack the recurrent
-    layers carry the order."""
+    """``n_blocks`` x [grouped-query causal attention + FFN].  As its
+    defaults stand: no positional term (in a hybrid stack the recurrent
+    layers carry the order), keys and values ``head_dim`` wide, full
+    attention, no sink, a dense SwiGLU."""
 
     n_heads: int = 8
     n_kv_heads: int = 1
     head_dim: Optional[int] = None   # default d / n_heads
+    qk_dim: Optional[int] = None     # default head_dim
+    v_dim: Optional[int] = None      # default head_dim
+    rotary_dim: Optional[int] = None  # lanes of q / k that rotate
+    rope_theta: float = 10000.0
+    value_scale: Optional[float] = None
+    window: Optional[int] = None     # None: full attention
+    sink: bool = False               # a learned logit per query head
 
     #: why a server cannot share, restore, re-verify or shard this
     #: kind's K/V rows (None would mean it can)
     REFUSES = ("AttentionBlockRun layers: the run has no "
-               "sequence(prefix=) over cached K/V rows, no W-row verify "
-               "step() and no shard points yet (ROADMAP M1)")
+               "sequence(prefix=) over cached K/V rows (rotated keys "
+               "and a window's blocks are not restorable from a shared "
+               "prefix yet), no W-row verify step() and no shard points "
+               "(ROADMAP M1, M2, M4)")
 
     def _check_widths(self):
         if self.head_dim is None:
@@ -155,54 +280,127 @@ class AttentionBlockRun(_PreNormRun):
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"n_heads {self.n_heads} must divide by "
                              f"n_kv_heads {self.n_kv_heads}")
+        self.qk_dim = self.qk_dim or self.head_dim
+        self.v_dim = self.v_dim or self.head_dim
+        if self.rotary_dim is not None and (
+                self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.qk_dim):
+            raise ValueError(f"rotary_dim {self.rotary_dim} must be even "
+                             f"and at most qk_dim {self.qk_dim}")
+        if self.window is not None and self.window < 1:
+            raise ValueError("window must be >= 1")
 
     def _init(self, key, dtype):
         d, n = self.n_in, self.n_blocks
-        hq, hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        hq, hkv, dk, dv = (self.n_heads, self.n_kv_heads, self.qk_dim,
+                           self.v_dim)
         ks = jax.random.split(key, 7)
+        sink = ({"sink": jnp.zeros((n, hq), dtype)} if self.sink else {})
         return {"norm1": jnp.ones((n, d), dtype),
-                "Wq": self._matrix(ks[0], (d, hq * dh), dtype),
-                "Wk": self._matrix(ks[1], (d, hkv * dh), dtype),
-                "Wv": self._matrix(ks[2], (d, hkv * dh), dtype),
-                "Wo": self._matrix(ks[3], (hq * dh, d), dtype),
-                **self._ffn_params(ks[4:], dtype)}
+                "Wq": self._matrix(ks[0], (d, hq * dk), dtype),
+                "Wk": self._matrix(ks[1], (d, hkv * dk), dtype),
+                "Wv": self._matrix(ks[2], (d, hkv * dv), dtype),
+                "Wo": self._matrix(ks[3], (hq * dv, d), dtype),
+                **sink, **self._ffn_params(ks[4:], dtype)}
 
-    def _qkv(self, p, x):
+    def _qkv(self, p, x, pos=None):
+        """q [..., n_heads, qk_dim], k [..., n_kv_heads, qk_dim] -- both
+        rotated at ``pos`` [...] where the run has a rotary term -- and
+        v [..., n_kv_heads, v_dim], scaled."""
         n = rms_norm(x, p["norm1"], self.eps)
         lead = x.shape[:-1]
-        hq, hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
-        return ((n @ p["Wq"].astype(x.dtype)).reshape(lead + (hq, dh)),
-                (n @ p["Wk"].astype(x.dtype)).reshape(lead + (hkv, dh)),
-                (n @ p["Wv"].astype(x.dtype)).reshape(lead + (hkv, dh)))
+        hq, hkv, dk, dv = (self.n_heads, self.n_kv_heads, self.qk_dim,
+                           self.v_dim)
+        q = (n @ p["Wq"].astype(x.dtype)).reshape(lead + (hq, dk))
+        k = (n @ p["Wk"].astype(x.dtype)).reshape(lead + (hkv, dk))
+        v = (n @ p["Wv"].astype(x.dtype)).reshape(lead + (hkv, dv))
+        if self.rotary_dim is not None:
+            q = rotate_half(q, pos, self.rotary_dim, self.rope_theta)
+            k = rotate_half(k, pos, self.rotary_dim, self.rope_theta)
+        if self.value_scale is not None:
+            v = v * jnp.asarray(self.value_scale, v.dtype)
+        return q, k, v
 
-    def sequence(self, p, x, t0=None, shard=None):
-        """x [b, t, d] -> (y, {"k", "v"} [b, n_kv_heads, t, head_dim],
-        the rows as a pool holds them).  Causal, so ``t0`` (a padded
-        prompt's real length) changes nothing a real position reads.
-        No ``prefix=`` form and no shard points (``shard`` names a
-        device, never a split): ``REFUSES``."""
-        b, t, _ = x.shape
-        hq, hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
-        q, k, v = self._qkv(p, x)
-        qg = q.reshape(b, t, hkv, hq // hkv, dh)
+    def _attend_block(self, qg, k, v, q_pos, sink):
+        """The queries qg [b, tq, hkv, g, dk] at positions ``q_pos``
+        [tq] over all of k / v [b, t, hkv, .]: float32 scores, a -1e9
+        mask (causal, and the window's band), softmax in float32 with
+        the sink's logit in the denominator."""
+        from deeplearning4j_tpu.kernels import softmax_with_sink
+        t = k.shape[1]
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
-        s = s * (1.0 / math.sqrt(dh))
-        causal = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
-        s = jnp.where(causal[None, None, None], s, -1e9)
-        w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        att = jnp.einsum("bhgqk,bkhd->bqhgd", w, v).reshape(b, t, hq * dh)
-        x = x + att @ p["Wo"].astype(x.dtype)
-        return self._ffn(p, x), {"k": k.transpose(0, 2, 1, 3),
-                                 "v": v.transpose(0, 2, 1, 3)}
+        s = s * (1.0 / math.sqrt(self.qk_dim))
+        k_pos = jnp.arange(t)[None, :]
+        seen = k_pos <= q_pos[:, None]
+        if self.window is not None:
+            seen = seen & (k_pos > q_pos[:, None] - self.window)
+        s = jnp.where(seen[None, None, None], s, -1e9)
+        w = softmax_with_sink(s, sink)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", w.astype(v.dtype), v)
 
-    def step(self, p, x, attend, shard=None):
-        """x [b, d], one new token per row.  ``attend(q [b, n_heads,
-        head_dim], k, v [b, n_kv_heads, head_dim]) -> (att like q,
-        cache)`` writes the row and reads the context."""
-        q, k, v = self._qkv(p, x)
-        att, cache = attend(q, k, v)
+    def sequence(self, p, x, t0=None, shard=None, layer=None):
+        """x [b, t, d] -> (y, {"k", "v"}): of a full-attention run the
+        rows [b, n_kv_heads, t, .] as a pool holds them (keys rotated).
+        Causal, so ``t0`` (a padded prompt's real length) changes
+        nothing a real position reads.  Of a WINDOW run the rows are
+        its cache AS AFTER TOKEN ``t0`` (default: the last): [b,
+        n_kv_heads, window, .], row j the newest position p < t0 with
+        p % window == j (zero where there is none) -- a ring, which
+        softmax does not mind, written on at ``pos % window``.  A routed
+        run gives "routed" too, ``_ffn``'s tally over the positions
+        before ``t0`` (``layer``: as ``_ffn`` takes it).  No ``prefix=`` form and no shard points
+        (``shard`` names a device, never a split): ``REFUSES``."""
+        b, t, _ = x.shape
+        hq, hkv = self.n_heads, self.n_kv_heads
+        q, k, v = self._qkv(p, x, jnp.arange(t))
+        qg = q.reshape(b, t, hkv, hq // hkv, self.qk_dim)
+        sink = p["sink"] if self.sink else None
+        if t <= _QUERY_BLOCK:
+            att = self._attend_block(qg, k, v, jnp.arange(t), sink)
+        else:
+            nq = -(-t // _QUERY_BLOCK)
+            pad = nq * _QUERY_BLOCK - t
+            blocks = jnp.pad(qg, ((0, 0), (0, pad)) + ((0, 0),) * 3).reshape(
+                (b, nq, _QUERY_BLOCK) + qg.shape[2:]).swapaxes(0, 1)
+            att = jax.lax.map(
+                lambda a: self._attend_block(
+                    a[0], k, v, a[1] * _QUERY_BLOCK
+                    + jnp.arange(_QUERY_BLOCK), sink),
+                (blocks, jnp.arange(nq)))
+            att = att.swapaxes(0, 1).reshape(
+                (b, nq * _QUERY_BLOCK) + att.shape[3:])[:, :t]
+        x = x + att.reshape(b, t, hq * self.v_dim) @ p["Wo"].astype(x.dtype)
+        live = None if t0 is None else jnp.broadcast_to(
+            jnp.arange(t) < t0, (b, t))
+        y, tally = self._ffn(p, x, live, layer)
+        rows = lambda z: z.transpose(0, 2, 1, 3)
+        if self.window is not None:
+            last = (t if t0 is None else t0) - 1
+            j = jnp.arange(self.window)
+            at = last - (last - j) % self.window      # < 0: none yet
+            rows = lambda z: jnp.where(
+                (at >= 0)[None, None, :, None],
+                jnp.take(z, jnp.clip(at, 0, t - 1), axis=1)
+                .transpose(0, 2, 1, 3), 0)
+        got = {"k": rows(k), "v": rows(v)}
+        if tally is not None:
+            got["routed"] = tally
+        return y, got
+
+    def step(self, p, x, attend, shard=None, pos=None, live=None,
+             layer=None):
+        """x [b, d], one new token per row at positions ``pos`` [b] (a
+        rotary run needs them).  ``attend(q [b, n_heads, qk_dim], k [b,
+        n_kv_heads, qk_dim], v [b, n_kv_heads, v_dim]) -> (att [b,
+        n_heads, v_dim], cache)`` writes the row and reads the context;
+        a run with a sink hands it its logits as ``sink=``.  Returns
+        (y, cache) and, of a routed run, ``_ffn``'s tally over the
+        ``live`` rows as a third (``layer``: as ``_ffn`` takes it)."""
+        q, k, v = self._qkv(p, x, pos)
+        att, cache = (attend(q, k, v, sink=p["sink"]) if self.sink
+                      else attend(q, k, v))
         x = x + att.reshape(x.shape[0], -1) @ p["Wo"].astype(x.dtype)
-        return self._ffn(p, x), cache
+        y, tally = self._ffn(p, x, live, layer)
+        return (y, cache) if tally is None else (y, cache, tally)
 
 
 @register_layer
@@ -226,6 +424,9 @@ class MambaBlockRun(_PreNormRun):
                "is not sharded")
 
     def _check_widths(self):
+        if self.n_experts is not None:
+            raise ValueError("a routed feed-forward on a recurrent run is "
+                             "not supported (its tally is not carried)")
         if self.dt_rank is None:
             self.dt_rank = -(-self.n_in // 16)
 
@@ -323,7 +524,7 @@ class MambaBlockRun(_PreNormRun):
         x = x + gated @ p["W_out"].astype(x.dtype)
         last = t if t0 is None else t0
         win = jax.lax.dynamic_slice_in_dim(up, last, k - 1, axis=1)
-        return self._ffn(p, x), {"h": h, "conv": win}
+        return self._ffn(p, x)[0], {"h": h, "conv": win}
 
     def step(self, p, x, rec, layer, active):
         """x [b, d], one new token per row; ``rec`` the whole stacked
@@ -345,7 +546,7 @@ class MambaBlockRun(_PreNormRun):
                             -jnp.exp(p["A_log"].astype(f32)), p["D"],
                             p["dt_bias"], active)
         x = x + gated @ p["W_out"].astype(x.dtype)
-        return self._ffn(p, x), {"h": h, "conv": conv}
+        return self._ffn(p, x)[0], {"h": h, "conv": conv}
 
 
 @register_layer
@@ -394,3 +595,24 @@ class TiedLMHead(BaseLayerConf):
         if compute_dtype is not None:
             x = x.astype(compute_dtype)
         return self.logits(params, tied["W"], x), state
+
+
+@register_layer
+@dataclasses.dataclass
+class LMHead(TiedLMHead):
+    """Final RMSNorm, then logits against the head's OWN ``[vocab, d]``
+    matrix (untied word embeddings).  An inference head like
+    ``TiedLMHead``."""
+
+    tied_to: Optional[int] = None
+
+    def init(self, key, dtype=jnp.float32):
+        w = init_weights(key, (self.n_out, self.n_in), self.n_in, self.n_out,
+                         self.weight_init, dtype, self.weight_distribution)
+        return {"g": jnp.ones((self.n_in,), dtype), "W": w}, {}
+
+    def apply(self, params, state, x, *, training: bool, rng=None,
+              compute_dtype=None):
+        if compute_dtype is not None:
+            x = x.astype(compute_dtype)
+        return self.logits(params, params["W"], x), state

@@ -300,6 +300,36 @@ _PREFILL_TOKENS = telemetry.counter(
     labelnames=("kind",))
 _PREFILL_REAL = _PREFILL_TOKENS.labels(kind="real")
 _PREFILL_PAD = _PREFILL_TOKENS.labels(kind="pad")
+# the routed feed-forwards' work: the tally every routed layer keeps on
+# the device (``rec["routed"]``) rides the decode scan's one read to the
+# host, with what the admissions since the last scan added to it
+_EXPERT_ROWS = telemetry.counter(
+    "generation_server_expert_rows_total",
+    "token-expert pairs the routers made over decode ticks and "
+    "prefills, by where the expert is held (held: here, a row of "
+    "kernels.expert_ffn; absent: on another chip of the deployment, "
+    "left out)", labelnames=("kind",))
+_EXPERT_HELD = _EXPERT_ROWS.labels(kind="held")
+_EXPERT_ABSENT = _EXPERT_ROWS.labels(kind="absent")
+_EXPERT_CALLS = telemetry.counter(
+    "generation_server_expert_calls_total",
+    "routed layers run (a decode tick's, an admission's) x experts "
+    "held: the experts' turns, each of which rows could have filled")
+_EXPERT_LOAD = telemetry.histogram(
+    "generation_server_expert_load_ratio",
+    "one sample a decode dispatch: the rows of the fullest held expert "
+    "/ the mean held expert's, over the dispatch and the admissions "
+    "before it (1 = even)",
+    buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0))
+_WINDOW_BYTES = telemetry.gauge(
+    "generation_server_window_cache_bytes",
+    "device bytes of the window layers' per-slot rings (K and V, all "
+    "slots and layers); 0 for a net without window attention")
+_POOL_BYTES = telemetry.gauge(
+    "generation_server_kv_pool_bytes",
+    "device bytes of a kind of attention layer's K and V pools (full: "
+    "the paged, allocated pool; window: the per-slot rings)",
+    labelnames=("kind",))
 _REC_BYTES = telemetry.gauge(
     "generation_server_recurrent_state_bytes",
     "device bytes of the per-slot recurrent state (state-space layers' "
@@ -552,13 +582,17 @@ _AdmitPlan = namedtuple("_AdmitPlan", ("phys", "matched", "hashes",
                         defaults=((), 0, (), 0))
 
 
-def _paged_blocks_walked(pos0, ticks, bs: int, chunk: int):
+def _paged_blocks_walked(pos0, ticks, bs: int, chunk: int, last=None):
     """(live, dead) table entries of a decode scan's reads: slot i
     starts at position ``pos0[i]`` and runs ``ticks[i]`` live ticks, a
     position a tick — all slots and ticks at once, by the function the
-    kernel sizes its loop with."""
+    kernel sizes its loop with.  ``last``: the table is a window's ring,
+    read up to position ``last`` at most."""
     tick = np.arange(int(ticks.max(initial=0)))[None, :]
-    live, covered = paged_walk_extent(pos0[:, None] + tick, bs, chunk)
+    pos = pos0[:, None] + tick
+    if last is not None:
+        pos = np.minimum(pos, last)
+    live, covered = paged_walk_extent(pos, bs, chunk)
     ran = tick < ticks[:, None]
     return int(live[ran].sum()), int((covered - live)[ran].sum())
 
@@ -615,6 +649,16 @@ def _pack_polled(toks, *columns):
     entry [B] as one more column."""
     return jnp.concatenate([toks] + [c[:, None] for c in columns],
                            axis=1)
+
+
+def _append_rows(polled, vector):
+    """In a trace: ``polled`` [B, n] with the int32 ``vector`` below it,
+    in as many more rows of n as hold it (zero-filled)."""
+    n = polled.shape[1]
+    rows = -(-vector.shape[0] // n)
+    tail = jnp.zeros((rows * n,), jnp.int32).at[:vector.shape[0]].set(
+        vector)
+    return jnp.concatenate([polled, tail.reshape(rows, n)], axis=0)
 
 
 def _refuse_cache_loaded_mesh_programs(devices) -> None:
@@ -981,6 +1025,8 @@ class GenerationServer:
         # the split (the global series aggregates every replica)
         self._n_prefix_hits = 0
         self._n_prefix_misses = 0
+        # admissions since the last decode scan read the routed tally
+        self._routed_admits = 0
         # per-INSTANCE tier tallies (the process-global kv_tier_*
         # counters aggregate every replica; a router sizing handoffs
         # or a bench proving THIS replica fetched needs the split)
@@ -1038,22 +1084,42 @@ class GenerationServer:
     # [layers, slots, ...] leaves -- d_inner on the lanes; donated, reset
     # and salvaged with the pool -- and a stack that keeps none has no
     # such leaf: this pair is where the generator's ``rec`` meets them
+    # A WINDOW kind's rings ride there likewise: two pools [layers,
+    # slots * window blocks + 1, kv heads, block_size, width] whose
+    # block 1 + slot * window blocks .. a slot owns for life (no
+    # allocator: the table is fixed), armed by an admission as after the
+    # prompt's last real token.  And the routed layers' tally, int32
+    # [held + 1], which the decode scan hands to the host and zeroes.
     _REC_KEYS = ("rec_h", "rec_conv")
+    _WIN_KEYS = ("win_k", "win_v")
+    _REC_LEAVES = {"rec_h": "h", "rec_conv": "conv", "win_k": "win_k",
+                   "win_v": "win_v", "routed": "routed"}
 
-    @staticmethod
-    def _rec_of(state):
-        """``state``'s recurrent leaves as the generator takes them
-        (None: the stack keeps no such state)."""
-        if "rec_h" not in state:
-            return None
-        return {"h": state["rec_h"], "conv": state["rec_conv"]}
+    @classmethod
+    def _rec_of(cls, state):
+        """``state``'s per-slot leaves besides the paged pool as the
+        generator takes them (None: the stack keeps none)."""
+        return {name: state[k] for k, name in cls._REC_LEAVES.items()
+                if k in state} or None
 
-    @staticmethod
-    def _with_rec(state, rec):
+    @classmethod
+    def _with_rec(cls, state, rec):
         """``state`` with the generator's ``rec`` (None: as it is)."""
         if rec is None:
             return state
-        return {**state, "rec_h": rec["h"], "rec_conv": rec["conv"]}
+        return {**state, **{k: rec[name]
+                            for k, name in cls._REC_LEAVES.items()
+                            if name in rec}}
+
+    def _slot_mask(self, key: str, m):
+        """The slots' mask ``m`` [B] as it applies to state leaf
+        ``key``: along the slots of a recurrent leaf, the blocks of a
+        window pool (the scratch block 0 is nobody's)."""
+        if key in self._WIN_KEYS:
+            per = jnp.repeat(m, self._gen.window_blocks(self.block_size))
+            return jnp.concatenate(
+                [jnp.zeros((1,), bool), per])[None, :, None, None, None]
+        return m[None, :, None, None]
 
     def _fresh_pool(self):
         """(Re)allocate the KV block pool and per-slot device state —
@@ -1064,22 +1130,40 @@ class GenerationServer:
         gen = self._gen
         B = self.n_slots
         # the pool is sized by the layers and heads that HOLD K/V
-        h, dh = gen.kv_heads, self._head_dim
+        h = gen.kv_heads
         n_layers = gen.kv_layers
         cd = gen.compute_dtype
         nb = self.kv_blocks + 1      # + block 0, the never-read
                                      # scratch sink for masked writes
-        # the pool's rows are as wide as the route reads them: dh, or
-        # whole 128-lane rows on the kernel route
-        shape = (n_layers, nb, h, self.block_size,
-                 paged_pool_width(dh, self._shard))
-        kc, vc = jnp.zeros(shape, cd), jnp.zeros(shape, cd)
+        # a pool's rows are as wide as the route reads them: the keys'
+        # or the values' own width, or whole 128-lane rows on the
+        # kernel route
+        shape = lambda dim: (n_layers, nb, h, self.block_size,
+                             paged_pool_width(dim, self._shard))
+        kc, vc = jnp.zeros(shape(gen.qk_dim), cd), \
+            jnp.zeros(shape(gen.v_dim), cd)
         # table entries a decode read covers at a time: the kernel's
         # chunk, or the whole table where the reference gathers it
+        on_kernel = paged_route(self._shard) == "pallas"
         self._walk_chunk = (
-            paged_walk_blocks(self.block_size, h, shape[-1], cd,
-                              self.max_blocks)[0]
-            if paged_route(self._shard) == "pallas" else self.max_blocks)
+            paged_walk_blocks(self.block_size, h, kc.shape[-1], cd,
+                              self.max_blocks, vc.shape[-1])[0]
+            if on_kernel else self.max_blocks)
+        # a window kind's table is its window's blocks and no more
+        self._win_walk = None
+        if gen.window_kind is not None:
+            wh, wk, wv, window = gen.window_kind
+            wb = gen.window_blocks(self.block_size)
+            if on_kernel and wb > 1:
+                raise ValueError(
+                    f"window {window} spans {wb} blocks of "
+                    f"{self.block_size}: the decode kernel patches a "
+                    "slot's LAST live block, so on the kernel route a "
+                    "window fits one block (block_size >= window)")
+            self._win_walk = (window, (paged_walk_blocks(
+                self.block_size, wh, paged_pool_width(wk, self._shard), cd,
+                wb, paged_pool_width(wv, self._shard))[0]
+                if on_kernel else wb))
         if self._shard is not None:
             # pool HEADS shard along tp (each chip holds its head
             # slice of every block); the block axis stays GLOBAL —
@@ -1123,9 +1207,14 @@ class GenerationServer:
             state = {k: self._shard.put_batch(v)
                      for k, v in state.items()}
         # zeros: a slot that has seen nothing
-        state = self._with_rec(state, gen.fresh_rec(B))
-        _REC_BYTES.set(sum(state[k].nbytes for k in self._REC_KEYS
-                           if k in state))
+        state = self._with_rec(
+            state, gen.fresh_rec(B, self.block_size, self._shard))
+        nbytes = lambda keys: sum(state[k].nbytes for k in keys
+                                  if k in state)
+        _REC_BYTES.set(nbytes(self._REC_KEYS))
+        _WINDOW_BYTES.set(nbytes(self._WIN_KEYS))
+        _POOL_BYTES.labels(kind="full").set(kc.nbytes + vc.nbytes)
+        _POOL_BYTES.labels(kind="window").set(nbytes(self._WIN_KEYS))
         # commit atomically: this also runs on the watchdog's recovery
         # path while the (fenced) scheduler may still be snapshotting.
         # The host allocator truth resets WITH the device pool — free
@@ -2083,8 +2172,14 @@ class GenerationServer:
             emitted0 = jnp.zeros(state["remaining"].shape, jnp.int32)
             (kc, vc, state, emitted), toks = jax.lax.scan(
                 step, (kc, vc, state, emitted0), None, length=K)
-            return kc, vc, state, _pack_polled(
-                toks.T, emitted, state["remaining"])
+            polled = _pack_polled(toks.T, emitted, state["remaining"])
+            if "routed" in state:
+                # the routed layers' tally since the last scan, in rows
+                # below the slots'; it starts again from nought
+                polled = _append_rows(polled, state["routed"])
+                state = {**state,
+                         "routed": jnp.zeros_like(state["routed"])}
+            return kc, vc, state, polled
 
         # donate caches + state: the scan updates them in place instead
         # of copying both full [n_layers, B, h, L, dh] buffers per
@@ -2400,9 +2495,8 @@ class GenerationServer:
         state of the one admitted row, as after its last real token."""
         armed = self._rec_of(state)
         if rec is not None:
-            armed = {k: jax.lax.dynamic_update_slice(
-                all_rows, rec[k].astype(all_rows.dtype), (0, slot, 0, 0))
-                for k, all_rows in armed.items()}
+            armed = {k: self._arm_leaf(k, all_rows, rec[k], slot)
+                     for k, all_rows in armed.items()}
         return self._with_rec({
             "pos": state["pos"].at[slot].set(t0),
             "remaining": state["remaining"].at[slot].set(n_new),
@@ -2420,6 +2514,29 @@ class GenerationServer:
                 state["dtable"], dtable_row[None], (slot, 0)),
             "rawlg": state["rawlg"].at[slot].set(False),
         }, armed)
+
+    def _arm_leaf(self, name: str, all_rows, row, slot):
+        """One leaf of the generator's ``rec`` with the admitted row's
+        in place: a recurrent leaf's row ``slot``; a window ring [layers,
+        1, kv heads, window, dim] cut into the slot's own blocks (a
+        kernel-route pool's rows zero past ``dim``); the routed tally,
+        which the prefill adds to."""
+        if name == "routed":
+            return all_rows + row
+        if name in self._WIN_KEYS:
+            layers, _, h, window, _ = row.shape
+            bs = self.block_size
+            wb = self._gen.window_blocks(bs)
+            ring = jnp.pad(row[:, 0], ((0, 0), (0, 0),
+                                       (0, wb * bs - window), (0, 0)))
+            blocks = pad_head_dim(
+                ring.reshape(layers, h, wb, bs, -1).transpose(0, 2, 1, 3, 4),
+                all_rows.shape[-1])
+            return jax.lax.dynamic_update_slice(
+                all_rows, blocks.astype(all_rows.dtype),
+                (0, 1 + slot * wb, 0, 0, 0))
+        return jax.lax.dynamic_update_slice(
+            all_rows, row.astype(all_rows.dtype), (0, slot, 0, 0))
 
     def _admit_miss_fn(self, tb: int, use_draft: bool = True):
         """Prefix-MISS admission program for prefill bucket ``tb`` (a
@@ -2718,6 +2835,9 @@ class GenerationServer:
                                    plan.reg_from + n_fills):
                         self._tier.touch(plan.hashes[j][0])
         _ADMITTED.inc()
+        if self._gen.held_experts is not None:
+            with self._lock:
+                self._routed_admits += 1
         _FLIGHT.record("admit", slot=slot, trace=req.trace_id,
                        t0=req.t0, n_new=req.n_new, cached=matched,
                        tier_fills=n_fills,
@@ -2739,6 +2859,23 @@ class GenerationServer:
             _KV_BLK_ALLOC.inc(plan.n_fresh)
         self._update_free_gauge()
         return True
+
+    def _count_routed(self, tally, ticks: int) -> None:
+        """A decode scan's look at the routed layers: ``tally`` is the
+        rows each held expert got, then every token-expert pair made,
+        since the last scan -- over this scan's ``ticks`` and the
+        admissions dispatched before it."""
+        per_expert, pairs = tally[:-1], int(tally[-1])
+        held = int(per_expert.sum())
+        with self._lock:
+            admissions, self._routed_admits = self._routed_admits, 0
+        _EXPERT_HELD.inc(held)
+        _EXPERT_ABSENT.inc(pairs - held)
+        _EXPERT_CALLS.inc((ticks + admissions) * self._gen.routed_layers
+                          * len(per_expert))
+        if held:
+            _EXPERT_LOAD.observe(float(per_expert.max())
+                                 * len(per_expert) / held)
 
     def _retire(self, req: _Pending, slot: int, error=None):
         if error is not None:
@@ -2879,6 +3016,13 @@ class GenerationServer:
                         if k in state:
                             log_fin = log_fin & np.asarray(jnp.isfinite(
                                 state[k]).all(axis=(0, 2, 3)))
+                    # and so does a non-finite block of its window ring
+                    for k in self._WIN_KEYS:
+                        if k in state:
+                            blk = np.asarray(jnp.isfinite(
+                                state[k]).all(axis=(0, 2, 3, 4)))[1:]
+                            log_fin = log_fin & blk.reshape(
+                                self.n_slots, -1).all(axis=1)
                     pos_h = np.asarray(state["pos"])
                     rem_h = np.asarray(state["remaining"])
             except (RuntimeError, ValueError):
@@ -2986,9 +3130,13 @@ class GenerationServer:
                         # with its flag (finite by the -1e30 clamp, so
                         # log_fin kept it); victims reset to plain
                         "rawlg": jnp.where(m, state["rawlg"], False),
-                        **{k: jnp.where(m[None, :, None, None], state[k],
+                        **{k: jnp.where(self._slot_mask(k, m), state[k],
                                         0)
-                           for k in self._REC_KEYS if k in state},
+                           for k in self._REC_KEYS + self._WIN_KEYS
+                           if k in state},
+                        # the tally is no slot's: it goes on
+                        **({"routed": state["routed"]}
+                           if "routed" in state else {}),
                     }
                     n_blk_salvaged = int(bmask.sum())
                     n_blk_dropped = len(used_before
@@ -3359,6 +3507,12 @@ class GenerationServer:
                                 time.perf_counter() - prefill_t0,
                                 device=dev)
                         prefill_t0 = None
+                    routed_h = None
+                    if len(polled_h) > self.n_slots:
+                        # below the slots' rows: the routed layers' tally
+                        routed_h = polled_h[self.n_slots:].reshape(-1)[
+                            :self._gen.held_experts + 1]
+                        polled_h = polled_h[:self.n_slots]
                     toks_h = polled_h[:, :n_tok]
                     emit_h, rem_h = (polled_h[:, n_tok],
                                      polled_h[:, n_tok + 1])
@@ -3438,8 +3592,18 @@ class GenerationServer:
                     n_live, n_dead = _paged_blocks_walked(
                         pos_h, emit_h[slots_h], self.block_size,
                         self._walk_chunk)
+                    if self._win_walk is not None:
+                        # the window kind's table too: a ring's one
+                        # block is live from the first token
+                        window, chunk = self._win_walk
+                        w_live, w_dead = _paged_blocks_walked(
+                            pos_h, emit_h[slots_h], self.block_size, chunk,
+                            last=window - 1)
+                        n_live, n_dead = n_live + w_live, n_dead + w_dead
                     _PAGED_LIVE.inc(n_live)
                     _PAGED_DEAD.inc(n_dead)
+                    if routed_h is not None:
+                        self._count_routed(routed_h, k)
                 _TOKENS_EMITTED.inc(int(emit_h.sum()))
                 _SLOT_TICKS.inc(n_active * (R if use_spec else k))
                 _OCC.observe(n_active / self.n_slots)

@@ -23,6 +23,7 @@ from deeplearning4j_tpu.zoo.facenet import FaceNetNN4Small2
 from deeplearning4j_tpu.zoo.bert import Bert
 from deeplearning4j_tpu.zoo.gpt import Gpt
 from deeplearning4j_tpu.zoo.hybrid_decoder import HybridDecoder
+from deeplearning4j_tpu.zoo.sparse_window_decoder import SparseWindowDecoder
 from deeplearning4j_tpu.zoo.squeezenet import SqueezeNet
 from deeplearning4j_tpu.zoo.xception import Xception
 from deeplearning4j_tpu.zoo.nasnet import NASNet
@@ -32,6 +33,6 @@ from deeplearning4j_tpu.zoo.pretrained import (load_pretrained, register,
 __all__ = ["ZooModel", "LeNet", "AlexNet", "VGG16", "VGG19", "ResNet50",
            "SimpleCNN", "TextGenerationLSTM", "UNet", "InceptionResNetV1",
            "Darknet19", "TinyYOLO", "YOLO2", "FaceNetNN4Small2",
-           "Yolo2OutputLayer", "Bert", "Gpt", "HybridDecoder",
+           "Yolo2OutputLayer", "Bert", "Gpt", "HybridDecoder", "SparseWindowDecoder",
            "SqueezeNet", "Xception", "NASNet",
            "save_pretrained", "load_pretrained", "register"]

@@ -34,6 +34,7 @@ flash_mod = importlib.import_module(
 paged_mod = importlib.import_module(
     "deeplearning4j_tpu.kernels.paged_attention")
 ssm_mod = importlib.import_module("deeplearning4j_tpu.kernels.ssm_step")
+expert_mod = importlib.import_module("deeplearning4j_tpu.kernels.expert_ffn")
 
 # (n_heads, d_head) at d_model 768: the zoo.Gpt default and GPT-2's
 HEADS = [(6, 128), (12, 64)]
@@ -75,6 +76,7 @@ def chip_paths(monkeypatch):
     monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
     monkeypatch.setattr(paged_mod, "_interpret", lambda: False)
     monkeypatch.setattr(ssm_mod, "_interpret", lambda: False)
+    monkeypatch.setattr(expert_mod, "_interpret", lambda: False)
 
 
 def _compiled_text(fn, *shapes):
@@ -346,6 +348,34 @@ def named_programs(one_chip):
                 srv._admit_miss_fn(64).lower(*pool, miss).compile())
         finally:
             srv.shutdown(drain=False, timeout=30.0)
+
+        # a stack of all four kinds of attention run: dense full, routed
+        # window x 2, routed full, routed window (keys 192 wide beside
+        # values 128, a sink on the window layers, 4 of 16 experts held)
+        from deeplearning4j_tpu.zoo import SparseWindowDecoder
+        mp.setattr(expert_mod, "_interpret", lambda: False)
+        mp.setattr(expert_mod, "expert_route", lambda: "pallas")
+        sparse = MultiLayerNetwork(SparseWindowDecoder(
+            vocab_size=128, d_model=256, layer_pattern=(0, 1, 1, 0, 1),
+            routed_layers=(0, 1, 1, 1, 1), n_heads=4, n_kv_heads=1,
+            window_kv_heads=2, qk_dim=192, v_dim=128, rotary_dim=64,
+            window=16, d_ff=512, expert_ff=128, n_experts=16, top_k=2,
+            held=(4, 4), seq_len=64, dtype="bfloat16").conf()).init()
+        srv = GenerationServer(sparse, n_slots=8, max_len=64, block_size=16,
+                               tick_batch=2, compute_dtype="bfloat16",
+                               prefix_cache=False)
+        try:
+            out["sparse_pools"] = (srv._kc.shape, srv._vc.shape,
+                                   srv._state["win_k"].shape)
+            ops = _on_chip((*srv._params, srv._kc, srv._vc, srv._state),
+                           one_chip)
+            scan = srv._decode_scan(2, False).lower(*ops)
+            out["sparse_decode_scan"] = _trace_names(scan.compile())
+            out["sparse_polled"] = scan.out_info[3]
+            out["sparse_admit_miss"] = _trace_names(
+                srv._admit_miss_fn(64).lower(*ops, miss).compile())
+        finally:
+            srv.shutdown(drain=False, timeout=30.0)
     finally:
         mp.undo()
     return out
@@ -358,7 +388,10 @@ def test_programs_and_their_kernels_carry_the_package_s_names(
     flash kernels keep their names under ``jvp`` and ``transpose``
     (one forward and two backward kernels a layer), inside the layer
     scan of a decode tick the paged kernel keeps its own."""
-    assert {k: v[0] for k, v in named_programs.items()} == {
+    assert {k: v[0] for k, v in named_programs.items()
+            if not k.startswith("sparse_po")} == {
+        "sparse_decode_scan": "jit_decode_scan(1)",
+        "sparse_admit_miss": "jit_admit_miss(1)",
         "train_step": "jit_train_step(1)",
         "decode_scan": "jit_decode_scan(1)",
         "admit_miss": "jit_admit_miss(1)",
@@ -422,8 +455,10 @@ def test_name_keyed_metric_matches_a_compiled_name(named_programs, name):
     """Each ``pattern`` (and ``per_events_of.pattern``) matches a
     module or an instruction of the programs compiled above: a rename
     in the package fails here, not silently on the chip."""
-    modules = [m for m, _ in named_programs.values()]
-    ops = [ln for _, lines in named_programs.values() for ln in lines]
+    programs = [v for k, v in named_programs.items()
+                if not k.startswith("sparse_po")]
+    modules = [m for m, _ in programs]
+    ops = [ln for _, lines in programs for ln in lines]
     args = _metric_args(name)
     line = modules if args.get("line") == "XLA Modules" else ops
     assert _matches(args["pattern"], line), args["pattern"]
@@ -607,3 +642,152 @@ def test_each_program_meets_the_host_through_one_array(
     assert max(numbers) == n_device, (max(numbers), n_device)
     packed = [ln for ln in entry if f" parameter({n_device})" in ln]
     assert any(re.search(r"= s32\[\d+\]", ln) for ln in packed), packed
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 33: keys wider than values, a sink, a window's one-block table,
+# the held experts' kernel -- and the accepted cells' kernel unmoved
+# ---------------------------------------------------------------------------
+def _decode_write_lowered(one_chip, B, hq, h, dk, dv, bs, mb, L, nb, sink):
+    """``_paged_decode_write_pallas`` lowered at a cell's geometry: bf16
+    pools of whole 128-lane rows, K and V each at its own width."""
+    def S(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    wide = lambda d: -(-d // 128) * 128
+    bf = jnp.bfloat16
+    args = [S((B, hq, dk), bf), S((B, h, dk), bf), S((B, h, dv), bf),
+            S((L, nb, h, bs, wide(dk)), bf), S((L, nb, h, bs, wide(dv)), bf),
+            S((B, mb)), S((B,)), S((B,)), S((B,)), S(())]
+    if sink:
+        args.append(S((hq,), jnp.float32))
+    return jax.jit(
+        lambda q, kn, vn, kp, vp, t, p, wb, wo, lay, *sk:
+        paged_mod._paged_decode_write_pallas(q, kn, vn, kp, vp, t, p, wb, wo,
+                                             lay, dk ** -0.5, *sk),
+        donate_argnums=(3, 4)).lower(*args)
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_paged_kernel_compiles_at_the_sparse_window_cell_s_geometry(
+        one_chip, chip_paths, kind):
+    """``mimo-v2-flash.closed-long-reasoning``'s two reads, 256 slots,
+    64 query heads, keys 192 wide in 256-lane rows beside values 128
+    wide, bf16: a FULL layer's (4 K/V heads, 16-entry tables of
+    128-token blocks, 2 layers x 4,097 blocks) and a WINDOW layer's (8
+    K/V heads, the slot's one block, a sink a query head, 5 layers x 257
+    blocks).  Both pools go through the kernel aliased, each in one
+    layout, and the name-keyed metrics find the kernel."""
+    h, mb, L, nb, sink = {"full": (4, 16, 2, 4097, False),
+                          "window": (8, 1, 5, 257, True)}[kind]
+    assert paged_mod.paged_walk_blocks(128, h, 256, jnp.bfloat16, mb, 128)[0] == 1
+    compiled = _decode_write_lowered(one_chip, 256, 64, h, 192, 128, 128, mb,
+                                     L, nb, sink).compile()
+    _, lines = _trace_names(compiled)
+    for width in (256, 128):
+        assert _pool_producers(lines, (L, nb, h, 128, width)) == []
+    for name in ("paged_attention_roofline", "decode_scan_tick_device_ms"):
+        args = _metric_args(name)
+        pattern = args.get("per_events_of", args)["pattern"]
+        assert len(_matches(pattern, lines)) == 1, name
+    kernel, = _matches(r"^%paged_attention[.\d]* = .*custom-call\(", lines)
+    for width in (256, 128):                       # both pools, whole
+        assert f"bf16[{L * nb * h},128,{width}]" in kernel
+
+
+@pytest.mark.parametrize("rows", [256, 512], ids=["decode_tick", "prefill"])
+def test_expert_ffn_compiles_at_the_sparse_window_cell_s_geometry(
+        one_chip, chip_paths, rows):
+    """16 held experts of 4096 x 2048 read out of a run's stacked [4, 16,
+    ., .] matrices by a layer index, top-8 picks of 256 rows (a decode
+    tick) or 512 (the largest prefill bucket), bf16: the chip's compiler
+    takes the weight tiles' VMEM and the aligned row tiles, the stacked
+    matrices reach the kernel WHOLE (no slice of a layer is made), and
+    ``expert_ffn_roofline`` finds the kernel by name."""
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    d, ff, held, k, n = 4096, 2048, 16, 8, 4
+    compiled = jax.jit(
+        lambda x, e, w, wg, wu, wd, lay: expert_mod._expert_ffn_call(
+            x, e, w, wg, wu, wd, lay, interpret=False)).lower(
+        S((rows, d), bf), S((rows, k), jnp.int32), S((rows, k), jnp.float32),
+        S((n, held, d, ff), bf), S((n, held, d, ff), bf),
+        S((n, held, ff, d), bf), S((), jnp.int32)).compile()
+    _, lines = _trace_names(compiled)
+    pattern = _metric_args("expert_ffn_roofline")["pattern"]
+    kernel, = _matches(pattern, lines)
+    assert kernel.count(f"bf16[{n},{held},{d},{ff}]") == 2      # gate, up
+    assert f"bf16[{n},{held},{ff},{d}]" in kernel
+    produced = [ln for ln in lines
+                if re.match(rf"%\S+ = bf16\[{held},({d},{ff}|{ff},{d})\]", ln)]
+    assert produced == []
+
+
+#: the decode kernel's Mosaic body at the accepted cells' geometries, as
+#: the parent of ISSUE 33 lowered it: (operations, sha256 of their text
+#: without source locations).  A new jax may move both together; a
+#: change to the kernel that moves them alone moved cells 2 and 3.
+_KERNEL_BODIES = {
+    "bert-large-causal.closed-decode": (
+        (64, 16, 16, 64, 64, 16, 32, 24, 2049, False),
+        698, "255a214b93185b54"),
+    "jamba2-3b.closed-reasoning": (
+        (256, 20, 1, 128, 128, 128, 8, 2, 2049, False),
+        700, "629d03e37915c0f8")}
+
+
+@pytest.mark.parametrize("cell", sorted(_KERNEL_BODIES))
+def test_equal_widths_and_no_sink_lower_the_kernel_the_accepted_cells_had(
+        one_chip, chip_paths, cell):
+    """With keys as wide as values and no sink the decode kernel is the
+    program it was before it learnt either: operation for operation."""
+    import base64
+    import hashlib
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    geometry, n_ops, digest = _KERNEL_BODIES[cell]
+    text = _decode_write_lowered(one_chip, *geometry).as_text()
+    body = re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text).group(1)
+    ctx = jax_mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        ops = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+    assert len(ops.splitlines()) == n_ops
+    assert hashlib.sha256(ops.encode()).hexdigest()[:16] == digest
+
+
+def test_decode_scan_at_the_benchmark_geometry_keeps_its_instruction_count(
+        benchmark_geometry_programs):
+    """Cell 2's ``jit_decode_scan`` (K = 8) for the described chip: the
+    818 instructions it had before the generator learnt kinds of pool,
+    window rings and a routed tally (ISSUE 33; the parent and the change
+    read alike, as did cell 3's 1,797 at its own widths).  A stack that
+    keeps none of those carries none of them."""
+    lines = [ln for ln in benchmark_geometry_programs["decode_scan"]
+             if " = " in ln]
+    assert len(lines) == 818
+
+
+def test_a_sparse_window_stack_carries_its_kernels_names(named_programs):
+    """A tiny stack of all four kinds of run lowered for the described
+    chip: K pools 256 lanes wide beside V pools of 128; its decode scan
+    calls ``%paged_attention`` once a run's layer scan (4) and
+    ``%expert_ffn`` once a routed run's (3); beside pools and state it
+    returns ONE array, the slots' rows and the routed tally's below
+    them; its admission runs the same expert kernel."""
+    kc, vc, win_k = named_programs["sparse_pools"]
+    assert kc[2:] == (1, 16, 256) and vc[2:] == (1, 16, 128)
+    assert win_k == (3, 9, 2, 16, 256)
+    _, lines = named_programs["sparse_decode_scan"]
+    expert = _metric_args("expert_ffn_roofline")["pattern"]
+    assert len(_matches(r"^%paged_attention[.\d]* = ", lines)) == 4
+    assert len(_matches(expert, lines)) == 3
+    polled = named_programs["sparse_polled"]
+    assert (polled.shape, polled.dtype) == ((8 + 2, 2 + 2), jnp.int32)
+    _, lines = named_programs["sparse_admit_miss"]
+    assert len(_matches(expert, lines)) == 3
