@@ -12,7 +12,8 @@ from deeplearning4j_tpu.kernels.flash_attention import (
     trace_mesh, xla_attention)
 from deeplearning4j_tpu.kernels.paged_attention import (
     pad_head_dim, paged_decode_attention, paged_decode_attention_reference,
-    paged_decode_write_attention, paged_gather, paged_pool_width,
+    paged_decode_write_attention, paged_gather, paged_head_rows,
+    paged_heads_a_row, paged_pool_rows, paged_pool_shape, paged_pool_width,
     paged_route, paged_verify_attention, paged_verify_attention_reference,
     paged_walk_blocks, paged_walk_extent, softmax_with_sink)
 from deeplearning4j_tpu.kernels.ssm_step import (ssm_route, ssm_step,
@@ -22,7 +23,8 @@ __all__ = ["attention", "expert_ffn", "expert_ffn_reference",
            "expert_route", "expert_row_plan", "flash_attention", "mask_to_bias", "pad_head_dim",
            "paged_decode_attention", "paged_decode_attention_reference",
            "paged_decode_write_attention", "paged_gather",
-           "paged_pool_width", "paged_route",
+           "paged_head_rows", "paged_heads_a_row", "paged_pool_rows",
+           "paged_pool_shape", "paged_pool_width", "paged_route",
            "paged_verify_attention",
            "paged_verify_attention_reference", "paged_walk_blocks",
            "paged_walk_extent", "reset_route_log",

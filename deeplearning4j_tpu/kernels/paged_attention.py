@@ -21,7 +21,8 @@ decision at trace time):
   offline-parity invariant survive the paged rewrite.  CPU tier-1
   always routes here.
 * ``_paged_verify_pallas`` — a Pallas TPU kernel, grid (B, max_blocks),
-  W query rows per slot (``_paged_decode_pallas`` is its W == 1 case):
+  W query positions per slot (``_paged_decode_pallas`` is its W == 1
+  case; over a pool of p heads a row, p query rows a position):
   the block table rides as a SCALAR-PREFETCH operand so each K/V block
   DMA is issued straight out of the table entry (no gathered [B, L]
   copy of the pool ever materializes in HBM), with the flash-style
@@ -41,14 +42,21 @@ decision at trace time):
   operands then cost a layout copy of a layer's pool per layer per
   tick).  The kernel route's pool is ``paged_pool_width`` wide: whole
   128-lane rows, for which row-major is also the chip's DEFAULT
-  layout — no copy at any program's entry or exit either.
+  layout — no copy at any program's entry or exit either.  A row holds
+  as many whole K/V heads side by side as fit it (``paged_heads_a_row``,
+  PR 34: two of BERT-large's 64-wide heads, every lane a number; one
+  where a head fills the lanes or keys and values differ in width), so
+  the pool is [L, nb, h / p, bs, width] (``paged_pool_shape``) and the
+  kernel sees ``h / p`` wide "heads" with ``g x p`` query rows each —
+  the same body; the two views ``paged_pool_rows`` / ``paged_head_rows``
+  are how everything else writes and reads such a pool.
 
   Grid ``(B,)``, a slot a step; the pools stay in HBM (``pl.ANY``) and
   the KERNEL fetches: ``make_async_copy`` of one ``(h, bs, width)``
   block per table entry, straight out of ``tbl_ref[b, j]``.  A CHUNK
   is ``paged_walk_blocks``' number of consecutive table entries (128
-  positions a K/V head, 512 where one head is all there is: 8 blocks
-  of 16 tokens under 16 heads, 4 of 128 under one), copied side by
+  positions a pool head, 512 where one head is all there is: 8 blocks
+  of 16 tokens under 8 or 16 heads, 4 of 128 under one), copied side by
   side into one VMEM buffer and accumulated WHOLE by
   one step of the softmax recurrence.  A slot's loop runs over the
   chunks that hold a live block (``pos // bs + 1`` entries) and no
@@ -81,6 +89,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -117,10 +126,12 @@ _WALK_VMEM_BYTES = 8 << 20
 _WALK_BUFFERS = 8
 
 
-def paged_pool_width(dh: int, shard=None) -> int:
-    """The last dimension of a pool whose heads are ``dh`` wide: ``dh``
-    on the reference routes; on the kernel route whole 128-lane rows
-    (a row's first ``dh`` lanes hold it, the rest stay zero).
+def paged_pool_width(dh: int, shard=None, heads: int = 1) -> int:
+    """The last dimension of a pool whose rows hold ``heads`` heads of
+    ``dh`` side by side (``paged_heads_a_row``): ``dh`` on the
+    reference routes, which keep a head a row; on the kernel route
+    whole 128-lane rows (head j of a row in lanes ``j * dh .. (j + 1) *
+    dh``, what is left past the last one stays zero).
 
     The kernel's operands are row-major, and a row-major TPU array
     pads its rows to the lane width anyway — but a TPU's DEFAULT
@@ -136,7 +147,104 @@ def paged_pool_width(dh: int, shard=None) -> int:
     compile cache: PERF.md, PR 26.)"""
     if paged_route(shard) != "pallas":
         return dh
-    return -(-dh // _LANES) * _LANES
+    return -(-heads * dh // _LANES) * _LANES
+
+
+def paged_heads_a_row(h: int, qk_dim: int, v_dim: int, shard=None) -> int:
+    """K/V heads a pool row holds, from the kind's shapes alone: on the
+    kernel route, with keys and values equally wide, as many WHOLE
+    heads as fit the 128 lanes and divide the kind's ``h`` (BERT-large's
+    16 heads of 64: two, every lane a number where a head a row left
+    half of each row zero -- the decode kernel is bound by its copies,
+    PR 30, so half the bytes are half its time); else one.  Keys wider
+    than values would want a map of segments a side, and no served
+    stack has them under 128; the reference routes, a ``tp > 1`` mesh
+    and the CPU keep a head a row, ``dh`` wide.
+
+    Heads side by side need no kernel of their own: a row of ``p``
+    heads is one K/V "head" 128 wide read by ``g x p`` query rows, the
+    row of a query head holding its numbers in its own head's lanes and
+    zeros in the companions' (:func:`_in_own_lanes`), so ``q . K_row``
+    over the lanes is that head's score and ``P . V_row`` carries every
+    companion's values, of which a query row keeps its own segment."""
+    if paged_route(shard) != "pallas" or qk_dim != v_dim:
+        return 1
+    return max((p for p in range(1, h + 1)
+                if h % p == 0 and p * qk_dim <= _LANES), default=1)
+
+
+def paged_pool_shape(h: int, bs: int, qk_dim: int, v_dim: int, shard=None):
+    """The last three dimensions of a kind's K pool and of its V pool,
+    (pool heads, ``bs``, width) each, from its ``h`` K/V heads, its
+    widths and the route: the ONE place a pool's shape is decided."""
+    p = paged_heads_a_row(h, qk_dim, v_dim, shard)
+    return tuple((h // p, bs, paged_pool_width(dim, shard, p))
+                 for dim in (qk_dim, v_dim))
+
+
+def paged_pool_rows(rows, heads: int, width: int):
+    """K/V head rows [..., h, n, dh] as the rows of a pool of ``heads``
+    heads ``width`` wide: [..., heads, n, width], head ``H * p + j`` in
+    lanes ``j * dh ..`` of pool head ``H``'s row (p = h // heads), zeros
+    past the last.  One of the two views every writer and reader of a
+    pool outside the kernel goes through; :func:`paged_head_rows` is
+    the way back."""
+    *lead, h, n, dh = rows.shape
+    p = h // heads
+    if p > 1:
+        rows = jnp.moveaxis(rows.reshape(*lead, heads, p, n, dh), -3, -2) \
+            .reshape(*lead, heads, n, p * dh)
+    return pad_head_dim(rows, width)
+
+
+def paged_head_rows(pool_rows, h: int, dh: int):
+    """:func:`paged_pool_rows` back: pool rows [..., heads, n, width] as
+    the ``h`` K/V heads' own [..., h, n, dh], the lanes past the last
+    head dropped."""
+    *lead, heads, n, width = pool_rows.shape
+    p = h // heads
+    if p * dh != width:
+        pool_rows = pool_rows[..., :p * dh]
+    if p == 1:
+        return pool_rows
+    return jnp.moveaxis(pool_rows.reshape(*lead, heads, n, p, dh), -2, -3) \
+        .reshape(*lead, h, n, dh)
+
+
+def _own_lanes(p: int, d: int):
+    """[p, 1, p * d] bool, a constant of the program: whether a lane of
+    a row of ``p`` heads ``d`` wide lies in head j's segment."""
+    return (np.arange(p * d) // d == np.arange(p)[:, None])[:, None, :]
+
+
+def _in_own_lanes(q, heads: int, p: int):
+    """Query rows [..., hq, dh] laid out against pool rows of ``p``
+    heads: [..., hq, p * dh], a head's numbers in its K/V head's
+    segment of the lanes and zeros in the companions', which so add
+    exact zeros to its scores (query heads ``k * g ..`` read K/V head
+    ``k``, head ``k % p`` of pool head ``k // p``).  Written as the
+    pool head's ``p`` query rows end to end, once a segment, each copy
+    masked to its own: where a query head has a K/V head to itself the
+    rows [.., hq * dh] that the projection made are those rows end to
+    end already, and no number changes lanes."""
+    *lead, hq, dh = q.shape
+    g = hq // (heads * p)
+    ends = jnp.moveaxis(q.reshape(*lead, heads, p, g, dh), -3, -2) \
+        .reshape(*lead, heads, 1, g, p * dh)
+    return jnp.where(_own_lanes(p, dh), ends, 0).reshape(*lead, hq, p * dh)
+
+
+def _own_segment(att, heads: int, p: int):
+    """``_in_own_lanes`` back, of the result: attention rows [..., hq,
+    p * dv] over pool rows of ``p`` heads carry every companion's
+    values; a query head keeps its own K/V head's [..., hq, dv] (a sum
+    of one number and zeros: a select, exact)."""
+    *lead, hq, width = att.shape
+    g, dv = hq // (heads * p), width // p
+    ends = jnp.where(_own_lanes(p, dv),
+                     att.reshape(*lead, heads, p, g, width), 0).sum(-3)
+    return jnp.moveaxis(ends.reshape(*lead, heads, g, p, dv), -3, -2) \
+        .reshape(*lead, hq, dv)
 
 
 def pad_head_dim(rows, width: int):
@@ -199,13 +307,13 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_table,
 
 
 def _paged_decode_pallas(q, k_pool, v_pool, block_table, pos,
-                         scale: float):
+                         scale: float, heads_a_row: int = 1):
     """One query row per slot: the W == 1 case of the verify kernel.
     A ``[h, dh] x [h, bs, dh]`` batched mat-vec has no free lhs
     dimension and Mosaic refuses it; the verify kernel's
     ``[h, W, dh]`` query block gives the product one."""
-    return _paged_verify_pallas(q[:, None], k_pool, v_pool,
-                                block_table, pos, scale)[:, 0]
+    return _paged_verify_pallas(q[:, None], k_pool, v_pool, block_table,
+                                pos, scale, heads_a_row)[:, 0]
 
 
 def paged_verify_attention_reference(q, k_pool, v_pool, block_table,
@@ -262,19 +370,24 @@ def _init_stats(m_ref, l_ref, acc_ref, sink=None):
 
 
 def _accumulate(q, k, v, kb, p0, m_ref, l_ref, acc_ref, *, bs: int,
-                scale: float, one_position: bool = False):
+                scale: float, rows_a_position: int = 1):
     """One K/V block into the running softmax state: q (h, W, dh),
-    k / v (h, bs, dh), query row w at position p0 + w -- or, with
-    ``one_position``, every row at p0: the W rows are then the query
-    heads that share this K/V head, and nothing is masked among them."""
+    k / v (h, bs, dh), query row w at position ``p0 + w //
+    rows_a_position``: a row a position; or the W rows all at p0, the
+    query heads that share this K/V head (a pool row's heads', side by
+    side), nothing masked among them; or ``rows_a_position`` such heads
+    at each of several positions in turn."""
     h, W, _ = q.shape
     dh = v.shape[2]
     s = lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale        # (h, W, bs)
     j = kb * bs + lax.broadcasted_iota(jnp.int32, (h, W, bs), 2)
-    qp = p0 if one_position else (
-        p0 + lax.broadcasted_iota(jnp.int32, (h, W, bs), 1))
+    if rows_a_position == W:
+        qp = p0
+    else:
+        row = lax.broadcasted_iota(jnp.int32, (h, W, bs), 1)
+        qp = p0 + (row if rows_a_position == 1 else row // rows_a_position)
     s = jnp.where(j <= qp, s, _NEG)
     m_prev, l_prev = m_ref[:], l_ref[:]                    # (h, W, 128)
     m_new = jnp.maximum(m_prev,
@@ -301,13 +414,14 @@ def _finish(l_ref, acc_ref, dtype, rows_first: bool = True):
 
 def _verify_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, bs: int, mb: int, W: int,
-                   scale: float):
+                   scale: float, heads_a_row: int = 1):
     """Grid (B, max_blocks), block axis minor/arbitrary: per slot,
     stream the table's K/V blocks through VMEM with the running softmax
-    state in scratch, W query rows per slot — query row w sits at
-    position pos0 + w, so the in-block causal mask compares each key's
-    position against a per-row query position.  Blocks past the
-    DEEPEST query's context skip compute entirely."""
+    state in scratch, W query positions per slot — the query at
+    pos0 + w, so the in-block causal mask compares each key's
+    position against a per-row query position (``heads_a_row`` rows a
+    position over a pool whose rows hold as many heads).  Blocks past
+    the DEEPEST query's context skip compute entirely."""
     b, kb = pl.program_id(0), pl.program_id(1)
 
     @pl.when(kb == 0)
@@ -321,7 +435,8 @@ def _verify_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         # a kernel-route pool's rows are padded to the lane width
         dh = q_ref.shape[3]
         _accumulate(q_ref[0], k_ref[0, :, :, :dh], v_ref[0, :, :, :dh],
-                    kb, p0, m_ref, l_ref, acc_ref, bs=bs, scale=scale)
+                    kb, p0, m_ref, l_ref, acc_ref, bs=bs, scale=scale,
+                    rows_a_position=heads_a_row)
 
     @pl.when(kb == mb - 1)
     def _done():
@@ -491,7 +606,7 @@ def _decode_write_kernel(tbl_ref, pos_ref, lay_ref, wblk_ref, woff_ref,
 
         _accumulate(q_ref[0], kbuf[buf, :, :, :dh], vbuf[buf, :, :, :dv],
                     c, p0, m_ref, l_ref, acc_ref, bs=chunk * bs,
-                    scale=scale, one_position=grouped)
+                    scale=scale, rows_a_position=g)
 
         # the next chunk's fetch may take this buffer
         @pl.when(patch)
@@ -513,14 +628,34 @@ def _decode_write_kernel(tbl_ref, pos_ref, lay_ref, wblk_ref, woff_ref,
 # at flash_attention._flash_fwd
 @jax.named_scope("paged_read")
 def _paged_verify_pallas(q, k_pool, v_pool, block_table, pos0,
-                         scale: float):
-    B, W, h, dh = q.shape
-    bs, width = k_pool.shape[2], k_pool.shape[3]
-    if v_pool.shape[3] != width:
+                         scale: float, heads_a_row: int = 1):
+    """q [B, W, hq, dh] over pools [n_blocks, h, bs, width] of
+    ``heads_a_row`` heads a row (``hq == h * heads_a_row``: a query
+    head a K/V head).  Over a pool of several heads a row the kernel
+    reads W x heads_a_row query rows a pool head, each in its own
+    head's lanes, position by position, and every query head keeps its
+    own segment of what comes back (``paged_heads_a_row``)."""
+    if v_pool.shape[3] != k_pool.shape[3]:
         raise ValueError(
             "keys and values of different widths read the pool through "
             "paged_decode_write_attention or the reference route; the "
             "verify / read-only kernel walks pools of one width")
+    p, heads = heads_a_row, k_pool.shape[1]
+    if p > 1:
+        B, W, hq, dh = q.shape
+        # row w * p + j of pool head H: query head H * p + j at pos0 + w
+        rows = _in_own_lanes(q, heads, p).reshape(B, W, heads, p, p * dh) \
+            .transpose(0, 1, 3, 2, 4).reshape(B, W * p, heads, p * dh)
+        out = _verify_call(rows, k_pool, v_pool, block_table, pos0, scale, p)
+        out = out.reshape(B, W, p, heads, p * dh).transpose(0, 1, 3, 2, 4)
+        return _own_segment(out.reshape(B, W, hq, p * dh), heads, p)
+    return _verify_call(q, k_pool, v_pool, block_table, pos0, scale, 1)
+
+
+def _verify_call(q, k_pool, v_pool, block_table, pos0, scale: float,
+                 heads_a_row: int):
+    B, W, h, dh = q.shape
+    bs, width = k_pool.shape[2], k_pool.shape[3]
     mb = block_table.shape[1]
     qh = q.transpose(0, 2, 1, 3)               # (B, h, W, dh)
     kv_spec = pl.BlockSpec(
@@ -543,8 +678,9 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_table, pos0,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_verify_kernel, bs=bs, mb=mb, W=W,
-                          scale=scale),
+        functools.partial(_verify_kernel, bs=bs, mb=mb,
+                          W=W // heads_a_row, scale=scale,
+                          heads_a_row=heads_a_row),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, W, h, dh), q.dtype),
         compiler_params=_dimsem("parallel", "arbitrary"),
@@ -670,7 +806,9 @@ def paged_verify_attention(q, k_pool, v_pool, block_table, pos0,
     tick writes-then-reads its single row).
 
     ``q`` [B, W, h, dh]; pools / table / scale as
-    :func:`paged_decode_attention`; ``pos0`` [B] int32.  Routes to the
+    :func:`paged_decode_attention` (a kernel-route pool of fewer heads
+    than ``q`` holds ``paged_heads_a_row`` of them a row); ``pos0`` [B]
+    int32.  Routes to the
     multi-query Pallas kernel on TPU, else to the per-row-unrolled
     reference — the byte-parity path the speculative greedy-parity
     tests pin (CPU tier-1 always exercises it).  ``shard`` (a
@@ -683,7 +821,8 @@ def paged_verify_attention(q, k_pool, v_pool, block_table, pos0,
     if route == "pallas":
         _ROUTE_PALLAS.inc()
         return _paged_verify_pallas(q, k_pool, v_pool, block_table,
-                                    pos0, float(scale))
+                                    pos0, float(scale),
+                                    q.shape[2] // k_pool.shape[1])
     (_ROUTE_REFERENCE_TP if route == "reference_tp"
      else _ROUTE_REFERENCE).inc()
     return paged_verify_attention_reference(q, k_pool, v_pool,
@@ -716,7 +855,7 @@ def paged_route(shard=None) -> str:
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, pos,
                            scale: Optional[float] = None, shard=None,
-                           sink=None):
+                           sink=None, kv_heads: Optional[int] = None):
     """softmax(q . K_table^T) V_table for ONE query token per slot.
 
     ``q`` [B, h, dh] — the just-written token's query per slot;
@@ -729,12 +868,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos,
     reference path (see :func:`paged_verify_attention`).  ``sink`` [h]
     (a logit a query head in the softmax's denominator) and values
     narrower or wider than the keys are the reference route's and the
-    decode scan's kernel's."""
+    decode scan's kernel's.  ``kv_heads`` (default: the pool's head
+    axis) says how many K/V heads the pool holds where its rows hold
+    several (``paged_heads_a_row``): the shapes alone cannot tell such
+    a pool from grouped query heads."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     route = paged_route(shard)
     if route == "pallas":
-        if q.shape[1] != k_pool.shape[1] or sink is not None:
+        kv_heads = kv_heads or k_pool.shape[1]
+        if q.shape[1] != kv_heads or sink is not None:
             raise ValueError(
                 "grouped query heads and a sink read the pool through "
                 "paged_decode_write_attention (the decode scan's kernel) "
@@ -742,7 +885,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos,
                 "query head a K/V head and knows no sink")
         _ROUTE_PALLAS.inc()
         return _paged_decode_pallas(q, k_pool, v_pool, block_table,
-                                    pos, float(scale))
+                                    pos, float(scale),
+                                    kv_heads // k_pool.shape[1])
     (_ROUTE_REFERENCE_TP if route == "reference_tp"
      else _ROUTE_REFERENCE).inc()
     return paged_decode_attention_reference(q, k_pool, v_pool,
@@ -756,10 +900,17 @@ def paged_decode_write_attention(q, k_new, v_new, k_pool, v_pool,
     """The kernel route's decode tick for ONE layer of the WHOLE pool:
     write each slot's new row ``k_new`` / ``v_new`` [B, h, dh] at
     (``layer``, ``wblk``, :, ``woff``) of ``k_pool`` / ``v_pool``
-    [L, n_blocks, h, block_size, paged_pool_width(dh)], then attend as
-    :func:`paged_decode_attention` does over positions <= ``pos`` —
-    both inside the kernel, the pools aliased through it.  Returns
-    (att [B, h, dh], k_pool, v_pool).
+    [L, n_blocks, h / p, block_size, paged_pool_width(dh, heads=p)],
+    then attend as :func:`paged_decode_attention` does over positions
+    <= ``pos`` — both inside the kernel, the pools aliased through it.
+    Returns (att [B, h, dh], k_pool, v_pool).
+
+    ``p``, the heads a pool row holds (``paged_heads_a_row``), is read
+    off the operands: the new rows' heads over the pool's.  Where it is
+    more than one the SAME kernel runs over the pool's wider "heads":
+    the query rows go in each in its own head's lanes, the new rows
+    laid end to end, and each query head keeps its own segment of the
+    result (two small fusions of XLA's around the call).
 
     ``wblk`` is an operand and not ``table[b, pos // bs]``: a slot
     retired mid-scan keeps a live table and is sent to the scratch
@@ -775,6 +926,12 @@ def paged_decode_write_attention(q, k_new, v_new, k_pool, v_pool,
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     _ROUTE_PALLAS_WRITE.inc()
-    return _paged_decode_write_pallas(q, k_new, v_new, k_pool, v_pool,
-                                      block_table, pos, wblk, woff,
-                                      layer, float(scale), sink)
+    B, heads = q.shape[0], k_pool.shape[2]
+    p = k_new.shape[1] // heads
+    if p > 1:
+        q = _in_own_lanes(q, heads, p)
+        k_new, v_new = k_new.reshape(B, heads, -1), v_new.reshape(B, heads, -1)
+    att, k_pool, v_pool = _paged_decode_write_pallas(
+        q, k_new, v_new, k_pool, v_pool, block_table, pos, wblk, woff,
+        layer, float(scale), sink)
+    return (att if p == 1 else _own_segment(att, heads, p)), k_pool, v_pool

@@ -213,12 +213,17 @@ def _scatter_then_read(kv, layer, wblk, woff, k, v, read):
     """XLA's write of the rows k / v into layer ``layer`` of the pools
     ``kv`` at (``wblk``, ``woff``), then ``read(k_layer, v_layer)`` of
     the written layer: where the kernel does not write the pool itself.
-    A kernel-route pool's rows are wider than ``head_dim``, zero past
-    it.  Returns (what ``read`` gave, kv)."""
-    from deeplearning4j_tpu.kernels import pad_head_dim
+    The rows [..., h, dh] go in as the pool holds them
+    (``kernels.paged_pool_rows``: a kernel-route pool's rows are whole
+    lanes, several heads side by side where they fit).  Returns (what
+    ``read`` gave, kv)."""
+    from deeplearning4j_tpu.kernels import paged_pool_rows
     kc, vc = kv
-    put = lambda c, rows: _layer_of(c, layer).at[wblk, :, woff, :].set(
-        pad_head_dim(rows, c.shape[-1]))
+
+    def put(c, rows):
+        row = paged_pool_rows(rows[..., None, :], c.shape[2], c.shape[4])
+        return _layer_of(c, layer).at[wblk, :, woff, :].set(row[..., 0, :])
+
     kl, vl = put(kc, k), put(vc, v)
     return read(kl, vl), (_with_layer(kc, kl, layer),
                           _with_layer(vc, vl, layer))
@@ -372,9 +377,9 @@ class TransformerGenerator:
         """The per-row state of ``b`` rows that have seen nothing (None:
         the stack keeps none).  With ``block_size`` the window rings are
         a server's: paged pools [win_layers, b * window_blocks + 1,
-        kv_heads, block_size, pool width], block 0 the scratch sink,
+        *kernels.paged_pool_shape], block 0 the scratch sink,
         row b's ring in blocks ``1 + b * window_blocks ..``, for life."""
-        from deeplearning4j_tpu.kernels import paged_pool_width
+        from deeplearning4j_tpu.kernels import paged_pool_shape
         out, r = {}, self._rec
         if r is not None:
             layers = (sum(self._layers) - self.kv_layers
@@ -385,11 +390,14 @@ class TransformerGenerator:
                                     self.compute_dtype)
         if self._win is not None:
             hkv, dk, dv, window = _kind(self._win)
-            for name, dim in (("win_k", dk), ("win_v", dv)):
-                shape = ((b, hkv, window, dim) if block_size is None else
-                         (b * self.window_blocks(block_size) + 1, hkv,
-                          block_size, paged_pool_width(dim, shard)))
-                out[name] = jnp.zeros((self.win_layers,) + shape,
+            if block_size is None:
+                tails = ((b, hkv, window, dk), (b, hkv, window, dv))
+            else:
+                tails = ((b * self.window_blocks(block_size) + 1,) + tail
+                         for tail in paged_pool_shape(hkv, block_size, dk,
+                                                      dv, shard))
+            for name, tail in zip(("win_k", "win_v"), tails):
+                out[name] = jnp.zeros((self.win_layers,) + tail,
                                       self.compute_dtype)
         if self._held is not None:
             out["routed"] = jnp.zeros((self._held + 1,), jnp.int32)
@@ -553,8 +561,9 @@ class TransformerGenerator:
         a layout copy and a write-back of a layer's pool per layer per
         tick (79% of the device's time before PR 26).  Otherwise the
         layer's pool is sliced, scattered into and put back.  A
-        kernel-route pool's rows are ``kernels.paged_pool_width(dh)``
-        wide, zero past ``head_dim``.
+        kernel-route pool's rows are whole 128-lane rows
+        (``kernels.paged_pool_shape``): as many heads side by side as
+        fit, zero past the last.
 
         ``shard`` (TpShardCtx) makes this the mesh-sharded tick: embeds
         replicate, block math shards heads / columns along ``tp`` with
@@ -589,7 +598,7 @@ class TransformerGenerator:
                         kv, layer, wblk, woff, k, v,
                         lambda kl, vl: paged_decode_attention(
                             q, kl, vl, table, pos, scale=scale,
-                            shard=shard, sink=sink))
+                            shard=shard, sink=sink, kv_heads=k.shape[1]))
                 return attend
             return attend_at
 

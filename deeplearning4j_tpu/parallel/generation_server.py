@@ -195,9 +195,9 @@ from deeplearning4j_tpu.analysis import sanitize as _sanitize
 #: allocator spill/fetch and watchdog transitions land in the
 #: black-box ring a postmortem bundle freezes
 _FLIGHT = telemetry.get_flight_recorder()
-from deeplearning4j_tpu.kernels import (pad_head_dim, paged_pool_width,
-                                        paged_route, paged_walk_blocks,
-                                        paged_walk_extent)
+from deeplearning4j_tpu.kernels import (paged_head_rows, paged_pool_rows,
+                                        paged_pool_shape, paged_route,
+                                        paged_walk_blocks, paged_walk_extent)
 from deeplearning4j_tpu.models.generation import (TransformerGenerator,
                                                   _cast_floating,
                                                   _filter_logits_rows,
@@ -280,6 +280,18 @@ _PAGED_BLOCKS = telemetry.counter(
     labelnames=("kind",))
 _PAGED_LIVE = _PAGED_BLOCKS.labels(kind="live")
 _PAGED_DEAD = _PAGED_BLOCKS.labels(kind="dead")
+# ... and how much of what those blocks hold is numbers: a kernel-route
+# pool's rows are whole 128-lane rows (kernels.paged_pool_shape), as
+# many heads side by side as fit and zeros in what is left
+_PAGED_LANE_BYTES = telemetry.counter(
+    "generation_server_paged_lane_bytes_total",
+    "bytes of the blocks the decode scans' reads covered (the entries "
+    "generation_server_paged_blocks_total counts, every layer's K and "
+    "V), by kind (kv: in lanes that hold a head's numbers; pad: in "
+    "lanes that pad a pool row to the lane width)",
+    labelnames=("kind",))
+_PAGED_LANES_KV = _PAGED_LANE_BYTES.labels(kind="kv")
+_PAGED_LANES_PAD = _PAGED_LANE_BYTES.labels(kind="pad")
 # the scheduler thread's own time between decode scans — while it
 # runs the device has nothing queued.  NOT the dispatch -> poll wait
 # (serve/tick) and NOT the blocked-on-an-empty-queue wait (serve/idle).
@@ -595,6 +607,17 @@ def _paged_blocks_walked(pos0, ticks, bs: int, chunk: int, last=None):
     live, covered = paged_walk_extent(pos, bs, chunk)
     ran = tick < ticks[:, None]
     return int(live[ran].sum()), int((covered - live)[ran].sum())
+
+
+def _lane_bytes_a_block(layers: int, h: int, dims, tails, dtype):
+    """(kv, pad) bytes of ONE block-table entry's block over a kind's
+    ``layers``: its ``h`` heads' numbers (``dims``: the keys' and the
+    values' widths), and the rest of the K and V pool rows that hold
+    them (``tails``: ``kernels.paged_pool_shape``)."""
+    bs = tails[0][1]
+    size = layers * bs * jnp.dtype(dtype).itemsize
+    kv = size * h * sum(dims)
+    return kv, size * sum(heads * width for heads, _, width in tails) - kv
 
 
 def _kill_slots(state, mask):
@@ -1135,19 +1158,21 @@ class GenerationServer:
         cd = gen.compute_dtype
         nb = self.kv_blocks + 1      # + block 0, the never-read
                                      # scratch sink for masked writes
-        # a pool's rows are as wide as the route reads them: the keys'
-        # or the values' own width, or whole 128-lane rows on the
-        # kernel route
-        shape = lambda dim: (n_layers, nb, h, self.block_size,
-                             paged_pool_width(dim, self._shard))
-        kc, vc = jnp.zeros(shape(gen.qk_dim), cd), \
-            jnp.zeros(shape(gen.v_dim), cd)
+        # a pool's rows are as the route reads them: a head a row, the
+        # keys' or the values' own width; or, on the kernel route, whole
+        # 128-lane rows of as many heads side by side as fit
+        tails = paged_pool_shape(h, self.block_size, gen.qk_dim, gen.v_dim,
+                                 self._shard)
+        kc, vc = (jnp.zeros((n_layers, nb) + tail, cd) for tail in tails)
+        self._lane_bytes = _lane_bytes_a_block(
+            n_layers, h, (gen.qk_dim, gen.v_dim), tails, cd)
         # table entries a decode read covers at a time: the kernel's
-        # chunk, or the whole table where the reference gathers it
+        # chunk (of the POOL's heads, as the kernel sees them), or the
+        # whole table where the reference gathers it
         on_kernel = paged_route(self._shard) == "pallas"
         self._walk_chunk = (
-            paged_walk_blocks(self.block_size, h, kc.shape[-1], cd,
-                              self.max_blocks, vc.shape[-1])[0]
+            paged_walk_blocks(self.block_size, kc.shape[2], kc.shape[-1],
+                              cd, self.max_blocks, vc.shape[-1])[0]
             if on_kernel else self.max_blocks)
         # a window kind's table is its window's blocks and no more
         self._win_walk = None
@@ -1160,10 +1185,14 @@ class GenerationServer:
                     f"{self.block_size}: the decode kernel patches a "
                     "slot's LAST live block, so on the kernel route a "
                     "window fits one block (block_size >= window)")
-            self._win_walk = (window, (paged_walk_blocks(
-                self.block_size, wh, paged_pool_width(wk, self._shard), cd,
-                wb, paged_pool_width(wv, self._shard))[0]
-                if on_kernel else wb))
+            tails = paged_pool_shape(wh, self.block_size, wk, wv,
+                                     self._shard)
+            (heads, _, k_width), (_, _, v_width) = tails
+            self._win_walk = (
+                window,
+                (paged_walk_blocks(self.block_size, heads, k_width, cd, wb,
+                                   v_width)[0] if on_kernel else wb),
+                _lane_bytes_a_block(gen.win_layers, wh, (wk, wv), tails, cd))
         if self._shard is not None:
             # pool HEADS shard along tp (each chip holds its head
             # slice of every block); the block axis stays GLOBAL —
@@ -1457,8 +1486,10 @@ class GenerationServer:
                     if entry is not None and entry[1] == tok:
                         blk = entry[0]
                         try:
-                            k = self._block_to_host(kc, blk)
-                            v = self._block_to_host(vc, blk)
+                            k = self._block_to_host(kc, blk,
+                                                    self._gen.qk_dim)
+                            v = self._block_to_host(vc, blk,
+                                                    self._gen.v_dim)
                         except (RuntimeError, ValueError):
                             # donated mid-read (jax raises ValueError
                             # for deleted/donated buffers on some
@@ -1667,8 +1698,8 @@ class GenerationServer:
         # in that tier regardless — fetch never removes them)
         if self._tier is not None and self.host_tier_blocks:
             try:
-                k = self._block_to_host(self._kc, blk)
-                v = self._block_to_host(self._vc, blk)
+                k = self._block_to_host(self._kc, blk, self._gen.qk_dim)
+                v = self._block_to_host(self._vc, blk, self._gen.v_dim)
             except (RuntimeError, ValueError):
                 k = None                 # consumed donated buffer
                                          # (recovery in flight): the
@@ -2465,28 +2496,26 @@ class GenerationServer:
         blocks = rows[:, 0].reshape(nl, h, T // bs, bs, dh) \
                            .transpose(0, 2, 1, 3, 4)
         return pool.at[:nl, phys].set(
-            pad_head_dim(blocks, pool.shape[-1]))
+            paged_pool_rows(blocks, pool.shape[2], pool.shape[-1]))
 
-    @property
-    def _head_dim(self) -> int:
-        return self._gen.head_dim
-
-    def _block_to_host(self, pool, blk: int):
+    def _block_to_host(self, pool, blk: int, dim: int):
         """Pool block ``blk`` of every layer as host bytes
-        [layers, h, block_size, dh] — what the host tier and a prefix
-        handoff carry, whatever the pool's width here or there."""
-        return np.asarray(pool[:, blk, :, :, :self._head_dim])
+        [layers, h, block_size, dim] — what the host tier and a prefix
+        handoff carry, whatever the pool's rows hold here or there
+        (``dim``: the keys' width for the K pool, the values' for V)."""
+        return np.asarray(paged_head_rows(pool[:, blk], self._gen.kv_heads,
+                                          dim))
 
-    def _gather_rows(self, pool, phys):
+    def _gather_rows(self, pool, phys, dim: int):
         """``_scatter_rows`` back: the pool blocks ``phys`` [n] of
         every layer of ``pool`` as K/V rows [layers, 1, h,
-        n * block_size, dh] (a kernel-route pool's lane padding
-        dropped)."""
-        nl, _, h, bs, width = pool.shape
-        rows = jnp.take(pool, phys, axis=1).transpose(0, 2, 1, 3, 4) \
-            .reshape(nl, 1, h, phys.shape[0] * bs, width)
-        dh = self._head_dim
-        return rows if width == dh else rows[..., :dh]
+        n * block_size, dim], each head's own (``dim`` as
+        ``_block_to_host`` takes it)."""
+        nl, _, _, bs, _ = pool.shape
+        h = self._gen.kv_heads
+        blocks = paged_head_rows(jnp.take(pool, phys, axis=1), h, dim)
+        return blocks.transpose(0, 2, 1, 3, 4) \
+            .reshape(nl, 1, h, phys.shape[0] * bs, dim)
 
     def _arm_slot(self, state, logits, slot, t0, n_new, eos_id, key,
                   temp, tk, tp, table_row, dtable_row, rec=None):
@@ -2518,9 +2547,9 @@ class GenerationServer:
     def _arm_leaf(self, name: str, all_rows, row, slot):
         """One leaf of the generator's ``rec`` with the admitted row's
         in place: a recurrent leaf's row ``slot``; a window ring [layers,
-        1, kv heads, window, dim] cut into the slot's own blocks (a
-        kernel-route pool's rows zero past ``dim``); the routed tally,
-        which the prefill adds to."""
+        1, kv heads, window, dim] cut into the slot's own blocks, as
+        the ring's pool holds rows; the routed tally, which the prefill
+        adds to."""
         if name == "routed":
             return all_rows + row
         if name in self._WIN_KEYS:
@@ -2529,9 +2558,9 @@ class GenerationServer:
             wb = self._gen.window_blocks(bs)
             ring = jnp.pad(row[:, 0], ((0, 0), (0, 0),
                                        (0, wb * bs - window), (0, 0)))
-            blocks = pad_head_dim(
+            blocks = paged_pool_rows(
                 ring.reshape(layers, h, wb, bs, -1).transpose(0, 2, 1, 3, 4),
-                all_rows.shape[-1])
+                all_rows.shape[2], all_rows.shape[-1])
             return jax.lax.dynamic_update_slice(
                 all_rows, blocks.astype(all_rows.dtype),
                 (0, 1 + slot * wb, 0, 0, 0))
@@ -2662,13 +2691,13 @@ class GenerationServer:
                 fill_k, fill_v = extra_ops[:2]
                 draft_params = extra_ops[2:]
                 kc = kc.at[:, fill_ids].set(
-                    pad_head_dim(fill_k, kc.shape[-1]))
+                    paged_pool_rows(fill_k, kc.shape[2], kc.shape[-1]))
                 vc = vc.at[:, fill_ids].set(
-                    pad_head_dim(fill_v, vc.shape[-1]))
+                    paged_pool_rows(fill_v, vc.shape[2], vc.shape[-1]))
             else:
                 draft_params = extra_ops
-            pk = self._gather_rows(kc, prefix_phys)
-            pv = self._gather_rows(vc, prefix_phys)
+            pk = self._gather_rows(kc, prefix_phys, gen.qk_dim)
+            pv = self._gather_rows(vc, prefix_phys, gen.v_dim)
             logits, ks, vs, rec = gen._prefill_rows_chunked(
                 emb_p, blk_stack, head_p, suffix, pk, pv,
                 jnp.int32(p0), t0 - p0 - 1, shard=shard)
@@ -2683,8 +2712,10 @@ class GenerationServer:
                     # the pool's first d layers, chunk-prefill only
                     # the draft suffix (logits discarded — rounds
                     # re-feed from the anchor)
-                    dpk = self._gather_rows(kc[:dl], dprefix_phys)
-                    dpv = self._gather_rows(vc[:dl], dprefix_phys)
+                    dpk = self._gather_rows(kc[:dl], dprefix_phys,
+                                            gen.qk_dim)
+                    dpv = self._gather_rows(vc[:dl], dprefix_phys,
+                                            gen.v_dim)
                     dp0 = dm * bs
                     _, dks, dvs, _ = spec.draft.gen._prefill_rows_chunked(
                         demb_p, dblk, dhead_p, dtokens[None], dpk, dpv,
@@ -3592,16 +3623,21 @@ class GenerationServer:
                     n_live, n_dead = _paged_blocks_walked(
                         pos_h, emit_h[slots_h], self.block_size,
                         self._walk_chunk)
+                    walked = [(n_live + n_dead, self._lane_bytes)]
                     if self._win_walk is not None:
                         # the window kind's table too: a ring's one
                         # block is live from the first token
-                        window, chunk = self._win_walk
+                        window, chunk, lane_bytes = self._win_walk
                         w_live, w_dead = _paged_blocks_walked(
                             pos_h, emit_h[slots_h], self.block_size, chunk,
                             last=window - 1)
                         n_live, n_dead = n_live + w_live, n_dead + w_dead
+                        walked.append((w_live + w_dead, lane_bytes))
                     _PAGED_LIVE.inc(n_live)
                     _PAGED_DEAD.inc(n_dead)
+                    _PAGED_LANES_KV.inc(sum(n * kv for n, (kv, _) in walked))
+                    _PAGED_LANES_PAD.inc(
+                        sum(n * pad for n, (_, pad) in walked))
                     if routed_h is not None:
                         self._count_routed(routed_h, k)
                 _TOKENS_EMITTED.inc(int(emit_h.sum()))

@@ -109,6 +109,30 @@ def test_paged_kernels_compile_for_v5e(one_chip, chip_paths, entry, h,
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("entry", ["decode", "verify"])
+def test_paged_read_kernels_compile_over_two_heads_a_row(one_chip, chip_paths,
+                                                         entry, dtype):
+    """The same server geometry at GPT-2's 12 heads of 64 as a
+    kernel-route pool holds them since ISSUE 34, two a 128-lane row (6
+    pool heads): the scatter-then-read kernels take 2 query rows a
+    position a pool head, whose positions the mask divides out."""
+    B, bs, mb, nb, W, h, dh, p = 8, 16, 128, 1025, 5, 12, 64, 2
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = S((nb, h // p, bs, p * dh), dtype)
+    q = S((B, h, dh) if entry == "decode" else (B, W, h, dh), dtype)
+    fn = (paged_mod._paged_decode_pallas if entry == "decode"
+          else paged_mod._paged_verify_pallas)
+    text = _compiled_text(
+        lambda q, k, v, t, pos: fn(q, k, v, t, pos, dh ** -0.5, p),
+        q, pool, pool, S((B, mb), jnp.int32), S((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def _attention_fn(causal, backward):
     """attention() as TransformerEncoderBlock calls it ([b, t, h, d]
     operands), forward or forward + all three cotangents."""
@@ -557,15 +581,21 @@ def test_decode_scan_at_the_benchmark_geometry_holds_one_pool_layout(
         benchmark_geometry_programs):
     """A 24 x 2049 x 16 x 16 x 64 array's default layout on the chip
     is NOT the kernel's (dh = 64 < 128 lanes: the block axis goes
-    minor), so the kernel route's pool is 128 wide: no ``copy`` at
+    minor), so the kernel route's pool is 128 wide -- two of the 16
+    heads side by side in a row, 8 pool heads (ISSUE 34): no ``copy`` at
     entry or exit, nothing pool-shaped produced in between."""
     lines = benchmark_geometry_programs["decode_scan"]
     pool = benchmark_geometry_programs["pool"]
-    assert pool == (24, 2049, 16, 16, 128)
+    assert pool == (24, 2049, 8, 16, 128)
     assert _pool_producers(lines, pool) == []
     # parameter, loops, kernel and result in ONE layout
-    assert set(re.findall(r"bf16\[24,2049,16,16,128\]\{([\d,]+):T",
+    assert set(re.findall(r"bf16\[24,2049,8,16,128\]\{([\d,]+):T",
                           "\n".join(lines))) == {"4,3,2,1,0"}
+    # the kernel takes both pools whole, as 8 wide heads a block, and
+    # two query rows a pool head; its result is 128 wide a query head
+    kernel, = _matches(r"^%paged_attention[.\d]* = .*custom-call\(", lines)
+    assert kernel.count("bf16[393408,16,128]") >= 2
+    assert "bf16[64,8,2,128]" in "\n".join(lines)
     # the two name-keyed metrics find the kernel in what the benchmark
     # keeps of its name, at the cell's own shapes
     for name in ("paged_attention_roofline", "decode_scan_tick_device_ms"):
@@ -725,24 +755,36 @@ def test_expert_ffn_compiles_at_the_sparse_window_cell_s_geometry(
     assert produced == []
 
 
-#: the decode kernel's Mosaic body at the accepted cells' geometries, as
-#: the parent of ISSUE 33 lowered it: (operations, sha256 of their text
-#: without source locations).  A new jax may move both together; a
-#: change to the kernel that moves them alone moved cells 2 and 3.
+#: the decode kernel's Mosaic body at the accepted cells' geometries:
+#: (operations, sha256 of their text without source locations).  Cell 3
+#: as the parent of ISSUE 33 lowered it and both of cell 4's kinds as
+#: the parent of ISSUE 34 did, all three unmoved by ISSUE 34; cell 2 as
+#: ISSUE 34 made it, the kernel handed a pool of two heads a row: 8
+#: pool heads 128 wide, 2 query rows on each.  A new jax may move
+#: operations and hash together; a change to the kernel that moves
+#: them alone moved those cells.
 _KERNEL_BODIES = {
     "bert-large-causal.closed-decode": (
-        (64, 16, 16, 64, 64, 16, 32, 24, 2049, False),
-        698, "255a214b93185b54"),
+        (64, 16, 8, 128, 128, 16, 32, 24, 2049, False),
+        699, "9f6017e060606b0d"),
     "jamba2-3b.closed-reasoning": (
         (256, 20, 1, 128, 128, 128, 8, 2, 2049, False),
-        700, "629d03e37915c0f8")}
+        700, "629d03e37915c0f8"),
+    "mimo-v2-flash.closed-long-reasoning/full": (
+        (256, 64, 4, 192, 128, 128, 16, 2, 4097, False),
+        704, "044cb7d6437fbd46"),
+    "mimo-v2-flash.closed-long-reasoning/window": (
+        (256, 64, 8, 192, 128, 128, 1, 5, 257, True),
+        714, "dfd8036e49fa9a28")}
 
 
 @pytest.mark.parametrize("cell", sorted(_KERNEL_BODIES))
 def test_equal_widths_and_no_sink_lower_the_kernel_the_accepted_cells_had(
         one_chip, chip_paths, cell):
-    """With keys as wide as values and no sink the decode kernel is the
-    program it was before it learnt either: operation for operation."""
+    """The decode kernel is the program it was, operation for
+    operation: with keys as wide as values and no sink, before it
+    learnt either; at every geometry, before pools held several heads a
+    row (which is the caller's layout, not the kernel's)."""
     import base64
     import hashlib
     from jax._src.interpreters import mlir as jax_mlir
@@ -763,14 +805,21 @@ def test_equal_widths_and_no_sink_lower_the_kernel_the_accepted_cells_had(
 
 def test_decode_scan_at_the_benchmark_geometry_keeps_its_instruction_count(
         benchmark_geometry_programs):
-    """Cell 2's ``jit_decode_scan`` (K = 8) for the described chip: the
-    818 instructions it had before the generator learnt kinds of pool,
-    window rings and a routed tally (ISSUE 33; the parent and the change
-    read alike, as did cell 3's 1,797 at its own widths).  A stack that
-    keeps none of those carries none of them."""
+    """Cell 2's ``jit_decode_scan`` (K = 8) for the described chip: 833
+    instructions.  It had 818 before the pool held two heads a row, and
+    through ISSUE 33 (the generator learnt kinds of pool, window rings
+    and a routed tally there; a stack that keeps none of those carries
+    none of them).  ISSUE 34's 15, all around the kernel's call in the
+    layer scan's body: the query rows' select against the lanes'
+    constant mask and the result's select + sum over a pool head's two
+    rows, with their operands (+2 select, +1 reduce, +1 add, +5
+    broadcast, +1 constant, +4 parameter, +3 get-tuple-element, +2
+    bitcast), less what a head a row cost (-2 pad of the new rows to
+    the lane width, -2 reshape: q and the result no longer change
+    lanes on their way between [64, 1024] and the kernel)."""
     lines = [ln for ln in benchmark_geometry_programs["decode_scan"]
              if " = " in ln]
-    assert len(lines) == 818
+    assert len(lines) == 833
 
 
 def test_a_sparse_window_stack_carries_its_kernels_names(named_programs):

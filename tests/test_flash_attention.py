@@ -582,6 +582,164 @@ def test_paged_write_kernel_at_served_widths_bf16(width):
                     dtype=jnp.bfloat16, width=width), layer=1, atol=2e-2)
 
 
+# -- several heads a pool row (ISSUE 34) ---------------------------------
+# (K/V heads, dh, query heads a K/V head) -> heads a row by the rule:
+# as many whole heads as fit the 128 lanes and divide the kind's
+_PACKED = {
+    "two_a_row": (4, 64, 1, 2),
+    "two_a_row_grouped": (4, 64, 2, 2),
+    "four_a_row": (8, 32, 1, 4),
+    "three_a_row_and_padding": (3, 40, 1, 3),
+    "one_a_row": (3, 64, 1, 1),
+}
+_PACKED_SLOTS = [(9, "live"), (0, "free"), (14, "live"), (0, "retired"),
+                 (4, "live")]
+
+
+def _packed(pool, heads):
+    from deeplearning4j_tpu.kernels import paged_pool_rows
+    return paged_pool_rows(pool, heads, 128)
+
+
+@pytest.mark.parametrize("case", sorted(_PACKED))
+def test_paged_write_kernel_on_a_pool_of_several_heads_a_row(case):
+    """``paged_decode_write_attention`` over a pool whose rows hold p
+    K/V heads side by side against scatter + the gather reference over
+    the heads' own view: the output to 1e-5; the written row in its own
+    head's lanes of the right layer and block; the companions' lanes,
+    the padding past the last head and every other layer bit for bit; a
+    free and a retired slot (``wblk == 0``) writing nothing."""
+    from deeplearning4j_tpu.kernels import (paged_decode_write_attention,
+                                            paged_head_rows)
+    from deeplearning4j_tpu.kernels.paged_attention import (
+        paged_decode_attention_reference)
+    h, dh, g, p = _PACKED[case]
+    q, kn, vn, kp, vp, tbl, pos, wblk, woff, live = _write_case(
+        _PACKED_SLOTS, L=3, h=h, dh=dh, g=g, seed=34)
+    layer, scale = 1, 1.0 / dh ** 0.5
+    out, ko, vo = paged_decode_write_attention(
+        q, kn, vn, _packed(kp, h // p), _packed(vp, h // p), tbl, pos, wblk,
+        woff, layer, scale)
+    assert ko.shape == vo.shape == kp.shape[:2] + (h // p, kp.shape[3], 128)
+    kr = kp.at[layer, wblk, :, woff, :].set(kn)
+    vr = vp.at[layer, wblk, :, woff, :].set(vn)
+    ref = paged_decode_attention_reference(q, kr[layer], vr[layer], tbl, pos,
+                                           scale)
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               atol=1e-5)
+    for got, want in ((ko, kr), (vo, vr)):
+        # off the scratch block: the heads' own view, then the rows as
+        # the pool holds them (the padding lanes still zero)
+        np.testing.assert_array_equal(
+            np.asarray(paged_head_rows(got, h, dh)[:, 1:]),
+            np.asarray(want[:, 1:]))
+        np.testing.assert_array_equal(
+            np.asarray(got[:, 1:]), np.asarray(_packed(want, h // p)[:, 1:]))
+    # a written row differs from what lay there, in its own lanes only
+    s, j = 0, 1                                   # slot 0, K/V head 1
+    lanes = slice((j % p) * dh, (j % p + 1) * dh)
+    row = np.asarray(ko[layer, wblk[s], j // p, woff[s]])
+    was = np.asarray(_packed(kp, h // p)[layer, wblk[s], j // p, woff[s]])
+    np.testing.assert_array_equal(row[lanes], np.asarray(kn[s, j]))
+    assert not np.array_equal(row[lanes], was[lanes])
+
+
+@pytest.mark.parametrize("W", [1, 3], ids=["decode", "verify"])
+@pytest.mark.parametrize("case", ["two_a_row", "four_a_row",
+                                  "three_a_row_and_padding"])
+def test_paged_read_kernels_on_a_pool_of_several_heads_a_row(case, W):
+    """The scatter-then-read kernels (the speculative programs' on the
+    kernel route) over such a pool: W query positions a slot, p rows a
+    position a pool head, each query head its own segment back."""
+    from deeplearning4j_tpu.kernels.paged_attention import (
+        _paged_verify_pallas, paged_verify_attention_reference)
+    h, dh, _, p = _PACKED[case]
+    _, kp, vp, tbl, pos, scale = _paged_fixture(seed=5, h=h, dh=dh)
+    q = jnp.asarray(np.random.default_rng(6).normal(size=(3, W, h, dh)),
+                    jnp.float32)
+    ref = paged_verify_attention_reference(q, kp, vp, tbl, pos, scale)
+    out = _paged_verify_pallas(q, _packed(kp, h // p), _packed(vp, h // p),
+                               tbl, pos, scale, p)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("case", sorted(_PACKED))
+def test_the_pool_s_two_views_are_each_other_s_way_back(case):
+    """Head rows -> pool rows -> head rows is the identity; head
+    ``H * p + j`` lies in lanes ``j * dh ..`` of pool head ``H``; what is
+    left of a row is zero."""
+    from deeplearning4j_tpu.kernels import paged_head_rows, paged_pool_rows
+    h, dh, _, p = _PACKED[case]
+    rows = jnp.asarray(np.random.default_rng(7).normal(size=(2, 5, h, 4, dh)),
+                       jnp.bfloat16)
+    pool = paged_pool_rows(rows, h // p, 128)
+    assert pool.shape == (2, 5, h // p, 4, 128)
+    np.testing.assert_array_equal(
+        np.asarray(paged_head_rows(pool, h, dh), np.float32),
+        np.asarray(rows, np.float32))
+    for head in range(h):
+        np.testing.assert_array_equal(
+            np.asarray(pool[:, :, head // p, :,
+                            (head % p) * dh:(head % p + 1) * dh], np.float32),
+            np.asarray(rows[:, :, head], np.float32))
+    assert not np.asarray(pool[..., p * dh:], np.float32).any()
+
+
+@pytest.mark.parametrize("case", sorted(_PACKED))
+def test_a_block_leaves_for_the_host_the_same_from_either_pool(case):
+    """``_block_to_host``: the host tier's and a prefix handoff's bytes
+    are [layers, h, block_size, dh] whatever the pool's rows hold, so a
+    block exported by a replica whose pool holds p heads a row imports
+    into one that holds a head a row, and back."""
+    from types import SimpleNamespace
+    from deeplearning4j_tpu.parallel.generation_server import \
+        GenerationServer
+    h, dh, _, p = _PACKED[case]
+    pool = jnp.asarray(np.random.default_rng(8).normal(size=(3, 6, h, 4, dh)),
+                       jnp.bfloat16)
+    srv = SimpleNamespace(_gen=SimpleNamespace(kv_heads=h))
+    plain = GenerationServer._block_to_host(srv, pool, 4, dh)
+    assert plain.shape == (3, h, 4, dh)
+    np.testing.assert_array_equal(plain, np.asarray(pool[:, 4]))
+    for heads in (h // p, h):                 # p heads a row; one, padded
+        np.testing.assert_array_equal(
+            GenerationServer._block_to_host(srv, _packed(pool, heads), 4, dh),
+            plain)
+
+
+@pytest.mark.parametrize("h,qk,v,route,want", [
+    (16, 64, 64, "pallas", 2),        # bert-large-causal.closed-decode
+    (1, 128, 128, "pallas", 1),       # jamba2-3b.closed-reasoning
+    (4, 192, 128, "pallas", 1),       # mimo-v2-flash, the full kind
+    (8, 192, 128, "pallas", 1),       # ... and its window kind
+    (4, 24, 16, "pallas", 1),         # unequal widths under the lanes
+    (12, 64, 64, "pallas", 2), (6, 128, 128, "pallas", 1),
+    (4, 8, 8, "pallas", 4), (3, 40, 40, "pallas", 3), (9, 32, 32, "pallas", 3),
+    (16, 64, 64, "reference", 1), (4, 8, 8, "reference", 1)],
+    ids=["closed_decode", "closed_reasoning", "long_reasoning_full",
+         "long_reasoning_window", "unequal_widths", "gpt2", "zoo_gpt",
+         "tiny_gpt", "padding_left", "largest_divisor",
+         "off_the_kernel_route", "tiny_off_the_kernel_route"])
+def test_heads_a_pool_row_by_the_rule(monkeypatch, h, qk, v, route, want):
+    """``paged_heads_a_row`` and the pool shape it gives, from (K/V
+    heads, widths, route) alone: the largest divisor of the heads whose
+    rows fit the 128 lanes, on the kernel route, keys and values equally
+    wide; else a head a row -- ``dh`` wide off the kernel route."""
+    from deeplearning4j_tpu import kernels
+    from deeplearning4j_tpu.kernels import paged_attention as pa
+    monkeypatch.setattr(pa, "_route", lambda: route)
+    assert kernels.paged_heads_a_row(h, qk, v) == want
+    wide = lambda d: -(-want * d // 128) * 128 if route == "pallas" else d
+    assert kernels.paged_pool_shape(h, 16, qk, v) == (
+        (h // want, 16, wide(qk)), (h // want, 16, wide(v)))
+
+    class Mesh:
+        tp = 2
+    assert kernels.paged_heads_a_row(h, qk, v, Mesh) == 1
+    assert kernels.paged_pool_shape(h, 16, qk, v, Mesh) == ((h, 16, qk),
+                                                            (h, 16, v))
+
+
 @pytest.mark.parametrize("entry", ["decode", "verify"])
 def test_paged_read_kernels_skip_a_lane_wide_pool_s_padding(entry):
     """The scatter-then-read kernels (speculative programs on the

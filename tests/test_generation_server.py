@@ -203,12 +203,13 @@ def test_multi_tick_parity_matrix(net, offline, bs, tb):
 def test_kernel_route_writes_in_the_kernel_and_decodes_the_same(
         net, offline, monkeypatch):
     """The kernel route (forced; Pallas interpret mode here): the
-    pool's rows are padded to the 128 lanes, the decode scan carries it
+    pool's rows are whole 128-lane rows, the net's four 8-wide heads
+    side by side in one (ISSUE 34), the decode scan carries it
     whole and the paged kernel writes each tick's row itself — three
     requests through two slots, so a slot turns over between scans and
     a free slot rides along, then the first prompt again down the
-    prefix-HIT admission (its cached blocks gathered without their
-    padding) — every greedy token equal to the reference route's
+    prefix-HIT admission (its cached blocks gathered out of the packed
+    rows, each head's own) — every greedy token equal to the reference route's
     (= offline decode)."""
     monkeypatch.setenv("DL4J_TPU_PAGED_KERNEL", "pallas")
     routes = telemetry.get_registry().get("paged_route_total")
@@ -220,7 +221,8 @@ def test_kernel_route_writes_in_the_kernel_and_decodes_the_same(
             for t0, n_new in [(9, 7), (3, 12), (6, 5)]]
     with GenerationServer(net, n_slots=2, max_len=32, tick_batch=4,
                           block_size=8, tick_timeout_s=None) as srv:
-        assert srv._kc.shape[-1] == 128          # dh is 8
+        # [layers, blocks + scratch, 4 heads / 4 a row, block, lanes]
+        assert srv._kc.shape == srv._vc.shape == (2, 9, 1, 8, 128)
         outs = [h.result(timeout=600) for h in
                 [srv.submit_async(p, n) for p, n in reqs]]
         h0 = hits.value
@@ -410,13 +412,22 @@ def test_paged_blocks_counter_follows_the_route_s_walk(net, monkeypatch,
     rest of what the read covered — the whole 4-entry table on the
     reference route (a gather), the kernel's chunks on the kernel
     route (here the 32-position table is one chunk: the same count,
-    by ``kernels.paged_walk_blocks``)."""
+    by ``kernels.paged_walk_blocks``).
+    ``generation_server_paged_lane_bytes_total``: the bytes of those
+    entries' blocks, both layers' K and V, that are heads' numbers
+    (``kv``) and that pad a pool row to the lanes (``pad``): none where
+    a row is a head, 8 wide; on the kernel route the four heads lie
+    side by side in one 128-lane row, 32 lanes of numbers in 128."""
     from deeplearning4j_tpu.kernels import paged_walk_blocks
     monkeypatch.setenv("DL4J_TPU_PAGED_KERNEL", route)
     blocks = telemetry.get_registry().get(
         "generation_server_paged_blocks_total")
+    lanes = telemetry.get_registry().get(
+        "generation_server_paged_lane_bytes_total")
     count = lambda: (blocks.labels(kind="live").value,
-                     blocks.labels(kind="dead").value)
+                     blocks.labels(kind="dead").value,
+                     lanes.labels(kind="kv").value,
+                     lanes.labels(kind="pad").value)
     reqs = [([1, 2, 3], 16), ([4, 5, 6, 7, 8, 9, 10, 11, 12], 9)]
     with GenerationServer(net, n_slots=2, max_len=32, tick_batch=8,
                           block_size=8, tick_timeout_s=None) as srv:
@@ -427,12 +438,23 @@ def test_paged_blocks_counter_follows_the_route_s_walk(net, monkeypatch,
         for h in [srv.submit_async(np.asarray(p, np.int32), n)
                   for p, n in reqs]:
             h.result(timeout=600)
-        live, dead = (a - b for a, b in zip(count(), before))
+        live, dead, kv, pad = (a - b for a, b in zip(count(), before))
+        pool = srv._kc.shape
     # a request's tokens are written at positions t0 .. t0 + n_new - 1
     want = [p // 8 + 1 for t0, n in ((3, 16), (9, 9))
             for p in range(t0, t0 + n)]
     assert live == sum(want)
     assert dead == sum(-(-w // chunk) * chunk - w for w in want)
+    # entry by entry: 2 layers x (K, V) x float32; the net's 4 heads of 8
+    # numbers a token, in pool rows of ``pool[2]`` heads ``pool[4]`` wide
+    assert pool[2:] == ((4, 8, 8) if route == "reference" else (1, 8, 128))
+    numbers = held = 0
+    for w in want:
+        for _ in range(-(-w // chunk) * chunk):
+            numbers += 2 * 2 * 4 * 4 * 8 * 8
+            held += 2 * 2 * 4 * pool[2] * 8 * pool[4]
+    assert (kv, pad) == (numbers, held - numbers)
+    assert pad == (0 if route == "reference" else 3 * kv)
 
 
 def test_useful_share_and_scheduler_host_counters(net):

@@ -175,6 +175,64 @@ def test_export_import_handoff_parity(net, offline):
     assert handoff.value - h0 == 4
 
 
+def test_spill_and_fetch_through_a_pool_of_several_heads_a_row(
+        net, offline, monkeypatch):
+    """The kernel route (forced; Pallas interpret mode here) holds the
+    tiny net's four 8-wide heads in ONE 128-lane pool row: the spill
+    reads a block out of it as [layers, h, block_size, dh], the
+    restore's fill lays the bytes back side by side, and two working
+    sets through a pool that holds one decode equal to offline."""
+    monkeypatch.setenv("DL4J_TPU_PAGED_KERNEL", "pallas")
+    pa = np.asarray([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9], np.int32)
+    pb = np.asarray([2, 7, 1, 8, 2, 8, 1, 8, 2, 8, 4, 5, 9], np.int32)
+    ref_a = offline.generate(pa[None], n_new=12)[0]
+    ref_b = offline.generate(pb[None], n_new=12)[0]
+    with GenerationServer(net, n_slots=2, max_len=32, block_size=4,
+                          kv_blocks=8, host_tier_blocks=8,
+                          tick_batch=1, tick_timeout_s=None) as srv:
+        assert srv._kc.shape == srv._vc.shape == (2, 9, 1, 4, 128)
+        for _ in range(2):
+            np.testing.assert_array_equal(
+                srv.submit(pa, n_new=12, timeout=600), ref_a)
+            np.testing.assert_array_equal(
+                srv.submit(pb, n_new=12, timeout=600), ref_b)
+        st = srv.stats()
+        assert st["tier_spills"] >= 2 and st["tier_fetches"] >= 1
+        # what the tier holds is a head a row, dh wide
+        _, k, v = next(iter(srv._tier._entries.values()))
+        assert k.shape == v.shape == (2, 4, 4, 8)
+
+
+@pytest.mark.parametrize("src_route,dst_route", [("pallas", "reference"),
+                                                 ("reference", "pallas")])
+def test_a_prefix_hands_off_between_pools_of_different_rows(
+        net, offline, monkeypatch, src_route, dst_route):
+    """A block exported by a replica whose pool holds four heads a row
+    (the kernel route) imports into one that holds a head a row (the
+    reference route), and back: the payload is [layers, h, block_size,
+    dh] either way and the decode on the importer equals offline."""
+    p = np.arange(2, 19, dtype=np.int32)     # 17 tokens: 4 full @bs=4
+    ref = offline.generate(p[None], n_new=6)[0]
+    kw = dict(n_slots=2, max_len=32, block_size=4, tick_batch=1,
+              tick_timeout_s=None)
+    shape = {"pallas": (2, 17, 1, 4, 128), "reference": (2, 17, 4, 4, 8)}
+    monkeypatch.setenv("DL4J_TPU_PAGED_KERNEL", src_route)
+    with GenerationServer(net, **kw) as src:
+        assert src._kc.shape == shape[src_route]
+        np.testing.assert_array_equal(
+            src.prefill_async(p).result(timeout=600), p)
+        payload = src.export_prefix(p)
+    assert len(payload) == 4
+    assert all(k.shape == v.shape == (2, 4, 4, 8) for _, _, k, v in payload)
+    monkeypatch.setenv("DL4J_TPU_PAGED_KERNEL", dst_route)
+    with GenerationServer(net, **kw) as dst:
+        assert dst._kc.shape == shape[dst_route]
+        assert dst.import_blocks(payload) == 4
+        np.testing.assert_array_equal(
+            dst.submit(p, n_new=6, timeout=600), ref)
+        assert dst.stats()["tier_fetches"] == 4
+
+
 def test_host_tier_validation(net):
     with pytest.raises(ValueError, match="host_tier_blocks"):
         GenerationServer(net, n_slots=1, max_len=32,

@@ -366,7 +366,9 @@ def test_spec_on_the_kernel_route_reads_a_lane_wide_pool(net, offline,
                                                         monkeypatch):
     """The speculative programs on the kernel route (forced; Pallas
     interpret mode here) still scatter, then read — into and from a
-    pool whose rows are padded to the 128 lanes: a request, then the
+    pool of whole 128-lane rows, the net's four 8-wide heads side by
+    side in one (ISSUE 34: the read-only kernels take four query rows a
+    position a pool head there): a request, then the
     same prompt down both prefix-HIT paths, equal to offline decode."""
     monkeypatch.setenv("DL4J_TPU_PAGED_KERNEL", "pallas")
     p = np.arange(1, 14, dtype=np.int32)     # 3 full blocks @ bs=4
@@ -375,7 +377,7 @@ def test_spec_on_the_kernel_route_reads_a_lane_wide_pool(net, offline,
                           tick_timeout_s=None,
                           speculative={"k": 2, "draft_layers": 2}) \
             as srv:
-        assert srv._kc.shape[-1] == 128
+        assert srv._kc.shape[2:] == srv._vc.shape[2:] == (1, 4, 128)
         np.testing.assert_array_equal(
             srv.submit(p, n_new=6, timeout=600), ref)
         np.testing.assert_array_equal(
