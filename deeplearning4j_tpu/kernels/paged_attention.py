@@ -477,8 +477,9 @@ def paged_walk_extent(pos, bs: int, chunk: int):
 
 
 def _decode_write_kernel(tbl_ref, pos_ref, lay_ref, wblk_ref, woff_ref,
-                         q_ref, kn_ref, vn_ref, *rest, bs: int, nb: int,
-                         mb: int, chunk: int, scale: float, has_sink: bool):
+                         *rest, bs: int, nb: int, mb: int, chunk: int,
+                         scale: float, has_sink: bool,
+                         has_write_at: bool = False):
     """Grid (B,), a slot a step, over ONE layer of the whole pool, which
     stays in HBM ([L * nb * h, bs, width], aliased in and out).  The
     kernel's work is the sequence of every slot's chunks that hold a
@@ -496,6 +497,10 @@ def _decode_write_kernel(tbl_ref, pos_ref, lay_ref, wblk_ref, woff_ref,
     attention reads the patched buffer and the block goes back whole by
     one copy (Mosaic refuses a DMA of one row into the pool).  A slot
     with ``wblk == 0`` (free, or retired mid-scan) writes nothing.
+    With ``has_write_at`` a sixth scalar operand follows ``woff_ref``:
+    the write block's entry in the slot's table where it is NOT the
+    last live one -- a window's ring over several blocks, written on at
+    ``pos % window`` while every block of it is read.
 
     The query block is (1, h, g, dh): the g query heads that share a
     K/V head are g rows at the slot's one position (g == 1: a query
@@ -504,6 +509,8 @@ def _decode_write_kernel(tbl_ref, pos_ref, lay_ref, wblk_ref, woff_ref,
     ``has_sink`` one more operand follows the new rows, the query
     heads' sink logits (h, g, 128) float32: the softmax's state starts
     from it (``_init_stats``)."""
+    wat_ref, rest = (rest[0], rest[1:]) if has_write_at else (None, rest)
+    q_ref, kn_ref, vn_ref, *rest = rest
     sink_ref, rest = (rest[0], rest[1:]) if has_sink else (None, rest)
     (k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm, kbuf, vbuf, sem, wsem, at_ref,
      m_ref, l_ref, acc_ref) = rest
@@ -583,8 +590,13 @@ def _decode_write_kernel(tbl_ref, pos_ref, lay_ref, wblk_ref, woff_ref,
                       fetch_next, 0)
         buf = at_ref[DONE] % buffers
         each_copy(b, c, live, buf, lambda copy: copy.wait())
-        patch = writes & (c + 1 == n_chunks)
-        at = pl.ds(pl.multiple_of((live - 1 - c * chunk) * bs, bs), bs)
+        if wat_ref is None:
+            patch = writes & (c + 1 == n_chunks)
+            at = pl.ds(pl.multiple_of((live - 1 - c * chunk) * bs, bs), bs)
+        else:
+            patch = writes & (c == wat_ref[b] // chunk)
+            at = pl.ds(pl.multiple_of(
+                jnp.clip(wat_ref[b] - c * chunk, 0, chunk - 1) * bs, bs), bs)
         backs = [pltpu.make_async_copy(vm.at[buf, :, at, :],
                                        out.at[rows(wblk_ref[b])],
                                        wsem.at[n])
@@ -692,13 +704,13 @@ def _verify_call(q, k_pool, v_pool, block_table, pos0, scale: float,
 @jax.named_scope("paged_read")
 def _paged_decode_write_pallas(q, k_new, v_new, k_pool, v_pool,
                                block_table, pos, wblk, woff, layer,
-                               scale: float, sink=None):
+                               scale: float, sink=None, write_at=None):
     """:func:`_decode_write_call` under the kernels' scope, on the
     compiled or the interpreted path as the backend says NOW (the
     call's cached trace may not decide that)."""
     return _decode_write_call(q, k_new, v_new, k_pool, v_pool, block_table,
-                              pos, wblk, woff, layer, sink, scale=scale,
-                              interpret=_interpret())
+                              pos, wblk, woff, layer, sink, write_at,
+                              scale=scale, interpret=_interpret())
 
 
 # jitted, inlined: the decode scans of 8, 4, 2 and 1 ticks (and both
@@ -707,8 +719,8 @@ def _paged_decode_write_pallas(q, k_new, v_new, k_pool, v_pool,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"),
                    inline=True)
 def _decode_write_call(q, k_new, v_new, k_pool, v_pool, block_table, pos,
-                       wblk, woff, layer, sink=None, *, scale: float,
-                       interpret: bool):
+                       wblk, woff, layer, sink=None, write_at=None, *,
+                       scale: float, interpret: bool):
     """The decode tick's write + read of one layer, the pool whole:
     q [B, hq, dh], k_new [B, h, dh], v_new [B, h, dv], pools [L, nb, h,
     bs, width] aliased to the outputs (each ``width`` its side's
@@ -716,7 +728,8 @@ def _decode_write_call(q, k_new, v_new, k_pool, v_pool, block_table, pos,
     the output is ``dv`` wide; ``hq`` a multiple of ``h``: query heads
     ``h * g .. h * g + g - 1`` read K/V head ``h``).  ``sink`` [hq]
     (None: none) is a logit a query head that enters the softmax's
-    denominator and nothing else.  Nothing but
+    denominator and nothing else.  ``write_at`` [B] (None: the slot's
+    last live block) is the write block's entry in the table.  Nothing but
     this kernel touches the pool, so XLA has no reason to hold it in
     any layout but the kernel's (= the parameter's default): no slice,
     layout copy or write-back of a layer exists in the program around
@@ -756,8 +769,10 @@ def _decode_write_call(q, k_new, v_new, k_pool, v_pool, block_table, pos,
         sink.astype(jnp.float32).reshape(h, g, 1), (h, g, _LANES))]
     sink_specs = [pl.BlockSpec((h, g, _LANES), lambda b, *_: (0, 0, 0))
                   for _ in sinks]
+    i32 = lambda z: jnp.asarray(z, jnp.int32)
+    write_ats = [] if write_at is None else [i32(write_at)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=5 + len(write_ats),
         grid=(B,),
         in_specs=[q_spec, new_spec(width), new_spec(v_width), *sink_specs,
                   pool_spec, pool_spec],
@@ -773,13 +788,13 @@ def _decode_write_call(q, k_new, v_new, k_pool, v_pool, block_table, pos,
         ],
     )
     new_row = lambda z, w: pad_head_dim(z, w)[:, :, None, :]
-    i32 = lambda z: jnp.asarray(z, jnp.int32)
     flat = lambda w: jax.ShapeDtypeStruct((L * nb * h, bs, w), k_pool.dtype)
     # operand numbers count the scalar-prefetch operands too
-    k_at = 8 + len(sinks)
+    k_at = 8 + len(write_ats) + len(sinks)
     att, k_flat, v_flat = pl.pallas_call(
         functools.partial(_decode_write_kernel, bs=bs, nb=nb, mb=mb,
-                          chunk=chunk, scale=scale, has_sink=bool(sinks)),
+                          chunk=chunk, scale=scale, has_sink=bool(sinks),
+                          has_write_at=bool(write_ats)),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(out_shape, q.dtype), flat(width),
                    flat(v_width)],
@@ -788,7 +803,7 @@ def _decode_write_call(q, k_new, v_new, k_pool, v_pool, block_table, pos,
         interpret=interpret,
         name="paged_attention",
     )(block_table, pos, i32(layer).reshape(1), i32(wblk), i32(woff),
-      q.reshape(B, h, g, dh), new_row(k_new, width),
+      *write_ats, q.reshape(B, h, g, dh), new_row(k_new, width),
       new_row(v_new, v_width), *sinks, k_pool.reshape(flat(width).shape),
       v_pool.reshape(flat(v_width).shape))
     return (att.reshape(B, hq, dv), k_flat.reshape(k_pool.shape),
@@ -896,7 +911,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos,
 
 def paged_decode_write_attention(q, k_new, v_new, k_pool, v_pool,
                                  block_table, pos, wblk, woff, layer,
-                                 scale: Optional[float] = None, sink=None):
+                                 scale: Optional[float] = None, sink=None,
+                                 write_at=None):
     """The kernel route's decode tick for ONE layer of the WHOLE pool:
     write each slot's new row ``k_new`` / ``v_new`` [B, h, dh] at
     (``layer``, ``wblk``, :, ``woff``) of ``k_pool`` / ``v_pool``
@@ -916,7 +932,10 @@ def paged_decode_write_attention(q, k_new, v_new, k_pool, v_pool,
     retired mid-scan keeps a live table and is sent to the scratch
     block 0, for which the kernel writes nothing.  An active slot's
     write block must be private to it and sit in its table at
-    ``pos // bs``.  ``k_new`` / the K pool may be wider than ``v_new``
+    ``pos // bs`` -- or at ``write_at`` [B], for a table that is a
+    window's ring over several blocks: the row lands in the ring's
+    block ``pos % window // bs`` while ``pos`` (clipped to the window)
+    says how many rows are read.  ``k_new`` / the K pool may be wider than ``v_new``
     / the V pool (att is then as wide as the values); ``sink`` [h] as
     :func:`softmax_with_sink` takes it.
 
@@ -933,5 +952,5 @@ def paged_decode_write_attention(q, k_new, v_new, k_pool, v_pool,
         k_new, v_new = k_new.reshape(B, heads, -1), v_new.reshape(B, heads, -1)
     att, k_pool, v_pool = _paged_decode_write_pallas(
         q, k_new, v_new, k_pool, v_pool, block_table, pos, wblk, woff,
-        layer, float(scale), sink)
+        layer, float(scale), sink, write_at)
     return (att if p == 1 else _own_segment(att, heads, p)), k_pool, v_pool

@@ -42,6 +42,8 @@ Two stacks decode here:
                 row j holds the newest position p with p % window == j)
       "routed": int32 [held + 1], the routed feed-forwards' tally: rows
                 each held expert got, then every token-expert pair made
+      "reached": int32 [1], the held experts that got a row, summed over
+                the routed layers run (those whose weights were read)
 
   One pool a KIND of attention run, a kind being the run's
   ``(n_kv_heads, qk_dim, v_dim, window)``: the full-attention runs of a
@@ -245,6 +247,12 @@ def _routed(run) -> bool:
     return getattr(run, "n_experts", None) is not None
 
 
+def _reached(tally):
+    """int32 [1]: of a routed layer's tally [held + 1] (or several
+    layers' [n, held + 1]), the held experts that got a row."""
+    return jnp.sum(tally[..., :-1] > 0).astype(jnp.int32)[None]
+
+
 def _scan_split(run, p):
     """(the leaves of a run's stacked parameters a scan over its layers
     slices, the leaves it closes over whole): a routed run's expert
@@ -360,6 +368,8 @@ class TransformerGenerator:
     # ... the WINDOW kind's (None: the stack has no window run) ...
     window_kind = property(
         lambda self: None if self._win is None else _kind(self._win))
+    window_sink = property(
+        lambda self: bool(getattr(self._win, "sink", False)))
     win_layers = property(lambda self: sum(
         n for r, n in zip(self.runs, self._layers) if _windowed(r)))
     # ... and the routed runs' layers and the experts each holds
@@ -401,6 +411,7 @@ class TransformerGenerator:
                                       self.compute_dtype)
         if self._held is not None:
             out["routed"] = jnp.zeros((self._held + 1,), jnp.int32)
+            out["reached"] = jnp.zeros((1,), jnp.int32)
         return out or None
 
     def _params(self):
@@ -471,7 +482,8 @@ class TransformerGenerator:
             # rows; a post-LN block knows neither
             extra = ({"pos": pos, "live": active}
                      if isinstance(run, AttentionBlockRun) else {})
-            tally = (rec["routed"],) if _routed(run) else ()
+            tally = ((rec["routed"], rec["reached"]) if _routed(run)
+                     else ())
             sliced, whole = _scan_split(run, p)
 
             def body(carry, xs, run=run, attend=attend, extra=extra,
@@ -482,12 +494,14 @@ class TransformerGenerator:
                 h, cache, *new = run.step(
                     {**xs[0], **whole}, h, attend(cache, xs[1]),
                     shard=shard, **extra)
+                if new:
+                    new = (new[0], _reached(new[0]))
                 return (h, cache, *(t + d for t, d in zip(tally, new))), None
             (x, caches[which], *tally), _ = jax.lax.scan(
                 body, (x, caches[which], *tally),
                 (sliced, jnp.arange(at[which], at[which] + n)))
             if tally:
-                rec["routed"] = tally[0]
+                rec["routed"], rec["reached"] = tally
             at[which] += n
         if self._win is not None:
             rec["win_k"], rec["win_v"] = caches["win"]
@@ -577,14 +591,16 @@ class TransformerGenerator:
         position ``pos % window`` and the read covers the ``min(pos + 1,
         window)`` rows written -- keys are cached rotated and softmax
         does not mind the order, so it is the SAME call as a full
-        layer's, through a table one window long.
+        layer's, through a table one window long (``window_blocks``
+        entries: the kernel route is told which of them the row lands
+        in, where that is not the last one read).
         Returns (logits, kc, vc, rec)."""
         from deeplearning4j_tpu.kernels import (
             paged_decode_attention, paged_decode_write_attention,
             paged_route)
         kernel = kernel_writes and paged_route(shard) == "pallas"
 
-        def paged(run, table, pos, wblk, woff):
+        def paged(run, table, pos, wblk, woff, write_at=None):
             scale = 1.0 / math.sqrt(_kind(run)[1])
 
             def attend_at(kv, layer):
@@ -592,7 +608,7 @@ class TransformerGenerator:
                     if kernel:
                         att, kc, vc = paged_decode_write_attention(
                             q, k, v, *kv, table, pos, wblk, woff, layer,
-                            scale=scale, sink=sink)
+                            scale=scale, sink=sink, write_at=write_at)
                         return att, (kc, vc)
                     return _scatter_then_read(
                         kv, layer, wblk, woff, k, v,
@@ -612,7 +628,9 @@ class TransformerGenerator:
                 self._win, mine[:, None] + jnp.arange(wb, dtype=jnp.int32),
                 jnp.minimum(pos, window - 1),
                 jnp.where(wblk != 0, mine + ring // bs, 0),
-                jnp.where(wblk != 0, ring % bs, 0))
+                jnp.where(wblk != 0, ring % bs, 0),
+                # a ring over several blocks is written on anywhere
+                ring // bs if wb > 1 else None)
         logits, (kc, vc), rec = self._tick(
             emb_p, runs_p, head_p, tok, pos, rec, active, (kc, vc),
             paged(self._attn, table, pos, wblk, woff), shard, attend_win)
@@ -671,7 +689,7 @@ class TransformerGenerator:
         x = x.astype(cd)
         if shard is not None:
             x = shard.rep(x)
-        ks, vs, hs, convs, wks, wvs, tally = [], [], [], [], [], [], []
+        ks, vs, hs, convs, wks, wvs, tally, reached = ([] for _ in range(8))
         kv_l = 0
         for run, p in zip(self.runs, runs_p):
             if run.RECURRENT:
@@ -698,6 +716,7 @@ class TransformerGenerator:
                         shard=shard), x, (p, pk[mine], pv[mine]))
             if "routed" in got:
                 tally.append(jnp.sum(got["routed"], axis=0))
+                reached.append(_reached(got["routed"]))
             if _windowed(run):          # its ring as after token t0
                 wks.append(got["k"].astype(cd))
                 wvs.append(got["v"].astype(cd))
@@ -713,7 +732,7 @@ class TransformerGenerator:
         if wks:
             rec.update(win_k=cat(wks), win_v=cat(wvs))
         if tally:
-            rec["routed"] = sum(tally)
+            rec["routed"], rec["reached"] = sum(tally), sum(reached)
         return (self._logits(emb_p, head_p, last, shard), cat(ks), cat(vs),
                 rec or None)
 

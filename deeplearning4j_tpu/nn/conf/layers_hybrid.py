@@ -13,8 +13,11 @@ FFN(RMSNorm(x))``, no biases:
   values ``v_dim`` wide, a rotary term on the first ``rotary_dim``
   lanes of q and k (rotate-half pairing, keys cached rotated), a scale
   on the values, a sliding ``window`` (a query reads the ``window``
-  newest positions, itself included) and a learned ``sink`` logit per
-  query head that enters the softmax's denominator and nothing else.
+  newest positions, itself included), a learned ``sink`` logit per
+  query head that enters the softmax's denominator and nothing else, a
+  scaled rotary base (``rope_scaling``: YaRN) and a sigmoid ``gate`` per
+  query head on the attention's output, read off the layer's normed
+  input.
 
 The FFN is a dense SwiGLU, or -- ``n_experts`` set -- a ROUTED one: a
 sigmoid router over all ``n_experts`` in float32, the ``top_k`` of
@@ -23,6 +26,8 @@ the experts this chip HOLDS (``held`` = (first, count): what expert
 parallelism tells a layer) computed by ``kernels.expert_ffn``.  What
 the experts held elsewhere would add is not here to add: the layer
 returns its own part of the sum, which is all there is on one chip.
+``routed_scale`` multiplies the routed sum, and ``shared_ff`` sets a
+SHARED expert that wide beside the routed ones, which every row takes.
 
 A layer conf here is a RUN of ``n_blocks`` identical blocks: its
 parameters carry a leading ``[n_blocks]`` axis as ``init()`` makes
@@ -97,6 +102,10 @@ class _PreNormRun(BaseLayerConf):
     n_experts: Optional[int] = None
     top_k: int = 1
     held: Optional[tuple] = None
+    # beside the routed experts: a factor on their weighted sum, and a
+    # shared expert (its width) that every row takes
+    routed_scale: Optional[float] = None
+    shared_ff: Optional[int] = None
 
     WANTED_KINDS = ("rnn",)
     RECURRENT = False                # keeps per-row state besides K/V
@@ -155,12 +164,19 @@ class _PreNormRun(BaseLayerConf):
         experts = lambda key, shape: init_weights(
             key, (n, held) + shape, shape[0], shape[-1], self.weight_init,
             dtype, self.weight_distribution)
+        shared = {}
+        if self.shared_ff:
+            sg, su, sd = jax.random.split(keys[1], 3)
+            sff = self.shared_ff
+            shared = {"Ws_gate": self._matrix(sg, (d, sff), dtype),
+                      "Ws_up": self._matrix(su, (d, sff), dtype),
+                      "Ws_down": self._matrix(sd, (sff, d), dtype)}
         return {"norm2": jnp.ones((n, d), dtype),
                 "W_router": self._matrix(kr, (d, self.n_experts), dtype),
                 "e_bias": jnp.zeros((n, self.n_experts), dtype),
                 "W_gate": experts(kg, (d, ff)),
                 "W_up": experts(ku, (d, ff)),
-                "W_down": experts(kd, (ff, d))}
+                "W_down": experts(kd, (ff, d)), **shared}
 
     #: leaves of a routed run that a scan over its layers leaves WHOLE
     #: (``whole_leaves``): the kernel reads a layer's experts out of the
@@ -184,10 +200,11 @@ class _PreNormRun(BaseLayerConf):
         elsewhere).  With ``layer`` the expert matrices of ``p`` are the
         whole run's, this layer's at that index."""
         n = rms_norm(x, p["norm2"], self.eps)
+        w = lambda k: p[k].astype(x.dtype)
+        swiglu = lambda h, gate, up, down: \
+            (_silu(h @ w(gate)) * (h @ w(up))) @ w(down)
         if self.n_experts is None:
-            w = lambda k: p[k].astype(x.dtype)
-            return x + (_silu(n @ w("W_gate")) * (n @ w("W_up"))) \
-                @ w("W_down"), None
+            return x + swiglu(n, "W_gate", "W_up", "W_down"), None
         from deeplearning4j_tpu.kernels import expert_ffn
         first, count = self.held_experts
         rows = n.reshape(-1, n.shape[-1])
@@ -201,6 +218,8 @@ class _PreNormRun(BaseLayerConf):
             _, idx = jax.lax.top_k(r + p["e_bias"].astype(f32), self.top_k)
             picked = jnp.take_along_axis(r, idx, axis=-1)
             weight = picked / jnp.sum(picked, axis=-1, keepdims=True)
+            if self.routed_scale is not None:
+                weight = weight * self.routed_scale
             alive = (jnp.ones(rows.shape[:1], bool) if live is None
                      else live.reshape(-1))
             here = (idx >= first) & (idx < first + count) & alive[:, None]
@@ -212,6 +231,9 @@ class _PreNormRun(BaseLayerConf):
                     jnp.int32)
         out = expert_ffn(rows, local, jnp.where(here, weight, 0.0),
                          p["W_gate"], p["W_up"], p["W_down"], layer)
+        if self.shared_ff:
+            with jax.named_scope("expert_shared"):
+                out = out + swiglu(rows, "Ws_gate", "Ws_up", "Ws_down")
         return x + out.reshape(x.shape), tally
 
     def apply(self, params, state, x, *, training: bool, rng=None,
@@ -228,16 +250,54 @@ class _PreNormRun(BaseLayerConf):
 _QUERY_BLOCK = 256
 
 
-def rotate_half(x, pos, rotary_dim: int, theta: float):
+def yarn_inv_freq(rotary_dim: int, theta: float, scaling: dict):
+    """(inv_freq float64 [rotary_dim / 2], the factor on cos and sin) of
+    a YaRN-scaled base, as ``transformers``' ``_compute_yarn_parameters``
+    has it: the pairs that turn fewer than ``beta_slow`` times over the
+    ``original_max_position_embeddings`` are interpolated (divided by
+    ``factor``), those that turn more than ``beta_fast`` times are left,
+    a linear ramp between (the bounds floored and ceiled)."""
+    import numpy as np
+    kind = scaling.get("rope_type", scaling.get("type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling {kind!r}: only 'yarn' is built")
+    d, factor = rotary_dim, float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    fast = float(scaling.get("beta_fast") or 32)
+    slow = float(scaling.get("beta_slow") or 1)
+    att = scaling.get("attention_factor")
+    if att is None:
+        att = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+    turns = lambda r: d * math.log(orig / (r * 2 * math.pi)) \
+        / (2 * math.log(theta))
+    low, high = math.floor(turns(fast)), math.ceil(turns(slow))
+    low, high = max(low, 0), min(high, d - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(d // 2, dtype=np.float64)
+    extra = theta ** (-2.0 * i / d)
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp), float(att)
+
+
+def rotate_half(x, pos, rotary_dim: int, theta: float, scaling=None):
     """The rotary term on the first ``rotary_dim`` lanes of x [...,
     heads, dim] at positions ``pos`` [...]: lane i pairs with lane
     i + rotary_dim / 2 (rotate-half), angle ``pos * theta ** (-i /
-    (rotary_dim / 2))``; in float32, back in x's dtype."""
+    (rotary_dim / 2))``; in float32, back in x's dtype.  With
+    ``scaling`` (a ``rope_scaling`` dict) the frequencies and the factor
+    on cos and sin are :func:`yarn_inv_freq`'s."""
     half = rotary_dim // 2
     f32 = jnp.float32
-    inv = jnp.exp(jnp.arange(half, dtype=f32) * (-math.log(theta) / half))
-    ang = jnp.asarray(pos, f32)[..., None, None] * inv
+    if scaling is None:
+        inv = jnp.exp(jnp.arange(half, dtype=f32)
+                      * (-math.log(theta) / half))
+    else:
+        inv, factor = yarn_inv_freq(rotary_dim, theta, scaling)
+    ang = jnp.asarray(pos, f32)[..., None, None] * jnp.asarray(inv, f32)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., :half].astype(f32), x[..., half:rotary_dim].astype(f32)
     return jnp.concatenate(
         [(x1 * cos - x2 * sin).astype(x.dtype),
@@ -262,14 +322,18 @@ class AttentionBlockRun(_PreNormRun):
     value_scale: Optional[float] = None
     window: Optional[int] = None     # None: full attention
     sink: bool = False               # a learned logit per query head
+    rope_scaling: Optional[dict] = None   # {"rope_type": "yarn", ...}
+    gate: bool = False               # sigmoid(n Wg) a query head on att
 
     #: why a server cannot share, restore, re-verify or shard this
     #: kind's K/V rows (None would mean it can)
     REFUSES = ("AttentionBlockRun layers: the run has no "
                "sequence(prefix=) over cached K/V rows (rotated keys "
-               "and a window's blocks are not restorable from a shared "
-               "prefix yet), no W-row verify step() and no shard points "
-               "(ROADMAP M1, M2, M4)")
+               "and a window's ring of one or several blocks are not "
+               "restorable from a shared prefix yet), no W-row verify "
+               "step() and no shard points: a gate's heads, a shared "
+               "expert and held experts are not split over tp (ROADMAP "
+               "M1, M2, M3, M4)")
 
     def _check_widths(self):
         if self.head_dim is None:
@@ -295,17 +359,20 @@ class AttentionBlockRun(_PreNormRun):
                            self.v_dim)
         ks = jax.random.split(key, 7)
         sink = ({"sink": jnp.zeros((n, hq), dtype)} if self.sink else {})
+        gate = ({"Wg": self._matrix(jax.random.fold_in(key, 7), (d, hq),
+                                    dtype)} if self.gate else {})
         return {"norm1": jnp.ones((n, d), dtype),
                 "Wq": self._matrix(ks[0], (d, hq * dk), dtype),
                 "Wk": self._matrix(ks[1], (d, hkv * dk), dtype),
                 "Wv": self._matrix(ks[2], (d, hkv * dv), dtype),
                 "Wo": self._matrix(ks[3], (hq * dv, d), dtype),
-                **sink, **self._ffn_params(ks[4:], dtype)}
+                **sink, **gate, **self._ffn_params(ks[4:], dtype)}
 
     def _qkv(self, p, x, pos=None):
         """q [..., n_heads, qk_dim], k [..., n_kv_heads, qk_dim] -- both
-        rotated at ``pos`` [...] where the run has a rotary term -- and
-        v [..., n_kv_heads, v_dim], scaled."""
+        rotated at ``pos`` [...] where the run has a rotary term -- v
+        [..., n_kv_heads, v_dim], scaled, and the gate [..., n_heads] on
+        the heads' outputs (None: the run has none)."""
         n = rms_norm(x, p["norm1"], self.eps)
         lead = x.shape[:-1]
         hq, hkv, dk, dv = (self.n_heads, self.n_kv_heads, self.qk_dim,
@@ -314,11 +381,24 @@ class AttentionBlockRun(_PreNormRun):
         k = (n @ p["Wk"].astype(x.dtype)).reshape(lead + (hkv, dk))
         v = (n @ p["Wv"].astype(x.dtype)).reshape(lead + (hkv, dv))
         if self.rotary_dim is not None:
-            q = rotate_half(q, pos, self.rotary_dim, self.rope_theta)
-            k = rotate_half(k, pos, self.rotary_dim, self.rope_theta)
+            q, k = (rotate_half(z, pos, self.rotary_dim, self.rope_theta,
+                                self.rope_scaling) for z in (q, k))
         if self.value_scale is not None:
             v = v * jnp.asarray(self.value_scale, v.dtype)
-        return q, k, v
+        gate = None
+        if self.gate:
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(n @ p["Wg"].astype(x.dtype))
+        return q, k, v, gate
+
+    def _out(self, p, x, att, gate):
+        """x + (att [..., n_heads, v_dim], each head's rows times its
+        gate) Wo."""
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                att = att * gate[..., None].astype(att.dtype)
+        return x + att.reshape(x.shape[:-1] + (-1,)) \
+            @ p["Wo"].astype(x.dtype)
 
     def _attend_block(self, qg, k, v, q_pos, sink):
         """The queries qg [b, tq, hkv, g, dk] at positions ``q_pos``
@@ -351,7 +431,7 @@ class AttentionBlockRun(_PreNormRun):
         (``shard`` names a device, never a split): ``REFUSES``."""
         b, t, _ = x.shape
         hq, hkv = self.n_heads, self.n_kv_heads
-        q, k, v = self._qkv(p, x, jnp.arange(t))
+        q, k, v, gate = self._qkv(p, x, jnp.arange(t))
         qg = q.reshape(b, t, hkv, hq // hkv, self.qk_dim)
         sink = p["sink"] if self.sink else None
         if t <= _QUERY_BLOCK:
@@ -368,7 +448,7 @@ class AttentionBlockRun(_PreNormRun):
                 (blocks, jnp.arange(nq)))
             att = att.swapaxes(0, 1).reshape(
                 (b, nq * _QUERY_BLOCK) + att.shape[3:])[:, :t]
-        x = x + att.reshape(b, t, hq * self.v_dim) @ p["Wo"].astype(x.dtype)
+        x = self._out(p, x, att.reshape(b, t, hq, self.v_dim), gate)
         live = None if t0 is None else jnp.broadcast_to(
             jnp.arange(t) < t0, (b, t))
         y, tally = self._ffn(p, x, live, layer)
@@ -395,10 +475,10 @@ class AttentionBlockRun(_PreNormRun):
         a run with a sink hands it its logits as ``sink=``.  Returns
         (y, cache) and, of a routed run, ``_ffn``'s tally over the
         ``live`` rows as a third (``layer``: as ``_ffn`` takes it)."""
-        q, k, v = self._qkv(p, x, pos)
+        q, k, v, gate = self._qkv(p, x, pos)
         att, cache = (attend(q, k, v, sink=p["sink"]) if self.sink
                       else attend(q, k, v))
-        x = x + att.reshape(x.shape[0], -1) @ p["Wo"].astype(x.dtype)
+        x = self._out(p, x, att, gate)
         y, tally = self._ffn(p, x, live, layer)
         return (y, cache) if tally is None else (y, cache, tally)
 

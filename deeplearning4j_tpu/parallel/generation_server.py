@@ -327,6 +327,12 @@ _EXPERT_CALLS = telemetry.counter(
     "generation_server_expert_calls_total",
     "routed layers run (a decode tick's, an admission's) x experts "
     "held: the experts' turns, each of which rows could have filled")
+_EXPERT_REACHED = telemetry.counter(
+    "generation_server_expert_reached_total",
+    "held experts that got at least one row, summed over the routed "
+    "layers run (a decode tick's, an admission's): the experts whose "
+    "weights a call streamed; over expert_calls_total it is the share "
+    "of the experts' turns that rows filled")
 _EXPERT_LOAD = telemetry.histogram(
     "generation_server_expert_load_ratio",
     "one sample a decode dispatch: the rows of the fullest held expert "
@@ -1112,11 +1118,13 @@ class GenerationServer:
     # block 1 + slot * window blocks .. a slot owns for life (no
     # allocator: the table is fixed), armed by an admission as after the
     # prompt's last real token.  And the routed layers' tally, int32
-    # [held + 1], which the decode scan hands to the host and zeroes.
+    # [held + 1], and the count of held experts reached, [1], which the
+    # decode scan hands to the host in one vector and zeroes.
     _REC_KEYS = ("rec_h", "rec_conv")
     _WIN_KEYS = ("win_k", "win_v")
     _REC_LEAVES = {"rec_h": "h", "rec_conv": "conv", "win_k": "win_k",
-                   "win_v": "win_v", "routed": "routed"}
+                   "win_v": "win_v", "routed": "routed",
+                   "reached": "reached"}
 
     @classmethod
     def _rec_of(cls, state):
@@ -1179,12 +1187,18 @@ class GenerationServer:
         if gen.window_kind is not None:
             wh, wk, wv, window = gen.window_kind
             wb = gen.window_blocks(self.block_size)
-            if on_kernel and wb > 1:
+            if on_kernel and wb > 1 and gen.window_sink:
+                # the kernel's ring over several blocks (the row lands
+                # in ANY block of the table) is compiled, tested and
+                # measured without a sink only; tests/benchmark_suite
+                # pins this refusal for a window kind that has one
                 raise ValueError(
                     f"window {window} spans {wb} blocks of "
-                    f"{self.block_size}: the decode kernel patches a "
-                    "slot's LAST live block, so on the kernel route a "
-                    "window fits one block (block_size >= window)")
+                    f"{self.block_size} and its layers have a sink: "
+                    "the decode kernel's write into a ring of several "
+                    "blocks is built without one, so with a sink on the "
+                    "kernel route a window fits one block "
+                    "(block_size >= window)")
             tails = paged_pool_shape(wh, self.block_size, wk, wv,
                                      self._shard)
             (heads, _, k_width), (_, _, v_width) = tails
@@ -2207,9 +2221,11 @@ class GenerationServer:
             if "routed" in state:
                 # the routed layers' tally since the last scan, in rows
                 # below the slots'; it starts again from nought
-                polled = _append_rows(polled, state["routed"])
+                polled = _append_rows(polled, jnp.concatenate(
+                    [state["routed"], state["reached"]]))
                 state = {**state,
-                         "routed": jnp.zeros_like(state["routed"])}
+                         "routed": jnp.zeros_like(state["routed"]),
+                         "reached": jnp.zeros_like(state["reached"])}
             return kc, vc, state, polled
 
         # donate caches + state: the scan updates them in place instead
@@ -2550,7 +2566,7 @@ class GenerationServer:
         1, kv heads, window, dim] cut into the slot's own blocks, as
         the ring's pool holds rows; the routed tally, which the prefill
         adds to."""
-        if name == "routed":
+        if name in ("routed", "reached"):
             return all_rows + row
         if name in self._WIN_KEYS:
             layers, _, h, window, _ = row.shape
@@ -2894,14 +2910,16 @@ class GenerationServer:
     def _count_routed(self, tally, ticks: int) -> None:
         """A decode scan's look at the routed layers: ``tally`` is the
         rows each held expert got, then every token-expert pair made,
-        since the last scan -- over this scan's ``ticks`` and the
-        admissions dispatched before it."""
-        per_expert, pairs = tally[:-1], int(tally[-1])
+        then the held experts that got a row (a layer a call), since the
+        last scan -- over this scan's ``ticks`` and the admissions
+        dispatched before it."""
+        per_expert, pairs, reached = tally[:-2], int(tally[-2]), int(tally[-1])
         held = int(per_expert.sum())
         with self._lock:
             admissions, self._routed_admits = self._routed_admits, 0
         _EXPERT_HELD.inc(held)
         _EXPERT_ABSENT.inc(pairs - held)
+        _EXPERT_REACHED.inc(reached)
         _EXPERT_CALLS.inc((ticks + admissions) * self._gen.routed_layers
                           * len(per_expert))
         if held:
@@ -3166,8 +3184,8 @@ class GenerationServer:
                            for k in self._REC_KEYS + self._WIN_KEYS
                            if k in state},
                         # the tally is no slot's: it goes on
-                        **({"routed": state["routed"]}
-                           if "routed" in state else {}),
+                        **{k: state[k] for k in ("routed", "reached")
+                           if k in state},
                     }
                     n_blk_salvaged = int(bmask.sum())
                     n_blk_dropped = len(used_before
@@ -3542,7 +3560,7 @@ class GenerationServer:
                     if len(polled_h) > self.n_slots:
                         # below the slots' rows: the routed layers' tally
                         routed_h = polled_h[self.n_slots:].reshape(-1)[
-                            :self._gen.held_experts + 1]
+                            :self._gen.held_experts + 2]
                         polled_h = polled_h[:self.n_slots]
                     toks_h = polled_h[:, :n_tok]
                     emit_h, rem_h = (polled_h[:, n_tok],

@@ -12,6 +12,16 @@ the ``top_k`` of score + bias, weights normalised, experts ``expert_ff``
 wide -- of which this chip holds ``held`` = (first, count), as expert
 parallelism deals them.  The head is an RMSNorm and its own matrix.
 
+By further arguments, each of which leaves the stack as it was where it
+is not given: a window layer may have its own number of query heads
+(``window_heads``) and rotate its own number of lanes
+(``window_rotary_dim``); either kind's rotary base may be scaled
+(``rope_scaling`` / ``window_rope_scaling``: YaRN); a sigmoid ``gate``
+a query head, read off the layer's normed input, may multiply the
+attention's output; a routed layer may have a SHARED expert
+``shared_ff`` wide that every token takes, and a factor
+``routed_scale`` on the routed experts' weighted sum.
+
 The net is ``EmbeddingSequenceLayer -> AttentionBlockRun ... ->
 LMHead``: consecutive layers of one attention kind and one
 feed-forward kind are one run (parameters stacked on a leading axis),
@@ -22,7 +32,8 @@ served-only model).
 Inference only: the head has no loss.  ``TransformerGenerator`` decodes
 it offline and ``GenerationServer`` serves it -- the full layers over
 the paged, allocated pool, each window layer over a ring of its
-window's blocks that a slot owns for life, the held experts through
+window's blocks (one or several) that a slot owns for life, the held
+experts through
 ``kernels.expert_ffn`` -- without what its runs cannot do yet (their
 ``REFUSES``: prefix reuse, the host tier, ``export_prefix`` /
 ``import_blocks``, ``prefill_async``, speculation, ``tp > 1``), which
@@ -71,6 +82,13 @@ class SparseWindowDecoder(ZooModel):
     n_experts: int = 32
     top_k: int = 4
     held: Optional[Sequence[int]] = None   # (first, count); None: all
+    window_heads: Optional[int] = None         # default: n_heads
+    window_rotary_dim: Optional[int] = None    # default: rotary_dim
+    rope_scaling: Optional[dict] = None        # full layers
+    window_rope_scaling: Optional[dict] = None
+    gate: bool = False                # sigmoid gate a query head
+    shared_ff: Optional[int] = None   # a shared expert's width
+    routed_scale: Optional[float] = None
     eps: float = 1e-5
     seq_len: int = 512
     compute_dtype: Optional[str] = "bfloat16"
@@ -107,19 +125,26 @@ class SparseWindowDecoder(ZooModel):
         for windowed, routed, n in self.runs():
             ffn = (dict(d_ff=self.expert_ff, n_experts=self.n_experts,
                         top_k=self.top_k,
-                        held=None if self.held is None else tuple(self.held))
+                        held=None if self.held is None else tuple(self.held),
+                        shared_ff=self.shared_ff,
+                        routed_scale=self.routed_scale)
                    if routed else dict(d_ff=self.d_ff))
             lst = lst.layer(AttentionBlockRun(
-                n_blocks=n, n_heads=self.n_heads,
+                n_blocks=n,
+                n_heads=((self.window_heads or self.n_heads) if windowed
+                         else self.n_heads),
                 n_kv_heads=(self.window_kv_heads if windowed
                             else self.n_kv_heads),
                 head_dim=self.qk_dim, qk_dim=self.qk_dim, v_dim=self.v_dim,
-                rotary_dim=self.rotary_dim,
+                rotary_dim=((self.window_rotary_dim or self.rotary_dim)
+                            if windowed else self.rotary_dim),
                 rope_theta=(self.window_rope_theta if windowed
                             else self.rope_theta),
+                rope_scaling=(self.window_rope_scaling if windowed
+                              else self.rope_scaling),
                 value_scale=self.value_scale,
                 window=self.window if windowed else None,
                 sink=self.window_sink if windowed else self.full_sink,
-                eps=self.eps, **ffn))
+                gate=self.gate, eps=self.eps, **ffn))
         return lst.layer(LMHead(n_out=self.vocab_size,
                                 eps=self.eps)).build()

@@ -678,9 +678,12 @@ def test_each_program_meets_the_host_through_one_array(
 # ISSUE 33: keys wider than values, a sink, a window's one-block table,
 # the held experts' kernel -- and the accepted cells' kernel unmoved
 # ---------------------------------------------------------------------------
-def _decode_write_lowered(one_chip, B, hq, h, dk, dv, bs, mb, L, nb, sink):
+def _decode_write_lowered(one_chip, B, hq, h, dk, dv, bs, mb, L, nb, sink,
+                          ring=False):
     """``_paged_decode_write_pallas`` lowered at a cell's geometry: bf16
-    pools of whole 128-lane rows, K and V each at its own width."""
+    pools of whole 128-lane rows, K and V each at its own width; with
+    ``ring`` the table is a window's ring over ``mb`` blocks and the
+    call says which of them the row lands in."""
     def S(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -689,12 +692,12 @@ def _decode_write_lowered(one_chip, B, hq, h, dk, dv, bs, mb, L, nb, sink):
     args = [S((B, hq, dk), bf), S((B, h, dk), bf), S((B, h, dv), bf),
             S((L, nb, h, bs, wide(dk)), bf), S((L, nb, h, bs, wide(dv)), bf),
             S((B, mb)), S((B,)), S((B,)), S((B,)), S(())]
-    if sink:
-        args.append(S((hq,), jnp.float32))
+    args.append(S((hq,), jnp.float32) if sink else None)
+    args.append(S((B,)) if ring else None)
     return jax.jit(
-        lambda q, kn, vn, kp, vp, t, p, wb, wo, lay, *sk:
+        lambda q, kn, vn, kp, vp, t, p, wb, wo, lay, sk, wat:
         paged_mod._paged_decode_write_pallas(q, kn, vn, kp, vp, t, p, wb, wo,
-                                             lay, dk ** -0.5, *sk),
+                                             lay, dk ** -0.5, sk, wat),
         donate_argnums=(3, 4)).lower(*args)
 
 
@@ -840,3 +843,153 @@ def test_a_sparse_window_stack_carries_its_kernels_names(named_programs):
     assert (polled.shape, polled.dtype) == ((8 + 2, 2 + 2), jnp.int32)
     _, lines = named_programs["sparse_admit_miss"]
     assert len(_matches(expert, lines)) == 3
+
+
+# ---------------------------------------------------------------------------
+# laguna-xs.2.closed-code-context (ISSUE 36): 128 slots of 4,096, 48 / 64
+# query heads on 8 K/V heads of 128, a 512-wide window ring over four
+# 128-position blocks, 256 held experts of 2048 x 512
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind", ["full", "ring"])
+def test_paged_kernel_compiles_at_the_code_context_cell_s_geometry(
+        one_chip, chip_paths, kind):
+    """A FULL layer's read (48 query heads, 6 a K/V head, 32-entry tables,
+    2 layers x 4,097 blocks) and a SLIDING layer's (64 query heads, the
+    slot's ring of FOUR blocks, 3 layers x 513 blocks): the ring's call
+    carries one more scalar operand, the table entry the row lands in,
+    and both pools still go through the kernel aliased, in one layout."""
+    hq, mb, L, nb, ring = {"full": (48, 32, 2, 4097, False),
+                           "ring": (64, 4, 3, 513, True)}[kind]
+    assert paged_mod.paged_walk_blocks(128, 8, 128, jnp.bfloat16, mb, 128) \
+        == (1, 5)
+    lowered = _decode_write_lowered(one_chip, 128, hq, 8, 128, 128, 128, mb,
+                                    L, nb, False, ring)
+    _, lines = _trace_names(lowered.compile())
+    assert _pool_producers(lines, (L, nb, 8, 128, 128)) == []
+    kernel, = _matches(r"^%paged_attention[.\d]* = .*custom-call\(", lines)
+    assert kernel.count(f"bf16[{L * nb * 8},128,128]") >= 2
+    for name in ("paged_attention_roofline", "decode_scan_tick_device_ms"):
+        args = _metric_args(name)
+        pattern = args.get("per_events_of", args)["pattern"]
+        assert len(_matches(pattern, lines)) == 1, name
+
+
+@pytest.mark.parametrize("rows", [128, 4096], ids=["decode_tick", "prefill"])
+def test_expert_ffn_compiles_at_the_code_context_cell_s_geometry(
+        one_chip, chip_paths, rows):
+    """256 HELD experts of 2048 x 512 (one fetch a matrix an expert) read
+    out of a run's stacked [3, 256, ., .] matrices by a layer index, top-8
+    picks of 128 rows (a decode tick: 4 rows an expert) or 4,096 (the
+    largest prefill bucket): the stacked matrices reach the kernel WHOLE
+    and ``expert_ffn_roofline`` finds the kernel by name."""
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    d, ff, held, k, n = 2048, 512, 256, 8, 3
+    assert expert_mod._ff_tile(d, ff, 2) == ff
+    compiled = jax.jit(
+        lambda x, e, w, wg, wu, wd, lay: expert_mod._expert_ffn_call(
+            x, e, w, wg, wu, wd, lay, interpret=False)).lower(
+        S((rows, d), bf), S((rows, k), jnp.int32), S((rows, k), jnp.float32),
+        S((n, held, d, ff), bf), S((n, held, d, ff), bf),
+        S((n, held, ff, d), bf), S((), jnp.int32)).compile()
+    _, lines = _trace_names(compiled)
+    kernel, = _matches(_metric_args("expert_ffn_roofline")["pattern"], lines)
+    assert kernel.count(f"bf16[{n},{held},{d},{ff}]") == 2      # gate, up
+    assert f"bf16[{n},{held},{ff},{d}]" in kernel
+    produced = [ln for ln in lines
+                if re.match(rf"%\S+ = bf16\[{held},({d},{ff}|{ff},{d})\]", ln)]
+    assert produced == []
+
+
+@pytest.fixture(scope="module")
+def code_context_programs(one_chip):
+    """``laguna-xs.2.closed-code-context``'s decode scan (K = 8) and its
+    1,024-token admission lowered and compiled for the described chip at
+    the cell's own shapes.  SHAPES only: the host stages 8 of the 256
+    experts, a 32-block pool and a 512-row vocabulary; the runs are then
+    told they hold every expert and the operands DESCRIBE the stacked
+    [., 256, ., .] matrices, the 4,097-block pool and the 100,352-row
+    table and head."""
+    from deeplearning4j_tpu.models.multi_layer_network import \
+        MultiLayerNetwork
+    from deeplearning4j_tpu.parallel import GenerationServer
+    from deeplearning4j_tpu.zoo import SparseWindowDecoder
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "laguna-xs.2.json")) as f:
+        ctor = json.load(f)["ctor"]
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "laguna-xs.2.closed-code-context.json")) as f:
+        server = json.load(f)["server"]
+    vocab, experts = ctor["vocab_size"], ctor["n_experts"]
+    mp = pytest.MonkeyPatch()
+    for mod in (paged_mod, expert_mod):
+        mp.setattr(mod, "_interpret", lambda: False)
+    mp.setattr(paged_mod, "_route", lambda: "pallas")
+    mp.setattr(expert_mod, "expert_route", lambda: "pallas")
+    out = {}
+    try:
+        net = MultiLayerNetwork(SparseWindowDecoder(
+            **{**ctor, "vocab_size": 512, "held": (0, 8)}).conf()).init()
+        net.layers[0].n_in = net.layers[-1].n_out = vocab
+        for run in net.layers[1:-1]:
+            run.held = None
+        srv = GenerationServer(net, kv_blocks=32, **server)
+        try:
+            def described(path, a):
+                shape = np.shape(a)
+                if path[-1].key in ("W_gate", "W_up", "W_down") \
+                        and len(shape) == 4:
+                    shape = (shape[0], experts) + shape[2:]
+                elif shape[:1] == (512,):               # table, head
+                    shape = (vocab,) + shape[1:]
+                return jax.ShapeDtypeStruct(shape, a.dtype,
+                                            sharding=one_chip)
+            params = jax.tree_util.tree_map_with_path(described, srv._params)
+            pool = jax.ShapeDtypeStruct((2, 4097) + srv._kc.shape[2:],
+                                        srv._kc.dtype, sharding=one_chip)
+            ops = (*params, pool, pool, _on_chip(srv._state, one_chip))
+            out["state"] = {k: (v.shape, v.dtype)
+                            for k, v in srv._state.items()}
+            scan = srv._decode_scan(8, False).lower(*ops)
+            out["decode_scan"] = _trace_names(scan.compile())
+            out["polled"] = scan.out_info[3]
+            miss = jax.ShapeDtypeStruct(
+                (_ADMIT_HEAD + 1024 + 8 + 2 * srv.max_blocks,), jnp.int32,
+                sharding=one_chip)
+            out["admit_miss"] = _trace_names(
+                srv._admit_miss_fn(1024).lower(*ops, miss).compile())
+        finally:
+            srv.shutdown(drain=False, timeout=30.0)
+    finally:
+        mp.undo()
+    return out
+
+
+def test_the_code_context_cell_s_programs_compile_for_v5e(
+        code_context_programs):
+    """Both programs fit the chip and keep their names; a tick calls
+    ``%paged_attention`` once a run (dense full, sliding x 3, routed
+    full) and ``%expert_ffn`` once a routed run, over pools and rings in
+    the kernel's layout alone; the scan returns ONE array, the slots'
+    rows and, below them, the [257] tally and the reached count; ``attn_gate`` and
+    ``expert_shared`` name their fusions in the HLO."""
+    state = code_context_programs["state"]
+    assert state["win_k"][0] == (3, 128 * 4 + 1, 8, 128, 128)
+    assert state["routed"][0] == (256 + 1,) and state["reached"][0] == (1,)
+    assert state["logits"][0] == (128, 100352)
+    name, lines = code_context_programs["decode_scan"]
+    assert name == "jit_decode_scan(1)"
+    assert len(_matches(r"^%paged_attention[.\d]* = ", lines)) == 3
+    expert = _metric_args("expert_ffn_roofline")["pattern"]
+    assert len(_matches(expert, lines)) == 2
+    for pool in ((2, 4097, 8, 128, 128), (3, 513, 8, 128, 128)):
+        assert _pool_producers(lines, pool) == []
+    text = "\n".join(lines)
+    assert "/attn_gate/" in text and "/expert_shared/" in text
+    polled = code_context_programs["polled"]
+    assert (polled.shape, polled.dtype) == ((128 + 26, 8 + 2), jnp.int32)
+    name, lines = code_context_programs["admit_miss"]
+    assert name == "jit_admit_miss(1)"
+    assert len(_matches(expert, lines)) == 2
