@@ -213,12 +213,14 @@ def run_train(family, config, cell, seed, seconds, tracer, note_setup_done):
         def on_step(n):
             if n in (a, b):
                 tap.losses[-1].block_until_ready()
-                if n == a:     # snapshots INSIDE the profiler's start and stop
-                    tracer.start()
+                if n == a:     # snapshots INSIDE the profiler's start and stop,
+                    tracer.start()  # each mark on the profile's clock too
                     traced["before"] = registry_snapshot()
-                    traced["t0"] = time.perf_counter()
+                    with jax.profiler.TraceAnnotation("bench/trace0"):
+                        traced["t0"] = time.perf_counter()
                 else:
-                    traced["t1"] = time.perf_counter()
+                    with jax.profiler.TraceAnnotation("bench/trace1"):
+                        traced["t1"] = time.perf_counter()
                     traced["after"] = registry_snapshot()
                     tracer.stop()
         tap.on_step = on_step
@@ -358,15 +360,21 @@ def closed_loop(srv, requests, ramp_s: float, seconds: float,
     ``closed-decode``); just after a landing, the tokens between two
     boundaries are those of the time between them.  A boundary waits at
     most ``settle_s`` for that.
-    Returns (finished, in_flight, marks): every request keeps, under
-    ``marks``, the tokens it had emitted at each boundary it was in
-    flight at ('open', 'close', and 'trace0'/'trace1' in a traced run).
+    In a traced run the sub-window's two boundaries, 'trace0' and
+    'trace1', are also host spans ``bench/trace0`` / ``bench/trace1`` on
+    the profile's clock, each followed by a registry snapshot: the trace
+    is cut to them and the counters cover the same interval.
+    Returns (finished, in_flight, marks, snapshots): every request
+    keeps, under ``marks``, the tokens it had emitted at each boundary
+    it was in flight at ('open', 'close', and 'trace0'/'trace1' in a
+    traced run); ``snapshots`` holds the registry at 'trace0' and
+    'trace1' (empty when untraced).
     """
     import jax
     feeds = [itertools.cycle(seq) for seq in requests]
     clients = len(requests)
     live = [None] * clients
-    finished, times = [], {}
+    finished, times, snaps = [], {}, {}
     t_begin, t_traced, landed_before = time.perf_counter(), None, False
 
     def mark(name):
@@ -374,6 +382,11 @@ def closed_loop(srv, requests, ramp_s: float, seconds: float,
         for r in live:
             if r is not None:
                 r.marks[name] = r.h.emitted
+
+    def traced_mark(name):
+        with jax.profiler.TraceAnnotation("bench/" + name):
+            mark(name)
+        snaps[name] = registry_snapshot()
 
     while True:
         now, landed = time.perf_counter(), False
@@ -411,17 +424,17 @@ def closed_loop(srv, requests, ramp_s: float, seconds: float,
                     t_traced = time.perf_counter()
         elif tracer is not None and "trace0" not in times:
             if due(t_traced):
-                mark("trace0")
+                traced_mark("trace0")
         elif tracer is not None and "trace1" not in times:
             if due(times["trace0"] + trace_s):
-                mark("trace1")
+                traced_mark("trace1")
                 tracer.stop()
         if "open" in times and due(times["open"] + seconds):
             mark("close")
             break
         time.sleep(poll_s)      # no span: an idle gap is then named by
                                 # what the scheduler's thread was doing
-    return finished, [r for r in live if r is not None], times
+    return finished, [r for r in live if r is not None], times, snaps
 
 
 def _emitted_between(reqs, a: str, b: str, t_a: float, t_b: float):
@@ -461,23 +474,13 @@ def run_serve(family, config, cell, seed, seconds, tracer, note_setup_done):
         warm_server(srv, traffic, server_kw, shape["vocab"])
         requests = serve_requests(traffic, shape["vocab"], seed)
 
-        class Tr:                        # registry snapshots ride along
-            def start(self):      # inside the profiler's start and stop,
-                tracer.start()    # which take seconds themselves
-                snaps["before"] = registry_snapshot()
-
-            def stop(self):
-                snaps["after"] = registry_snapshot()
-                tracer.stop()
-
         def on_open():
             note_setup_done()
             snaps["open"] = registry_snapshot()
 
-        finished, in_flight, times = closed_loop(
+        finished, in_flight, times, traced_snaps = closed_loop(
             srv, requests, traffic["ramp_seconds"],
-            seconds, traffic["trace_seconds"],
-            Tr() if tracer is not None else None, on_open,
+            seconds, traffic["trace_seconds"], tracer, on_open,
             poll_s=1e-3 * traffic.get("poll_ms", 4.0))
         snaps["close"] = registry_snapshot()
     finally:
@@ -516,7 +519,13 @@ def run_serve(family, config, cell, seed, seconds, tracer, note_setup_done):
                        if r.error is None and r.t_first is not None
                        and times["trace0"] <= r.t_submit
                        and r.t_first <= times["trace1"]],
-            "before": snaps["before"], "after": snaps["after"]}
+            # first token and last inside the sub-window, for the same
+            # reason
+            "tpot_s": [(r.t_done - r.t_first) / max(1, r.n_new - 1)
+                       for r in finished if r.error is None
+                       and times["trace0"] <= r.t_first
+                       and r.t_done <= times["trace1"]],
+            "before": traced_snaps["trace0"], "after": traced_snaps["trace1"]}
     sample = [(np.asarray(r.tokens), len(r.prompt), r.n_new)
               for r in pick_sample(ok, traffic["compare_requests"], seed)]
 

@@ -105,9 +105,17 @@ class Tracer:
 
 def per_layer(manifest, name, family, shape, traffic, facts, tracer, peak):
     """The cell's per-layer metrics over the traced sub-window, and the
-    numbers the result line's ``device`` and ``breakdown`` want."""
+    numbers the result line's ``device`` and ``breakdown`` want: all of
+    them from the capture cut to that sub-window."""
     from benchmark import readers, trace_reduce
-    trace = trace_reduce.load(tracer.path)
+    window_s = facts["traced"]["window_s"]
+    capture = trace_reduce.load(tracer.path)
+    trace = trace_reduce.sub_window(capture, window_s)
+    print(f"run.py: device busy {trace_reduce.busy_seconds(capture):.6f} s in "
+          f"the capture, {trace_reduce.busy_seconds(trace):.6f} s in the "
+          f"{window_s:.6f} s sub-window"
+          + ("" if trace is not capture else
+             f" (NOT cut: no {trace_reduce.WINDOW_MARK} span)"), file=sys.stderr)
     ctx = {"facts": facts["traced"], "before": facts["traced"]["before"],
            "after": facts["traced"]["after"], "trace": trace, "peak": peak,
            "family": family, "shape": shape, "traffic": traffic}
@@ -123,7 +131,7 @@ def per_layer(manifest, name, family, shape, traffic, facts, tracer, peak):
               file=sys.stderr)
     return metrics, {
         "busy_s": trace_reduce.busy_seconds(trace),
-        "window_s": facts["traced"]["window_s"]}, {
+        "window_s": window_s}, {
         "device_ops": trace_reduce.top_ops(trace),
         "idle_gaps": trace_reduce.idle_gaps(trace)}
 

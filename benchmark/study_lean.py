@@ -37,7 +37,7 @@ def study_serve(config, cell, seeds, seconds):
             gc.collect()
             drivers.seed_weights(net, family, shape, seed, dtype)
             srv.refresh_params()
-            finished, in_flight, _ = drivers.closed_loop(
+            finished, in_flight, *_ = drivers.closed_loop(
                 srv, drivers.serve_requests(traffic, shape["vocab"], seed),
                 0.0, seconds, 0.0, None, lambda: None)
             for r in in_flight:

@@ -6,6 +6,8 @@ longest idle gaps with what the host was doing in them.
 A trace is reduced to plain lists first (``load``), so the arithmetic
 below is checked on hand-built traces with no profiler
 (``tests/benchmark_suite``).  Times are seconds on the profile's clock.
+The capture is longer than the sub-window the driver times; every
+reading is taken on the trace ``sub_window`` cut to it.
 """
 from __future__ import annotations
 
@@ -25,6 +27,9 @@ NAME_CHARS = 160
 #: a loop, a branch or a call holds its body's operations as events of
 #: their own on the same line: it is a container, not work to rank
 CONTAINER = re.compile(r"^%(while|conditional|call)[.\d]* = |(?<![\w-])(while|conditional|call)\(")
+#: the host span a traced driver opens where it takes its sub-window's
+#: first mark (``trace0``): that moment on the device lines' clock
+WINDOW_MARK = "bench/trace0"
 
 
 def load(trace_dir: str) -> dict:
@@ -51,10 +56,39 @@ def load(trace_dir: str) -> dict:
     return {"devices": devices, "host_spans": sorted(host, key=lambda e: e[1])}
 
 
+def sub_window(trace: dict, window_s: float) -> dict:
+    """The trace cut to ``[m0, m0 + window_s]``, ``m0`` the start of the
+    ``WINDOW_MARK`` span: every device event and host span cut to its
+    overlap with it, one with none dropped.  A device event gets a
+    fourth field, whether its middle lies inside: ``time_of`` counts
+    only those, so a ratio of events to their time carries no bias from
+    the two cut at the edges.  The interval's length is the host's
+    ``window_s``, so no busy time can exceed it, whatever the two clocks
+    say.  A trace without the mark comes back unchanged."""
+    m0 = next((start for name, start, _ in trace["host_spans"]
+               if name == WINDOW_MARK), None)
+    if m0 is None:
+        return trace
+    m1 = m0 + window_s
+
+    def cut(events, flag: bool) -> list:
+        out = []
+        for name, start, dur, *_ in events:
+            lo, hi = max(start, m0), min(start + dur, m1)
+            if hi > lo or (hi == lo and dur == 0):
+                inside = (m0 <= start + dur / 2 < m1,) if flag else ()
+                out.append((name, lo, hi - lo) + inside)
+        return out
+    return {"devices": {plane: {line: cut(events, True)
+                                for line, events in lines.items()}
+                        for plane, lines in trace["devices"].items()},
+            "host_spans": cut(trace["host_spans"], False)}
+
+
 def busy_intervals(events) -> list:
     """Merged [start, end] intervals of (name, start, dur) events."""
     merged = []
-    for _, start, dur in sorted(events, key=lambda e: e[1]):
+    for _, start, dur, *_ in sorted(events, key=lambda e: e[1]):
         if merged and start <= merged[-1][1]:
             merged[-1][1] = max(merged[-1][1], start + dur)
         else:
@@ -73,14 +107,15 @@ def busy_seconds(trace: dict) -> float:
 
 def time_of(trace: dict, pattern: str, line: str = OPS_LINE):
     """(device seconds, events) of the events on ``line`` whose name
-    matches ``pattern``, averaged over the devices.  Nothing matched:
-    (None, 0) -- a reader then returns nothing, never 0."""
+    matches ``pattern``, averaged over the devices; an event cut by
+    ``sub_window`` counts in ``events`` only where it says so.  Nothing
+    counted: (None, 0) -- a reader then returns nothing, never 0."""
     rx = re.compile(pattern)
     total, count = 0.0, 0
     for lines in trace["devices"].values():
-        for name, _, dur in lines.get(line, []):
+        for name, _, dur, *inside in lines.get(line, []):
             if rx.search(name):
-                total, count = total + dur, count + 1
+                total, count = total + dur, count + (inside[0] if inside else 1)
     n = max(1, len(trace["devices"]))
     return (total / n, count // n) if count else (None, 0)
 
@@ -99,7 +134,7 @@ def top_ops(trace: dict, n: int = 10) -> list:
     """[[group, seconds], ...]: the device operations that took most."""
     by_name = {}
     for lines in trace["devices"].values():
-        for name, _, dur in lines.get(OPS_LINE, []):
+        for name, _, dur, *_ in lines.get(OPS_LINE, []):
             if CONTAINER.search(name):
                 continue
             key = op_group(name)

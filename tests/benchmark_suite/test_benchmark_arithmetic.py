@@ -1,18 +1,21 @@
 """The yardstick's arithmetic, checked by hand: the trace reduction on a
-hand-built trace, the cost functions on a tiny shape, the percentile
-and rate with a stall in the window, every per-layer reader on made-up
-facts, and BENCHMARK.json's names and files."""
+hand-built trace and its cut to the traced sub-window, where the serving
+loop takes that sub-window's marks, the cost functions on a tiny shape,
+the percentile and rate with a stall in the window, every per-layer
+reader on made-up facts, and BENCHMARK.json's names and files."""
 import json
 import os
 import re
 import sys
+import time
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import costs, readers, run, trace_reduce  # noqa: E402
+from benchmark import costs, drivers, readers, run, trace_reduce  # noqa: E402
 from benchmark.families import post_ln  # noqa: E402
 
 OPS = [("%while.4 = (s32[], bf16[24,2049,16,16,64]", 0.0, 1.5),  # holds the next two (name cut short)
@@ -47,6 +50,132 @@ def test_trace_reduction_on_a_hand_built_trace():
     kept = [s for s in spans if s[0].startswith(trace_reduce.HOST_SPAN_PREFIX)]
     assert trace_reduce.idle_gaps(dict(TRACE, host_spans=kept)) == [
         ["serve/retire", 2.0], ["train/step", 1.5]]
+
+
+def _marked(ops, m0=2.0, host=()):
+    """A one-device trace with the sub-window's first mark at ``m0``."""
+    return {"devices": {"/device:TPU:0": {"XLA Ops": list(ops)}},
+            "host_spans": sorted([(trace_reduce.WINDOW_MARK, m0, 1e-6), *host],
+                                 key=lambda e: e[1])}
+
+
+IDLE = run.load("benchmark", "layer_metrics", "device_idle_share.serve.json")
+
+
+# the sub-window is [2.0, 7.0]; "whole" is a device busy through a 10 s
+# capture; at each edge an event straddles it with its middle inside or
+# outside; 5.0 .. 6.0 lies inside whole
+@pytest.mark.parametrize("ops,busy,kernel", [
+    ([("flash", 0.0, 10.0)], 5.0, (5.0, 1)),
+    ([("flash", 1.0, 3.0), ("flash", 5.0, 1.0)], 3.0, (3.0, 2)),
+    ([("flash", 0.0, 3.0), ("flash", 5.0, 1.0)], 2.0, (2.0, 1)),
+    ([("flash", 6.0, 1.5), ("flash", 5.0, 1.0)], 2.0, (2.0, 2)),
+    ([("flash", 6.5, 2.0), ("flash", 5.0, 1.0)], 1.5, (1.5, 1)),
+    ([("flash", 0.0, 1.5), ("flash", 8.0, 1.0), ("flash", 5.0, 1.0)], 1.0, (1.0, 1)),
+], ids=["whole", "start_mid_in", "start_mid_out", "end_mid_in", "end_mid_out",
+        "outside"])
+def test_the_trace_is_cut_to_the_sub_window(ops, busy, kernel):
+    """Each event is cut to its overlap with [mark, mark + window_s] and
+    counted only where its middle lies inside; none outlasts the window."""
+    cut = trace_reduce.sub_window(_marked(ops), 5.0)
+    events = cut["devices"]["/device:TPU:0"]["XLA Ops"]
+    assert all(2.0 <= s and s + d <= 7.0 for _, s, d, _ in events)
+    assert trace_reduce.busy_seconds(cut) == busy
+    assert trace_reduce.busy_seconds(_marked(ops)) > busy
+    secs, n = trace_reduce.time_of(cut, "flash")
+    assert (secs, n) == (pytest.approx(kernel[0]), kernel[1])
+    share = readers.read(IDLE, {"trace": cut, "facts": {"window_s": 5.0}})
+    assert 0.0 <= share <= 100.0 and share == pytest.approx(100.0 - 20.0 * busy)
+
+
+def test_a_trace_without_the_mark_is_left_as_it_is():
+    assert trace_reduce.sub_window(TRACE, 1.0) is TRACE
+    assert trace_reduce.busy_seconds(TRACE) == pytest.approx(3.0)
+    assert trace_reduce.time_of(TRACE, "flash") == (pytest.approx(2.0), 2)
+
+
+def test_ranking_and_gaps_see_only_the_sub_window():
+    """TRACE's ops over [1.2, 4.2]: the last flash and the first fusion
+    fall outside, the first flash is cut to 0.3 s and, its middle
+    outside, is not counted; one gap is left, and one host span goes."""
+    trace = dict(TRACE, host_spans=sorted(
+        TRACE["host_spans"] + [(trace_reduce.WINDOW_MARK, 1.2, 1e-6),
+                               ("bench/late", 8.0, 1.0)], key=lambda e: e[1]))
+    cut = trace_reduce.sub_window(trace, 3.0)
+    assert [n for n, *_ in cut["host_spans"]] == [
+        "bench/fit", trace_reduce.WINDOW_MARK, "bench/poll_sleep"]
+    ranked = trace_reduce.top_ops(cut)
+    assert [n for n, _ in ranked] == ["fusion", "flash_kernel"]
+    assert [s for _, s in ranked] == [pytest.approx(0.5), pytest.approx(0.3)]
+    assert trace_reduce.idle_gaps(cut) == [["bench/poll_sleep", 1.5]]
+    assert trace_reduce.time_of(cut, "flash") == (None, 0)
+    assert trace_reduce.time_of(cut, "scan_fn", "XLA Modules") == (None, 0)
+    assert trace_reduce.time_of(cut, "admit", "XLA Modules") == (0.5, 1)
+
+
+class _Handle:
+    """A request that lands one token a poll."""
+
+    def __init__(self, n_new):
+        self.n_new, self.emitted = n_new, 0
+
+    def done(self):
+        self.emitted = min(self.n_new, self.emitted + 1)
+        return self.emitted == self.n_new
+
+    def result(self, timeout=None):
+        return np.zeros(self.n_new, np.int32)
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["traced", "untraced"])
+def test_the_serving_loop_snapshots_at_its_trace_marks(monkeypatch, traced):
+    """A traced loop opens a host span at each of 'trace0' and 'trace1'
+    around the moment it takes, then snapshots the registry, all between
+    the profiler's start and stop; an untraced one does neither."""
+    import jax
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name, time.perf_counter()))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, time.perf_counter()))
+
+    class Tracer:
+        def start(self):
+            log.append(("start",))
+
+        def stop(self):
+            log.append(("stop",))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(drivers, "registry_snapshot",
+                        lambda: log.append(("snapshot",)) or {"at": len(log)})
+    srv = type("Srv", (), {"submit_async": lambda self, p, n_new: _Handle(n_new)})()
+    requests = [[(np.zeros(3, np.int32), 4)] for _ in range(2)]
+    _, _, times, snaps = drivers.closed_loop(
+        srv, requests, 0.05, 0.4, 0.1, Tracer() if traced else None,
+        lambda: None, poll_s=1e-3, settle_s=0.01)
+    kept = [e for e in log if e[0] in ("start", "stop", "snapshot")
+            or "trace" in e[1]]
+    if not traced:
+        assert kept == [] and snaps == {} and "trace0" not in times
+        return
+    assert [e[:2] for e in kept] == [
+        ("start",), ("enter", "bench/trace0"), ("exit", "bench/trace0"),
+        ("snapshot",), ("enter", "bench/trace1"), ("exit", "bench/trace1"),
+        ("snapshot",), ("stop",)]
+    for mark in ("trace0", "trace1"):
+        (enter,), (leave,) = ([e[2] for e in log if e[:2] == (side, "bench/" + mark)]
+                              for side in ("enter", "exit"))
+        assert enter <= times[mark] <= leave
+        # the snapshot taken right after that span
+        assert log[snaps[mark]["at"] - 2][:2] == ("exit", "bench/" + mark)
+    assert times["open"] < times["trace0"] < times["trace1"] < times["close"]
 
 
 def test_costs_against_hand_counts():
@@ -105,6 +234,26 @@ def test_a_stall_in_the_window_moves_the_tail_and_the_rate():
     assert readers.read(tail, {"facts": {"ttft_s": stalled}}) == pytest.approx(500.0)
     assert readers.read(tail, {"facts": {"ttft_s": []}}) is None
     assert readers.read(tail, {"facts": {}}) is None
+
+
+@pytest.mark.parametrize("name,of", [("ttft_p95_ms.closed", "ttft_s"),
+                                     ("tpot_p95_ms.closed", "tpot_s")])
+def test_a_tail_read_per_layer_is_the_percentile_of_its_sub_window_list(name, of):
+    """The per-layer tails take the 95th percentile of the list the
+    serving loop keeps for the traced sub-window, in ms, and are silent
+    where that list is empty or missing; each is reported only in a cell
+    that does not bound it end to end."""
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["reader"]["args"]["of"] == of
+    stalled = [0.010] * 90 + [0.500] * 10
+    assert readers.read(spec, {"facts": {of: stalled}}) == pytest.approx(500.0)
+    assert readers.read(spec, {"facts": {of: []}}) is None
+    assert readers.read(spec, {"facts": {}}) is None
+    b = _manifest()
+    cells = next(m for m in b["per_layer"] if m["name"] == name)["workloads"]
+    same = [m for m in b["end_to_end"] if m["name"] == name.split(".")[0]]
+    assert cells and not any(set(cells) & set(m["workloads"]) for m in same)
 
 
 def _manifest():

@@ -37,7 +37,7 @@ CONFIGS = {c["name"]: c for c in MANIFEST["configs"]}
 #: the facts a driver's traced sub-window carries beside what the
 #: family's ``serve_work`` counts (``drivers.run_train`` / ``run_serve``)
 DRIVER_FACTS = {"train": {"window_s", "steps", "tokens", "flops"},
-                "serve_closed": {"window_s", "tokens", "ttft_s"}}
+                "serve_closed": {"window_s", "tokens", "ttft_s", "tpot_s"}}
 
 
 def _family_and_shape(cell: str):
