@@ -95,24 +95,41 @@ def _lane_bcast(stat, width):
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
+def _band_first(j, blk_q: int, blk_k: int, window: int):
+    """The first key block the band of query block ``j`` reaches: its
+    first row sees keys from ``j * blk_q - window + 1`` on."""
+    return jnp.maximum(j * blk_q - (window - 1), 0) // blk_k
+
+
+def _band_tile(j, ki, blk_q, blk_k, window):
+    """Bool [blk_q, blk_k]: ``row - window < col <= row``."""
+    rows = j * blk_q + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
+    cols = ki * blk_k + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
+    return (cols <= rows) & (cols > rows - window)
+
+
 def _fwd_kernel(*refs, n_k: int, scale: float, causal: bool,
-                has_bias: bool):
+                has_bias: bool, window: Optional[int] = None,
+                has_lse: bool = True):
     """Grid (bh, n_q, n_k): the KV dim is the MINOR grid axis, so each
     K/V block copy double-buffers behind the previous block's compute;
     the running softmax state lives in VMEM scratch across KV steps,
-    lane-replicated [blk_q, 128] (see module docstring)."""
-    if has_bias:
-        q_ref, k_ref, v_ref, b_ref = refs[:4]
-        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[4:]
-    else:
-        q_ref, k_ref, v_ref = refs[:3]
-        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[3:]
-        b_ref = None
-    j, ki = pl.program_id(1), pl.program_id(2)
+    lane-replicated [blk_q, 128] (see module docstring).  With a
+    ``window`` the minor axis counts steps from the band's first block
+    (``_band_first``), not key blocks from 0."""
+    n_in = 4 if has_bias else 3
+    q_ref, k_ref, v_ref = refs[:3]
+    b_ref = refs[3] if has_bias else None
+    o_ref = refs[n_in]
+    lse_ref = refs[n_in + 1] if has_lse else None
+    m_ref, l_ref, acc_ref = refs[n_in + 1 + has_lse:]
+    j, step = pl.program_id(1), pl.program_id(2)
     blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
     d = q_ref.shape[-1]
+    ki = step if window is None else step + _band_first(j, blk_q, blk_k,
+                                                         window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -128,7 +145,13 @@ def _fwd_kernel(*refs, n_k: int, scale: float, causal: bool,
             preferred_element_type=jnp.float32) * scale  # [blk_q, blk_k]
         if has_bias:
             s = s + b_ref[0, :1, :]          # [1, blk_k] sublane splat
-        if causal:
+        if window is not None:
+            # a row with none of its band's keys in the first tile it
+            # meets holds phantom mass (exp(0) a masked key) until its
+            # first real key: then corr = exp(_NEG - m) = 0 wipes it
+            # (every row reaches its diagonal in the band's last block)
+            s = jnp.where(_band_tile(j, ki, blk_q, blk_k, window), s, _NEG)
+        elif causal:
             s = jnp.where(_causal_tile(j, ki, blk_q, blk_k), s, _NEG)
         m_prev, l_prev = m_ref[:], l_ref[:]          # [blk_q, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -149,30 +172,34 @@ def _fwd_kernel(*refs, n_k: int, scale: float, causal: bool,
 
     if causal:
         # Blocks entirely above the diagonal contribute nothing — skip
-        # their matmuls (the source of the ~2x causal speedup).
+        # their matmuls (the source of the ~2x causal speedup); so does
+        # a banded step past the band's last block, which repeats that
+        # block's index (no copy).
         pl.when(ki * blk_k <= j * blk_q + blk_q - 1)(_compute)
     else:
         _compute()
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finish():
         l = l_ref[:]
         empty = l == 0.0          # fully-masked rows -> zero output
         l_safe = jnp.where(empty, 1.0, l)
         o_ref[0] = (acc_ref[:]
                     / _lane_bcast(l_safe, d)).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(empty, _POS, m_ref[:] + jnp.log(l_safe))
+        if has_lse:
+            lse_ref[0] = jnp.where(empty, _POS, m_ref[:] + jnp.log(l_safe))
 
 
 def _fwd_kernel_single(*refs, scale: float, causal: bool,
-                       has_bias: bool):
+                       has_bias: bool, window: Optional[int] = None,
+                       has_lse: bool = True):
     """One K/V block covers the whole row (t <= blk_k): plain softmax,
     no streaming state, no scratch — grid (bh, n_q)."""
-    if has_bias:
-        q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref = refs
-        b_ref = None
+    n_in = 4 if has_bias else 3
+    q_ref, k_ref, v_ref = refs[:3]
+    b_ref = refs[3] if has_bias else None
+    o_ref = refs[n_in]
+    lse_ref = refs[n_in + 1] if has_lse else None
     j = pl.program_id(1)
     blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
     q, k, v = q_ref[0], k_ref[0], v_ref[0]
@@ -181,7 +208,9 @@ def _fwd_kernel_single(*refs, scale: float, causal: bool,
         preferred_element_type=jnp.float32) * scale
     if has_bias:
         s = s + b_ref[0, :1, :]
-    if causal:
+    if window is not None:
+        s = jnp.where(_band_tile(j, 0, blk_q, blk_k, window), s, _NEG)
+    elif causal:
         s = jnp.where(_causal_tile(j, 0, blk_q, blk_k), s, _NEG)
     m = jnp.max(s, axis=1, keepdims=True)            # [blk_q, 1]
     p = jnp.exp(s - m)
@@ -193,8 +222,9 @@ def _fwd_kernel_single(*refs, scale: float, causal: bool,
     o_ref[0] = lax.dot_general(
         (p / l_safe).astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
-    lse = jnp.where(empty, _POS, m + jnp.log(l_safe))
-    lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+    if has_lse:
+        lse = jnp.where(empty, _POS, m + jnp.log(l_safe))
+        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 # Names in a profile: an instruction is named by the LAST scope of its
@@ -207,24 +237,39 @@ def _fwd_kernel_single(*refs, scale: float, causal: bool,
 # benchmark's readers match these names: tests/test_chip_compile.py.
 @jax.named_scope("flash_attention")
 def _flash_fwd(q, k, v, bias, blk_q: int, blk_k: int, causal: bool,
-               scale: float, bthd: "Static[bool]" = False):
+               scale: float, bthd: "Static[bool]" = False, *,
+               window: Optional[int] = None, with_lse: bool = True):
+    """(out, lse) -- ``lse`` None without ``with_lse`` (inference wants
+    no residual).  ``window`` (keys ``i - window < j <= i``; implies
+    causal): the minor grid axis covers only the key blocks a query
+    block's band reaches.  Causal either way: a step past the block a
+    query block last needs repeats that block's index, so Pallas copies
+    nothing for it and the kernel computes nothing.  In the ``bthd``
+    layout k / v may carry FEWER heads than q: query head h reads K/V
+    head h // (hq / hkv) through the index map, never repeated in
+    HBM."""
+    causal = causal or window is not None
     if bthd:
         # [b, t, h, d] viewed as [b, t, h*d] (a free bitcast): blocks
         # stay (1, blk, d) — Mosaic-legal since d % 128 == 0 — and the
         # third block index SELECTS the head's d-chunk, so the kernel
         # reads the projection layout in place with no transpose.
         b, t, h, d = q.shape
+        hkv = k.shape[2]
+        group = h // hkv
         bh = b * h
         q = q.reshape(b, t, h * d)
-        k = k.reshape(b, t, h * d)
-        v = v.reshape(b, t, h * d)
+        k = k.reshape(b, t, hkv * d)
+        v = v.reshape(b, t, hkv * d)
         dshape = (b, t, h * d)
         qspec = lambda f: pl.BlockSpec(
             (1, blk_q, d),
             lambda *g: (f(*g)[0] // h, f(*g)[1], f(*g)[0] % h))
+        kv_head = ((lambda i: i % h) if group == 1
+                   else (lambda i: i % h // group))
         kspec = lambda f: pl.BlockSpec(
             (1, blk_k, d),
-            lambda *g: (f(*g)[0] // h, f(*g)[1], f(*g)[0] % h))
+            lambda *g: (f(*g)[0] // h, f(*g)[1], kv_head(f(*g)[0])))
     else:
         bh, t, d = q.shape
         h = None
@@ -236,6 +281,12 @@ def _flash_fwd(q, k, v, bias, blk_q: int, blk_k: int, causal: bool,
     n_q = pl.cdiv(t, blk_q)
     n_k = pl.cdiv(t, blk_k)
     has_bias = bias is not None
+    opts = dict(scale=scale, causal=causal, has_bias=has_bias,
+                window=window, has_lse=with_lse)
+    lse_spec, lse_shape = (
+        ([pl.BlockSpec((1, blk_q, _LANES), lambda i, j, *s: (i, j, 0))],
+         [jax.ShapeDtypeStruct((bh, t, _LANES), jnp.float32)])
+        if with_lse else ([], []))
     # f(*grid) -> (bh_index, block_index) for q/k/v/o data operands
     if n_k == 1:
         q_ix = lambda i, j: (i, j)
@@ -246,45 +297,43 @@ def _flash_fwd(q, k, v, bias, blk_q: int, blk_k: int, causal: bool,
             in_specs.append(
                 pl.BlockSpec((1, 8, blk_k), lambda i, j: (i, 0, 0)))
             inputs.append(bias)
-        out, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_single, scale=scale,
-                              causal=causal, has_bias=has_bias),
+        out = pl.pallas_call(
+            functools.partial(_fwd_kernel_single, **opts),
             grid=(bh, n_q),
             in_specs=in_specs,
-            out_specs=[
-                qspec(q_ix),
-                pl.BlockSpec((1, blk_q, _LANES), lambda i, j: (i, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct(dshape, q.dtype),
-                jax.ShapeDtypeStruct((bh, t, _LANES), jnp.float32),
-            ],
+            out_specs=[qspec(q_ix)] + lse_spec,
+            out_shape=[jax.ShapeDtypeStruct(dshape, q.dtype)] + lse_shape,
             compiler_params=_dimsem("parallel", "parallel"),
             interpret=_interpret(),
             name="flash_fwd",
         )(*inputs)
-        return out, lse
+        return out[0], (out[1] if with_lse else None)
+    last = lambda j: (j * blk_q + blk_q - 1) // blk_k
+    if window is not None:
+        # the band of query block j spans key blocks _band_first(j) ..
+        # last(j): the grid's minor axis is the widest such span
+        n_k = max(last(j) - (max(j * blk_q - window + 1, 0) // blk_k) + 1
+                  for j in range(n_q))
+        kb = lambda j, s: jnp.minimum(
+            _band_first(j, blk_q, blk_k, window) + s, last(j))
+    elif causal:
+        kb = lambda j, ki: jnp.minimum(ki, last(j))
+    else:
+        kb = lambda j, ki: ki
     q_ix = lambda i, j, ki: (i, j)
-    k_ix = lambda i, j, ki: (i, ki)
+    k_ix = lambda i, j, ki: (i, kb(j, ki))
     in_specs = [qspec(q_ix), kspec(k_ix), kspec(k_ix)]
     inputs = [q, k, v]
     if has_bias:
         in_specs.append(
-            pl.BlockSpec((1, 8, blk_k), lambda i, j, ki: (i, 0, ki)))
+            pl.BlockSpec((1, 8, blk_k), lambda i, j, ki: (i, 0, kb(j, ki))))
         inputs.append(bias)
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, n_k=n_k, scale=scale,
-                          causal=causal, has_bias=has_bias),
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, n_k=n_k, **opts),
         grid=(bh, n_q, n_k),
         in_specs=in_specs,
-        out_specs=[
-            qspec(q_ix),
-            pl.BlockSpec((1, blk_q, _LANES), lambda i, j, ki: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(dshape, q.dtype),
-            jax.ShapeDtypeStruct((bh, t, _LANES), jnp.float32),
-        ],
+        out_specs=[qspec(q_ix)] + lse_spec,
+        out_shape=[jax.ShapeDtypeStruct(dshape, q.dtype)] + lse_shape,
         scratch_shapes=[
             pltpu.VMEM((blk_q, _LANES), jnp.float32),  # running max
             pltpu.VMEM((blk_q, _LANES), jnp.float32),  # running denom
@@ -294,7 +343,7 @@ def _flash_fwd(q, k, v, bias, blk_q: int, blk_k: int, causal: bool,
         interpret=_interpret(),
         name="flash_fwd",
     )(*inputs)
-    return out, lse
+    return out[0], (out[1] if with_lse else None)
 
 
 # ---------------------------------------------------------------------------
@@ -671,6 +720,46 @@ def _auto_blocks(t: int, causal: bool = False):
     bq = 1024 if t % 1024 == 0 else (512 if t % 512 == 0 else t)
     bk = 512 if t % 512 == 0 else t
     return min(bq, t), min(bk, t)
+
+
+def _prefill_blocks(t: int):
+    """Blocks of ``flash_prefill`` over a prompt of ``t`` positions (a
+    multiple of 128): the largest of 512, 256, 128 that divides it, for
+    queries and keys alike, full or banded (on a v5e at 64 / 48 heads of
+    128 on 8, window 512 or none, t 1,024-4,096: 512 x 512 beat 256 x
+    256 by 1.4-2.1x and 1,024 x 512 but once, by 2%; PERF.md)."""
+    blk = next(b for b in (512, 256, _LANES) if t % b == 0)
+    return blk, blk
+
+
+def flash_prefill(q, k, v, *, window: Optional[int] = None,
+                  scale: Optional[float] = None):
+    """Causal attention over a whole prompt, for inference: q [b, t, hq,
+    d], k / v [b, t, hkv, d] as the projections give them, ``hq`` a
+    multiple of ``hkv`` (query head h reads K/V head h // (hq / hkv));
+    ``window``: key j is seen by query i iff ``i - window < j <= i``.
+    The flash forward streams only the key blocks the causal triangle
+    or the band reaches; scores and softmax statistics are float32 in
+    VMEM, never in HBM, and nothing is kept for a backward pass (no
+    VJP: use ``flash_attention`` to train).  A ``t`` that is not a
+    multiple of 128 is padded at the end, which no real query sees."""
+    b, t, h, d = q.shape
+    if h % k.shape[2] or k.shape != v.shape or k.shape[3] != d:
+        raise ValueError(f"flash_prefill: q {q.shape} on k {k.shape}, "
+                         f"v {v.shape}: keys and values as wide as the "
+                         "queries, query heads a multiple of K/V heads")
+    if d % _LANES and not _interpret():
+        raise ValueError(f"flash_prefill needs head dim % 128 == 0 on "
+                         f"TPU (got {d})")
+    tp = -(-t // _LANES) * _LANES
+    if tp != t:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
+                   for a in (q, k, v))
+    blk_q, blk_k = _prefill_blocks(tp)
+    out, _ = _flash_fwd(q, k, v, None, blk_q, blk_k, True,
+                        float(1.0 / d ** 0.5 if scale is None else scale),
+                        True, window=window, with_lse=False)
+    return out.reshape(b, tp, h, d)[:, :t]
 
 
 def _flash_applicable(q, k, bias, blk_q, blk_k) -> bool:

@@ -378,6 +378,17 @@ class TransformerGenerator:
     held_experts = property(lambda self: self._held)
     vocab_size = property(lambda self: int(self.head.n_out))
 
+    def prefill_routes(self, t: int, shard=None) -> dict:
+        """{'kernel' | 'dense': positions x layers}: how a prefill of
+        ``t`` positions attends in the runs that choose
+        (``AttentionBlockRun.prefill_route``); the host knows it from
+        the bucket and the runs' fields alone."""
+        routes = {"kernel": 0, "dense": 0}
+        for run, n in zip(self.runs, self._layers):
+            if isinstance(run, AttentionBlockRun):
+                routes[run.prefill_route(t, shard)] += t * n
+        return routes
+
     def window_blocks(self, block_size: int) -> int:
         """Blocks of ``block_size`` positions a slot's window takes."""
         return -(-self._win.window // block_size)

@@ -417,6 +417,22 @@ class AttentionBlockRun(_PreNormRun):
         w = softmax_with_sink(s, sink)
         return jnp.einsum("bhgqk,bkhd->bqhgd", w.astype(v.dtype), v)
 
+    def prefill_route(self, t: int, shard=None) -> str:
+        """'kernel' | 'dense': how ``sequence`` attends ``t`` positions.
+        The kernel (``kernels.flash_prefill``: the band's and the causal
+        triangle's key blocks alone, K/V heads grouped in its index
+        maps) where the kernel route is on (``kernels.paged_route``: the
+        TPU, as for the paged pool), the prompt is longer than one
+        query block, there is no sink, and keys and values are equally
+        wide in whole 128-lane rows; else ``_attend_block`` over every
+        key, a query block at a time."""
+        from deeplearning4j_tpu.kernels import paged_route
+        if (t > _QUERY_BLOCK and not self.sink
+                and self.qk_dim == self.v_dim and self.qk_dim % 128 == 0
+                and paged_route(shard) == "pallas"):
+            return "kernel"
+        return "dense"
+
     def sequence(self, p, x, t0=None, shard=None, layer=None):
         """x [b, t, d] -> (y, {"k", "v"}): of a full-attention run the
         rows [b, n_kv_heads, t, .] as a pool holds them (keys rotated).
@@ -428,7 +444,9 @@ class AttentionBlockRun(_PreNormRun):
         softmax does not mind, written on at ``pos % window``.  A routed
         run gives "routed" too, ``_ffn``'s tally over the positions
         before ``t0`` (``layer``: as ``_ffn`` takes it).  No ``prefix=`` form and no shard points
-        (``shard`` names a device, never a split): ``REFUSES``."""
+        (``shard`` names a device, never a split): ``REFUSES``.  A long
+        prompt is attended as ``prefill_route`` says: on the kernel
+        route the forward has no VJP (inference alone)."""
         b, t, _ = x.shape
         hq, hkv = self.n_heads, self.n_kv_heads
         q, k, v, gate = self._qkv(p, x, jnp.arange(t))
@@ -436,6 +454,10 @@ class AttentionBlockRun(_PreNormRun):
         sink = p["sink"] if self.sink else None
         if t <= _QUERY_BLOCK:
             att = self._attend_block(qg, k, v, jnp.arange(t), sink)
+        elif self.prefill_route(t, shard) == "kernel":
+            from deeplearning4j_tpu.kernels import flash_prefill
+            att = flash_prefill(q, k, v, window=self.window,
+                                scale=1.0 / math.sqrt(self.qk_dim))
         else:
             nq = -(-t // _QUERY_BLOCK)
             pad = nq * _QUERY_BLOCK - t

@@ -312,6 +312,16 @@ _PREFILL_TOKENS = telemetry.counter(
     labelnames=("kind",))
 _PREFILL_REAL = _PREFILL_TOKENS.labels(kind="real")
 _PREFILL_PAD = _PREFILL_TOKENS.labels(kind="pad")
+# ... and how its attention reaches the keys, in the runs that choose
+# (``AttentionBlockRun.prefill_route``): the flash kernel over the
+# causal triangle's and the band's blocks, or every key in jax.numpy
+_PREFILL_ATTENTION = telemetry.counter(
+    "generation_server_prefill_attention_total",
+    "prompt positions x attention layers the admit programs were "
+    "dispatched over, by route (kernel: kernels.flash_prefill; dense: "
+    "every key scored, a query block at a time)", labelnames=("route",))
+_PREFILL_ROUTE = {r: _PREFILL_ATTENTION.labels(route=r)
+                  for r in ("kernel", "dense")}
 # the routed feed-forwards' work: the tally every routed layer keeps on
 # the device (``rec["routed"]``) rides the decode scan's one read to the
 # host, with what the admissions since the last scan added to it
@@ -2849,6 +2859,9 @@ class GenerationServer:
             payloads = ()
         _PREFILL_REAL.inc(n_real)
         _PREFILL_PAD.inc(n_pad)
+        for route, n in self._gen.prefill_routes(n_real + n_pad,
+                                                 self._shard).items():
+            _PREFILL_ROUTE[route].inc(n)
         ops = self._to_device("admit", _pack_admission(req, slot, *rows))
         _DISPATCHED["admit"].inc()
         # ledger-mark BEFORE the donating dispatch (a host-side weakref

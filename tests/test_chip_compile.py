@@ -252,6 +252,27 @@ def test_flash_names_at_bert_large_widths(one_chip, chip_paths):
     assert len(_matches(bwd["pattern"], ops)) == bwd["events_per_call"]
 
 
+@pytest.mark.parametrize("t,hq,window", [
+    (4096, 64, 512), (4096, 48, None), (512, 64, 512), (2048, 48, None)],
+    ids=["window_t4096", "full_t4096", "window_one_block", "full_t2048"])
+def test_flash_prefill_compiles_at_the_code_context_cell_s_widths(
+        one_chip, chip_paths, t, hq, window):
+    """``laguna-xs.2.closed-code-context``'s admissions: 64 (window 512)
+    or 48 (full) query heads on 8 K/V heads of 128, bf16, at its longest
+    bucket and its shortest on the kernel route: one Mosaic kernel a
+    call, named ``%flash_fwd`` as ``flash_forward_roofline`` finds it,
+    and no lse output (one result)."""
+    q = jax.ShapeDtypeStruct((1, t, hq, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, t, 8, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: kernels.flash_prefill(
+        q, k, v, window=window)).lower(q, kv, kv).compile()
+    _, ops = _trace_names(compiled)
+    found = _matches(_metric_args("flash_forward_roofline")["pattern"], ops)
+    assert len(found) == 1 and found[0].split(" = ")[1].startswith("bf16[")
+
+
 def test_paged_kernel_name_at_the_benchmark_geometry(one_chip, chip_paths):
     """The decode read of ``bert-large-causal.closed-decode``: 64
     slots, 32-entry tables, 2,049 blocks of 16 x (16 heads x 64), bf16."""

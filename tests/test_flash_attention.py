@@ -819,3 +819,133 @@ def test_paged_verify_pallas_interpret_matches_reference():
     out = _paged_verify_pallas(q, kp, vp, tbl, pos, scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# flash_prefill: the inference forward over a prompt -- a band, grouped K/V
+# ---------------------------------------------------------------------------
+def _dense_attention(hq, hkv, window, q, k, v, t0):
+    """``AttentionBlockRun._attend_block`` -- the dense route the kernel
+    replaces -- a query block of 256 at a time, over the first ``t0``
+    queries."""
+    from deeplearning4j_tpu.nn.conf.layers_hybrid import AttentionBlockRun
+    run = AttentionBlockRun(n_heads=hq, n_kv_heads=hkv, head_dim=128,
+                            window=window)
+    run.infer_shapes((q.shape[1], 128))
+    b, t = q.shape[:2]
+    qg = q.reshape(b, t, hkv, hq // hkv, 128)
+    block = jax.jit(lambda qb, at: run._attend_block(qb, k, v, at, None))
+    outs = [block(qg[:, a:a + 256], jnp.arange(a, min(a + 256, t)))
+            for a in range(0, t0, 256)]
+    return jnp.concatenate(outs, axis=1)[:, :t0].reshape(b, t0, hq, 128)
+
+
+@pytest.mark.parametrize("t", [384, 1024, 2048])
+@pytest.mark.parametrize("hq,hkv,window", [(48, 8, None), (64, 8, 512)],
+                         ids=["full_48_on_8", "window_512_64_on_8"])
+def test_flash_prefill_matches_the_dense_route(hq, hkv, window, t):
+    """Cell 5's two kinds of attention layer (48 query heads on 8 K/V
+    heads, full; 64 on 8 in a 512-wide band), bf16: the kernel that
+    visits only the blocks the causal triangle or the band reaches,
+    K/V heads grouped in its index maps, against ``_attend_block``
+    over every key -- the real positions of a prompt padded past its
+    length ``t0``, within bf16's step at the outputs' size."""
+    from deeplearning4j_tpu.kernels import flash_prefill
+    t0 = t - 37
+    ks = jax.random.split(jax.random.PRNGKey(t + hq), 3)
+    q = jax.random.normal(ks[0], (1, t, hq, 128), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, t, hkv, 128), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, t, hkv, 128), jnp.bfloat16)
+    got = jax.jit(lambda q, k, v: flash_prefill(
+        q, k, v, window=window, scale=128 ** -0.5))(q, k, v)
+    assert got.shape == q.shape and got.dtype == jnp.bfloat16
+    want = _dense_attention(hq, hkv, window, q, k, v, t0)
+    np.testing.assert_allclose(np.asarray(got[:, :t0], np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+def test_flash_prefill_band_reaches_across_blocks():
+    """A band narrower than a block and not aligned to one (window 100,
+    blocks of 128 at t = 384; rows whose band starts in the next block
+    meet a tile with none of their keys first), a prompt that is not a
+    multiple of 128 (t = 300, padded inside), in float32: the masked
+    softmax over every key, exactly up to float32 rounding."""
+    from deeplearning4j_tpu.kernels import flash_prefill
+    for t, window in ((384, 100), (300, 7), (300, None)):
+        rng = np.random.default_rng(t)
+        q = jnp.asarray(rng.normal(size=(2, t, 4, 16)), jnp.float32)
+        k, v = (jnp.asarray(rng.normal(size=(2, t, 2, 16)), jnp.float32)
+                for _ in range(2))
+        got = flash_prefill(q, k, v, window=window)
+        kk, vv = (jnp.repeat(a, 2, axis=2).swapaxes(1, 2) for a in (k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q.swapaxes(1, 2), kk) / 4.0
+        i, j = np.arange(t)[:, None], np.arange(t)[None]
+        seen = (j <= i) & ((j > i - window) if window else True)
+        want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(
+            jnp.where(seen, s, -1e30), -1), vv).swapaxes(1, 2)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def _pallas_calls(jaxpr):
+    """(name, grid, block shapes) of every pallas_call in ``jaxpr``, in
+    order, nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            gm = eqn.params["grid_mapping"]
+            found.append((eqn.params["name"], tuple(gm.grid),
+                          tuple(tuple(getattr(n, "block_size", n)
+                                      for n in bm.block_shape)
+                                for bm in gm.block_mappings)))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    found += _pallas_calls(sub)
+    return found
+
+
+def test_the_training_forward_keeps_its_grid_and_blocks():
+    """Cell 1's call ([16, 512, 16, 64] bf16, a [b, tk] bias, not
+    causal, through ``attention``): the forward keeps the single-block
+    kernel it had -- grid (256, 1), q / k / v / out blocks (1, 512, 64),
+    the bias's (1, 8, 512), the lse's (1, 512, 128) -- and the backward
+    its two kernels; with no band and as many K/V heads as query heads
+    nothing of the new options is traced."""
+    from deeplearning4j_tpu import kernels
+    b, t, h, d = 16, 512, 16, 64
+    qkv = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16)
+    bias = jax.ShapeDtypeStruct((b, t), jnp.float32)
+    fwd = lambda q, k, v, bias: kernels.attention(q, k, v, bias=bias,
+                                                  layout="bthd")
+    calls = _pallas_calls(jax.make_jaxpr(fwd)(qkv, qkv, qkv, bias).jaxpr)
+    blk, lse = (1, 512, 64), (1, 512, 128)
+    assert calls == [("flash_fwd", (256, 1),
+                      (blk, blk, blk, (1, 8, 512), blk, lse))]
+    loss = lambda *a: jnp.sum(fwd(*a).astype(jnp.float32))
+    grad = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        qkv, qkv, qkv, bias).jaxpr
+    assert [(n, g) for n, g, _ in _pallas_calls(grad)] == [
+        ("flash_fwd", (256, 1)), ("flash_bwd_dkv", (256, 1, 1)),
+        ("flash_bwd_dq", (256, 1, 1))]
+
+
+@pytest.mark.parametrize("t,window,grid", [
+    (4096, 512, (64, 8, 2)), (2048, 512, (64, 4, 2)), (4096, None, (48, 8, 8)),
+    (384, 100, (64, 3, 2)), (512, 512, (64, 1))])
+def test_flash_prefill_grid_covers_only_the_band(t, window, grid):
+    """The grid's minor axis is the widest span of key blocks a query
+    block's band reaches (two of 512 at window 512, not t / 512), every
+    block (1, blk, 128), K/V read at the query's group's head, no lse
+    output; a prompt of one block takes the single-block kernel."""
+    from deeplearning4j_tpu.kernels import flash_prefill
+    hq = grid[0]
+    q = jax.ShapeDtypeStruct((1, t, hq, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, t, 8, 128), jnp.bfloat16)
+    calls = _pallas_calls(jax.make_jaxpr(
+        lambda q, k, v: flash_prefill(q, k, v, window=window))(q, kv, kv).jaxpr)
+    blk = (1, 512 if t % 512 == 0 else 128, 128)
+    assert calls == [("flash_fwd", grid, (blk,) * 4)]
