@@ -176,6 +176,7 @@ exact pre-mesh jaxpr.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import logging
 import queue
@@ -190,6 +191,8 @@ import numpy as np
 
 from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.analysis import sanitize as _sanitize
+from deeplearning4j_tpu.telemetry import gc_watch as _gc_watch
+from deeplearning4j_tpu.telemetry.tracing import _profiler_annotation
 
 #: the per-host flight recorder (ISSUE 15): admissions, retires,
 #: allocator spill/fetch and watchdog transitions land in the
@@ -302,6 +305,19 @@ _SCHED_HOST = telemetry.counter(
     labelnames=("phase",))
 _SCHED_HOST_PHASE = {p: _SCHED_HOST.labels(phase=p)
                      for p in ("admit", "retire")}
+#: a poll that lands tokens more than this many seconds (less the
+#: scheduler's idle time) after the previous landing is a stall
+STALL_S = 1.0
+_STALLS = telemetry.counter(
+    "generation_server_stalls_total",
+    "polls that landed tokens more than STALL_S seconds (1 s) after "
+    "the previous landing, not counting the scheduler's idle time")
+_STALL_SECONDS = telemetry.counter(
+    "generation_server_stall_seconds_total",
+    "seconds of the stalls' gaps by phase: idle, admit, launch, poll "
+    "and retire partition the scheduler thread's time; gc is Python's "
+    "collections in the gap, on any thread, and overlaps the phase it "
+    "fell in", labelnames=("phase",))
 # a prompt is prefilled at its admit bucket's length: a padded position
 # costs a matmul row in every layer and, in a recurrent layer, a step
 # of the time scan
@@ -542,34 +558,116 @@ _PROFILE_PREFILL_EVERY = 4
 
 
 class _SchedPhases:
-    """The scheduler thread's phases around its decode scans: each is
-    a scoped span ``serve/<phase>`` (so it lands in a running profile
-    on the device lines' clock), and the phases during which the
-    device has nothing queued — ``admit``, ``retire`` — add their
-    duration to ``generation_server_sched_host_seconds_total``.  One
-    phase is open at a time; :meth:`switch` closes it and opens the
-    next, so the many ``return`` and ``continue`` edges of the loop
-    need no scope of their own."""
+    """The scheduler thread's phases, a partition of its time from the
+    loop's first admission to its exit: one is open at a time, each a
+    span ``serve/<phase>`` in the tracer (where one's end and the next
+    one's start are one instant) and in a running profile (on the
+    device lines' clock).
+
+    ========  =====================================================
+    idle      blocked on the empty queue
+    admit     ingest, planning, the admit dispatches, the scan's plan
+    launch    from ``serve/tick``'s opening to the scan's dispatch and
+              its result's copy to the host started
+    poll      the one read of the scan's result (``_from_device``)
+    retire    everything after the poll, up to the next phase
+    ========  =====================================================
+
+    :meth:`switch` closes the open phase and opens the next, so the
+    many ``return`` and ``continue`` edges of the loop need no scope of
+    their own; :meth:`tick` is the ``serve/tick`` span around exactly
+    ``launch`` and ``poll``.  ``seconds`` keeps each phase's running
+    total (for :meth:`landed`), and the phases during which the device
+    has nothing queued -- ``admit``, ``retire`` -- add theirs to
+    ``generation_server_sched_host_seconds_total``.  Each switch also
+    brings the ``gc_watch`` counters up to date."""
+
+    PHASES = ("idle", "admit", "launch", "poll", "retire")
 
     def __init__(self, tracer, owner):
         self._tracer, self._owner = tracer, owner
-        self._open = None    # (phase, its span's context, Span, t0)
+        self._open = None    # (phase, its profiler span, Span, t0)
+        self.seconds = dict.fromkeys(self.PHASES, 0.0)
+        # the previous landing: (when, ``seconds`` then, gc seconds then)
+        self._landing = (time.perf_counter(), dict(self.seconds),
+                         _gc_watch.seconds_total())
 
-    def switch(self, phase: Optional[str]) -> None:
-        now = time.perf_counter()
+    def _begin(self, name: str, now: float, **args):
+        """A span opened at ``now``, in the tracer and in a running
+        profile, as ``tracer.span`` opens one."""
+        ann = (_profiler_annotation(name) if self._tracer.enabled
+               else contextlib.nullcontext())
+        ann.__enter__()
+        return ann, self._tracer.begin(name, bound=True, owner=self._owner,
+                                       at=now, **args)
+
+    @staticmethod
+    def _end(opened, now: float, **extra) -> None:
+        ann, sp = opened
+        sp.end_at(now, **extra)
+        ann.__exit__(None, None, None)
+
+    def _close(self, now: float) -> None:
         if self._open is not None:
-            was, ctx, _, t0 = self._open
+            was, ann, sp, t0 = self._open
             self._open = None
-            ctx.__exit__(None, None, None)
-            if was in _SCHED_HOST_PHASE:      # not serve/idle
+            self._end((ann, sp), now)
+            self.seconds[was] += now - t0
+            if was in _SCHED_HOST_PHASE:
                 _SCHED_HOST_PHASE[was].inc(now - t0)
-        if phase is not None:
-            ctx = self._tracer.span("serve/" + phase, owner=self._owner)
-            self._open = (phase, ctx, ctx.__enter__(), now)
+
+    def switch(self, phase: str, now: Optional[float] = None) -> None:
+        """Close the open phase and open ``phase`` at the same instant."""
+        now = time.perf_counter() if now is None else now
+        self._close(now)
+        _gc_watch.sync()
+        self._open = (phase, *self._begin("serve/" + phase, now), now)
+
+    def close(self) -> None:
+        """End the open phase: the scheduler thread is leaving."""
+        self._close(time.perf_counter())
+
+    @contextlib.contextmanager
+    def tick(self, **args):
+        """``serve/tick`` (its ``args``) opening with ``launch``; the body
+        switches to ``poll``, and where it leaves, poll and the tick
+        close and ``retire`` opens, all at one instant."""
+        now = time.perf_counter()
+        self._close(now)
+        tick = self._begin("serve/tick", now, **args)
+        self.switch("launch", now)
+        error = {}
+        try:
+            yield
+        except BaseException as e:
+            error["error"] = type(e).__name__
+            raise
+        finally:
+            now = time.perf_counter()
+            self._close(now)
+            self._end(tick, now, **error)
+        self.switch("retire", now)
 
     def note(self, **args) -> None:
         if self._open is not None:
             self._open[2].note(**args)
+
+    def landed(self):
+        """At a poll that landed tokens: None, or -- when the time
+        since the previous landing, less what ``idle`` held of it,
+        passed ``STALL_S`` -- (that gap, {phase: its seconds in the
+        gap, and ``gc``: the collections' seconds, which overlap the
+        phase they fell in}).  The gap restarts here."""
+        now = time.perf_counter()
+        t0, was, gc0 = self._landing
+        gc_s = _gc_watch.seconds_total()
+        self._landing = (now, dict(self.seconds), gc_s)
+        gap = now - t0
+        if gap - (self.seconds["idle"] - was["idle"]) <= STALL_S:
+            return None
+        split = {p: self.seconds[p] - was[p] for p in self.PHASES}
+        split["gc"] = gc_s - gc0
+        return gap, split
 
 
 def _pow2_floor(n: int) -> int:
@@ -3262,7 +3360,7 @@ class GenerationServer:
 
     def _run(self, my_epoch: int):
         tracer = telemetry.get_tracer()
-        # phase spans share the tick span's owner: this scheduler
+        # the phase and tick spans' owner: this scheduler
         # INCARNATION (id, epoch), not the raw thread ident — idents
         # of dead threads are recycled, and the watchdog must never
         # flush an unrelated thread's spans
@@ -3270,11 +3368,10 @@ class GenerationServer:
         try:
             self._run_loop(my_epoch, tracer, phases)
         finally:
-            phases.switch(None)    # whichever edge left the loop
+            phases.close()    # whichever edge left the loop
 
     def _run_loop(self, my_epoch: int, tracer, phases: _SchedPhases):
         prof = telemetry.get_profiler()
-        owner = (id(self), my_epoch)
         stop = False
         prefill_t0 = None   # a sampled admission's dispatch, until the
                             # next poll shows the device past it
@@ -3287,7 +3384,6 @@ class GenerationServer:
             if idle and not stop:
                 phases.switch("idle")
                 item = self._queue.get()
-                phases.switch(None)
                 if self._superseded(my_epoch):
                     # recovered past us while we slept: hand the item
                     # to the live scheduler (sentinels included)
@@ -3501,10 +3597,9 @@ class GenerationServer:
                 else:
                     k = (1 if queue_busy
                          else min(self.tick_batch, _pow2_floor(k_drain)))
-                # serve/tick: dispatch -> the one host poll
-                phases.switch(None)
-                with tracer.span("serve/tick", owner=owner,
-                                 active=n_active, queued=n_pending,
+                # serve/tick: serve/launch (the dispatch) -> serve/poll
+                # (the one host read), then serve/retire
+                with phases.tick(active=n_active, queued=n_pending,
                                  k=k, spec=int(use_spec)):
                     self._mark_tick(my_epoch,
                                     (my_epoch, time.monotonic(), k))
@@ -3541,55 +3636,61 @@ class GenerationServer:
                     with prof.measure("verify" if use_spec
                                       else "decode_tick",
                                       devices=self._device_labels):
-                        # serve/launch: the dispatch call, and the
-                        # packed result's copy to the host started
-                        # behind it — before the host asks
-                        with tracer.span("serve/launch", owner=owner):
-                            kc, vc, state, polled = fn(
-                                *self._params, *operands)
-                            polled.copy_to_host_async()
+                        # the dispatch call, and the packed result's
+                        # copy to the host started behind it — before
+                        # the host asks
+                        kc, vc, state, polled = fn(
+                            *self._params, *operands)
+                        polled.copy_to_host_async()
                         _sanitize.mark_donated("serve/tick", kc_in,
                                                vc_in, state_in)
                         _DISPATCHED["scan"].inc()
-                        # serve/poll: THE host sync, one read per
-                        # dispatch of either kind — tokens staged
-                        # device-side, per-slot live-tick counts,
-                        # budgets left (a speculative round: its
-                        # per-slot tallies too, so the host can
-                        # attribute acceptance to tenants and feed the
-                        # controller)
-                        with tracer.span("serve/poll", owner=owner):
-                            polled_h = self._from_device("scan", polled)
-                    if prefill_t0 is not None:
-                        # the device is past every admission dispatched
-                        # before this scan: the sampled one's clock ends
-                        for dev in self._device_labels or (None,):
-                            prof.observe(
-                                "prefill",
-                                time.perf_counter() - prefill_t0,
-                                device=dev)
-                        prefill_t0 = None
-                    routed_h = None
-                    if len(polled_h) > self.n_slots:
-                        # below the slots' rows: the routed layers' tally
-                        routed_h = polled_h[self.n_slots:].reshape(-1)[
-                            :self._gen.held_experts + 2]
-                        polled_h = polled_h[:self.n_slots]
-                    toks_h = polled_h[:, :n_tok]
-                    emit_h, rem_h = (polled_h[:, n_tok],
-                                     polled_h[:, n_tok + 1])
-                    alive_h = int(np.count_nonzero(rem_h))
-                    prop_h = acc_h = None
-                    n_prop = n_acc = 0
-                    if use_spec:
-                        prop_h, acc_h = (polled_h[:, n_tok + 2],
-                                         polled_h[:, n_tok + 3])
-                        n_prop = int(prop_h.sum())
-                        n_acc = int(acc_h.sum())
-                    _HOST_SYNCS.inc()
-                    self._mark_tick(my_epoch, None)
+                        # THE host sync, one read per dispatch of either
+                        # kind — tokens staged device-side, per-slot
+                        # live-tick counts, budgets left (a speculative
+                        # round: its per-slot tallies too, so the host
+                        # can attribute acceptance to tenants and feed
+                        # the controller)
+                        phases.switch("poll")
+                        polled_h = self._from_device("scan", polled)
                 # serve/retire: unpack the poll, retire, free blocks
-                phases.switch("retire")
+                if prefill_t0 is not None:
+                    # the device is past every admission dispatched
+                    # before this scan: the sampled one's clock ends
+                    for dev in self._device_labels or (None,):
+                        prof.observe(
+                            "prefill",
+                            time.perf_counter() - prefill_t0,
+                            device=dev)
+                    prefill_t0 = None
+                routed_h = None
+                if len(polled_h) > self.n_slots:
+                    # below the slots' rows: the routed layers' tally
+                    routed_h = polled_h[self.n_slots:].reshape(-1)[
+                        :self._gen.held_experts + 2]
+                    polled_h = polled_h[:self.n_slots]
+                toks_h = polled_h[:, :n_tok]
+                emit_h, rem_h = (polled_h[:, n_tok],
+                                 polled_h[:, n_tok + 1])
+                alive_h = int(np.count_nonzero(rem_h))
+                prop_h = acc_h = None
+                n_prop = n_acc = 0
+                if use_spec:
+                    prop_h, acc_h = (polled_h[:, n_tok + 2],
+                                     polled_h[:, n_tok + 3])
+                    n_prop = int(prop_h.sum())
+                    n_acc = int(acc_h.sum())
+                _HOST_SYNCS.inc()
+                self._mark_tick(my_epoch, None)
+                n_emitted = int(emit_h.sum())
+                stall = phases.landed() if n_emitted else None
+                if stall is not None:
+                    gap, split = stall
+                    _STALLS.inc()
+                    for phase, secs in split.items():
+                        _STALL_SECONDS.labels(phase=phase).inc(secs)
+                    _FLIGHT.record("stall", seconds=gap, k=k,
+                                   active=n_active, **split)
                 # device-truth occupancy at scan end (the host view is
                 # reconciled below after retire/cancel bookkeeping)
                 _SLOTS_BUSY.set(alive_h)
@@ -3671,7 +3772,7 @@ class GenerationServer:
                         sum(n * pad for n, (_, pad) in walked))
                     if routed_h is not None:
                         self._count_routed(routed_h, k)
-                _TOKENS_EMITTED.inc(int(emit_h.sum()))
+                _TOKENS_EMITTED.inc(n_emitted)
                 _SLOT_TICKS.inc(n_active * (R if use_spec else k))
                 _OCC.observe(n_active / self.n_slots)
                 now_p = time.perf_counter()
@@ -3761,9 +3862,9 @@ class GenerationServer:
                 # update the gauges)
                 _SLOTS_BUSY.set(n_active)
                 _QDEPTH.set(n_pending + self._queue.qsize())
-                phases.switch(None)
             except Exception as e:  # surface to the implicated callers
-                phases.switch(None)
+                # the recovery frees slots and blocks, as retire does
+                phases.switch("retire")
                 self._mark_tick(my_epoch, None)
                 with self._lock:
                     if self._epoch != my_epoch:

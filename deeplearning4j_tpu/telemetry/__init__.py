@@ -35,6 +35,10 @@ pieces, all stdlib-only:
   tracer's open spans, a metric snapshot, pre-crash metric HISTORY
   and the SLO state — into atomic postmortem bundles
   ``scripts/postmortem.py`` renders as a merged timeline;
+* ``gc_watch``  — Python's garbage collections: seconds
+  and collections by generation, and from generation 1 up a
+  ``gc/collect`` profiler host span; its ``gc.callbacks`` hook takes
+  no lock and the counters catch up at each read of the registry;
 * ``tsdb``      — the embedded time-series store (ISSUE 16): bounded
   per-series history rings (raw window + downsampled older tier)
   recorded each scrape/beacon cycle, range reads with
@@ -76,8 +80,10 @@ from deeplearning4j_tpu.telemetry.flightrec import FlightRecorder
 from deeplearning4j_tpu.telemetry.slo import (AlertEngine, CommandSink,
                                               SLOSpec, WebhookFileSink)
 from deeplearning4j_tpu.telemetry.tsdb import TimeSeriesStore
+from deeplearning4j_tpu.telemetry import gc_watch
 
 _REGISTRY = MetricsRegistry()
+gc_watch.install(_REGISTRY)
 _TRACER = SpanTracer()
 _PROFILER = DeviceProfiler(_REGISTRY)
 _FLIGHTREC = FlightRecorder()

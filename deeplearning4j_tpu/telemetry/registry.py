@@ -29,7 +29,8 @@ from __future__ import annotations
 import math
 import threading
 import time
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 _INF = float("inf")
 
@@ -301,6 +302,18 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families: Dict[str, _Family] = {}
+        self._pre_read: List[Callable[[], None]] = []
+
+    def add_pre_read_hook(self, fn: Callable[[], None]) -> None:
+        """Call ``fn()`` first in every ``snapshot()`` and
+        ``render_prometheus()``: a source that cannot take a child's
+        lock where it counts (``gc_watch``'s callback) brings its
+        counters up to date there."""
+        self._pre_read.append(fn)
+
+    def _refresh(self) -> None:
+        for fn in self._pre_read:
+            fn()
 
     def _get_or_create(self, cls, name: str, documentation: str,
                        labelnames=(), **kw):
@@ -350,6 +363,7 @@ class MetricsRegistry:
     # -- exposition ----------------------------------------------------
     def render_prometheus(self) -> str:
         """Prometheus/OpenMetrics text format, one sample per series."""
+        self._refresh()
         out: List[str] = []
         for fam in self.families():
             out.append(f"# HELP {fam.name} {fam.documentation}")
@@ -378,6 +392,7 @@ class MetricsRegistry:
         histograms with count/sum/buckets and derived p50/p95/p99 —
         the record shape ``ui.FileStatsStorage`` appends and
         ``ui.render_report`` tabulates."""
+        self._refresh()
         snap = {"timestamp": time.time(), "counters": {}, "gauges": {},
                 "histograms": {}}
         for fam in self.families():

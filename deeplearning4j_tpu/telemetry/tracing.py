@@ -99,6 +99,12 @@ class Span:
         if self._tracer is not None:
             self._tracer._end(self._sid, extra)
 
+    def end_at(self, t: float, **extra) -> None:
+        """:meth:`end` at the ``time.perf_counter()`` reading ``t``:
+        one span's end and the next one's start can be one instant."""
+        if self._tracer is not None:
+            self._tracer._end(self._sid, extra, t)
+
     def note(self, **args) -> None:
         """Add args learned while the span is open (how many requests
         an admission block admitted); a no-op on a no-op span."""
@@ -117,15 +123,15 @@ class Span:
 _NULL_SPAN = Span(None, -1, "", 0.0, 0, False, None, {})
 
 
-def _profiler_annotation(name: str):
-    """The profiler's own host span (a TraceMe) for a scoped span.
-    jax is looked up, never imported: a profile can only be running in
-    a process that has imported it, and this module stays importable
-    without it."""
+def _profiler_annotation(name: str, **args):
+    """The profiler's own host span (a TraceMe) for a scoped span,
+    ``args`` its stats in the capture.  jax is looked up, never
+    imported: a profile can only be running in a process that has
+    imported it, and this module stays importable without it."""
     jax = sys.modules.get("jax")
     if jax is None:
         return contextlib.nullcontext()
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 class SpanTracer:
@@ -150,11 +156,15 @@ class SpanTracer:
         self._eseq = itertools.count()   # closed-EVENT seq (beacon dedup)
         self._open: Dict[int, Span] = {}
 
-    def _now_us(self) -> float:
-        return (time.perf_counter_ns() - self._t0) / 1e3
+    def _now_us(self, t: Optional[float] = None) -> float:
+        """Now, or the ``time.perf_counter()`` reading ``t``, in
+        microseconds from this tracer's origin."""
+        if t is None:
+            return (time.perf_counter_ns() - self._t0) / 1e3
+        return (t * 1e9 - self._t0) / 1e3
 
     # -- tracked spans --------------------------------------------------
-    def begin(self, name: str, bound: bool = False, owner=None,
+    def begin(self, name: str, bound: bool = False, owner=None, at=None,
               **args) -> Span:
         """Open a tracked span.  ``bound=True`` ties its lifetime to
         an OWNER: :meth:`end_owned_by` flushes it when that owner is
@@ -164,18 +174,19 @@ class SpanTracer:
         self), epoch)``) — CPython recycles thread idents of dead
         threads, so a raw tid can collide with an unrelated thread
         started after the owner died.  Unbound spans outlive threads
-        — a request phase ends wherever the request retires."""
+        — a request phase ends wherever the request retires.  ``at``:
+        the ``time.perf_counter()`` reading it opened at (now if None)."""
         if not self.enabled:
             return _NULL_SPAN
         if bound and owner is None:
             owner = threading.get_ident()
-        sp = Span(self, next(self._seq), name, self._now_us(),
+        sp = Span(self, next(self._seq), name, self._now_us(at),
                   threading.get_ident(), bound, owner, dict(args))
         with self._lock:
             self._open[sp._sid] = sp
         return sp
 
-    def _end(self, sid: int, extra: Dict) -> None:
+    def _end(self, sid: int, extra: Dict, at=None) -> None:
         with self._lock:
             sp = self._open.pop(sid, None)
         if sp is None:
@@ -187,7 +198,7 @@ class SpanTracer:
         # private perf_counter origin
         self._events.append({
             "name": sp.name, "ph": "X", "ts": sp.ts,
-            "dur": self._now_us() - sp.ts,
+            "dur": self._now_us(at) - sp.ts,
             "pid": os.getpid(), "tid": sp.tid, "args": args,
             "seq": next(self._eseq), "wall": time.time(),
         })
